@@ -545,18 +545,10 @@ def bench_transport(trials: int, sizes=None):
     chain+envelope strictly below plain delta bytes-on-wire at 10^7 params
     with fresh-pull latency within 1.5x of the uncached delta path."""
     from repro.core import InMemoryFolder, NodeUpdate, WeightStore
-    from repro.core.serialize import _zstd_module
 
-    # prefer the real zstd frame; fall back to the deflate envelope when the
-    # container has no zstd module (CI installs zstandard and runs the real
-    # thing). Both the bare and the enveloped chain specs are measured: zstd
-    # inflates at GB/s so the enveloped spec carries the acceptance check,
-    # but deflate decodes ~40MB/s — judging the chain codec by np.load's
-    # inflate speed would measure the fallback envelope, not the chains — so
-    # without zstd the bare chain spec carries it (recorded in the JSON).
-    envelope = "zstd" if _zstd_module() is not None else "npz"
-    chain_env_spec = f"delta(chain=4)|{envelope}"
-    accept_spec = chain_env_spec if envelope == "zstd" else "delta(chain=4)"
+    # Both the bare and the zstd-enveloped chain specs are measured; zstd
+    # inflates at GB/s, so the enveloped spec carries the acceptance check.
+    chain_env_spec = "delta(chain=4)|zstd"
     specs = ["full", "delta", "delta(chain=4)", chain_env_spec,
              "topk(adaptive)"]
     sizes = sizes or [10**6, 10**7]
@@ -610,34 +602,25 @@ def bench_transport(trials: int, sizes=None):
                     f"steady={per_spec[spec]['steady_pull_ms']}ms")
         results[str(N)] = per_spec
     biggest = str(max(int(n) for n in results))
-    chain_r, delta_r = results[biggest][accept_spec], results[biggest]["delta"]
-    env_r = results[biggest][chain_env_spec]
+    chain_r, delta_r = results[biggest][chain_env_spec], results[biggest]["delta"]
     from ._schema import write_bench
 
     payload = {
-        "pushes": pushes, "step_fraction": frac, "envelope": envelope,
+        "pushes": pushes, "step_fraction": frac, "envelope": "zstd",
         "results": results,
         "acceptance": {
-            "criterion": (f"{accept_spec} strictly below plain delta "
+            "criterion": (f"{chain_env_spec} strictly below plain delta "
                           "bytes-on-wire at the largest size, fresh pull "
                           "within 1.5x of the uncached delta path"),
-            "note": (None if envelope == "zstd" else
-                     "no zstd module in this container: the enveloped spec "
-                     "ran with the deflate fallback (decodes ~40MB/s, which "
-                     "measures np.load's inflate, not the chain codec), so "
-                     "the bare chain spec carries the latency bound"),
             "at_params": int(biggest),
             "wire_ratio_chain_vs_delta": round(
                 chain_r["bytes_on_wire"] / max(delta_r["bytes_on_wire"], 1), 3),
-            "wire_ratio_chain_env_vs_delta": round(
-                env_r["bytes_on_wire"] / max(delta_r["bytes_on_wire"], 1), 3),
             "fresh_pull_ratio_chain_vs_delta": round(
                 chain_r["fresh_pull_ms"] / max(delta_r["fresh_pull_ms"], 1e-9), 3),
             "steady_pull_ratio_chain_vs_delta": round(
                 chain_r["steady_pull_ms"] / max(delta_r["steady_pull_ms"], 1e-9), 3),
             "passed": bool(
                 chain_r["bytes_on_wire"] < delta_r["bytes_on_wire"]
-                and env_r["bytes_on_wire"] < delta_r["bytes_on_wire"]
                 and chain_r["fresh_pull_ms"] <= 1.5 * delta_r["fresh_pull_ms"]),
         },
     }
@@ -1185,6 +1168,9 @@ def _parse_soak_size(token: str):
 
 
 def main(argv=None) -> None:
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--only", default=None, choices=list(TABLES))
     ap.add_argument("--trials", type=int, default=1)

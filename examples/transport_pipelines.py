@@ -21,7 +21,6 @@ from repro.core import (
     WeightStore,
     normalize_transport,
 )
-from repro.core.serialize import _zstd_module
 from repro.core.strategies import FedAvgM
 
 
@@ -38,10 +37,9 @@ def sparse_steps(n_params=200_000, pushes=12, fraction=0.005, seed=0):
 
 
 def compare_pipelines():
-    envelope = "zstd" if _zstd_module() is not None else "npz"
-    specs = ["full", "quantized", "delta", f"delta(chain=4)|{envelope}",
+    specs = ["full", "quantized", "delta", "delta(chain=4)|zstd",
              "topk(adaptive)"]
-    print(f"pipeline comparison ({envelope} envelope available)\n")
+    print("pipeline comparison\n")
     print(f"{'spec':<22}{'wire MB':>9}{'rebases':>9}{'re-anchors':>11}"
           f"{'max depth':>11}")
     for spec in specs:

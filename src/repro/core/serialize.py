@@ -17,6 +17,7 @@ from typing import Any
 
 import jax
 import numpy as np
+import zstandard
 
 from .tree import LeafSpec, PyTree, path_str
 
@@ -29,48 +30,18 @@ _ZSTD_MAGIC = b"\x28\xb5\x2f\xfd"
 COMPRESSIONS = ("none", "npz", "zstd")
 
 
-_ZSTD_MODULE: object = None  # cached binding; False = probed and absent
-
-
-def _zstd_module():
-    """The first importable zstd binding, or None (offline containers).
-    Cached: the import probe runs once per process, not per blob. Compressor
-    contexts are still built per call — zstandard contexts are not
-    thread-safe, and stores are shared across threads."""
-    global _ZSTD_MODULE
-    if _ZSTD_MODULE is None:
-        import importlib
-
-        for name in ("zstandard", "zstd", "compression.zstd"):
-            try:
-                _ZSTD_MODULE = importlib.import_module(name)
-                break
-            except ImportError:
-                continue
-        else:
-            _ZSTD_MODULE = False
-    return _ZSTD_MODULE or None
-
-
 def _zstd_compress(blob: bytes) -> bytes:
-    mod = _zstd_module()
-    if mod is None:
-        raise ImportError("compress='zstd' requires a zstd module (zstandard)")
-    if hasattr(mod, "ZstdCompressor"):  # zstandard
-        return mod.ZstdCompressor().compress(blob)
-    return mod.compress(blob)
+    # contexts are built per call: zstandard contexts are not thread-safe,
+    # and stores are shared across threads
+    return zstandard.ZstdCompressor().compress(blob)
+
 
 def maybe_decompress(blob: bytes) -> bytes:
     """Undo the optional zstd wire wrapping; readers stay format-agnostic.
     (``savez_compressed`` needs no sniffing — np.load handles it natively.)"""
     if blob[:4] != _ZSTD_MAGIC:
         return blob
-    mod = _zstd_module()
-    if mod is None:
-        raise ImportError("blob is zstd-compressed but no zstd module is available")
-    if hasattr(mod, "ZstdDecompressor"):  # zstandard
-        return mod.ZstdDecompressor().decompress(blob)
-    return mod.decompress(blob)
+    return zstandard.ZstdDecompressor().decompress(blob)
 
 
 @dataclass

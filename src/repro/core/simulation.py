@@ -83,7 +83,18 @@ class ProcessCrashed(RuntimeError):
 def _mp_entry(target: Callable[..., Any], args: tuple, kwargs: dict, conn) -> None:
     """Child entrypoint: run the client and ship (ok, result, tb) back over the
     child's private pipe (one channel per process — a SIGKILL mid-send can only
-    corrupt the victim's own channel, never a survivor's)."""
+    corrupt the victim's own channel, never a survivor's).
+
+    Supervised children run JAX on the host CPU, and so do the processes they
+    start (the variable is inherited). An accelerator belongs to one process at
+    a time: on a TPU host the first child to start would take the chip and
+    every sibling would fail to open it, losing all clients but one. The work
+    these children do is the federation plane, which is host work; a chip is
+    driven by one process that runs its nodes as threads (``run_threaded``)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     try:
         result = target(*args, **kwargs)
         conn.send((True, result, ""))

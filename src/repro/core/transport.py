@@ -1139,12 +1139,6 @@ class TransportPipeline:
             LEGACY_TRANSPORTS.get(spec, spec)))
         self.spec = _canonical(policy, envelope)
         self.compress = envelope
-        if envelope == "zstd":
-            from .serialize import _zstd_module
-
-            if _zstd_module() is None:
-                raise ImportError(
-                    "compress='zstd' requires a zstd module (zstandard)")
         name, args = policy
         kw: dict[str, Any] = dict(
             compress=envelope if envelope != "none" else "none",

@@ -5,10 +5,11 @@ Each kernel subpackage ships:
   ops.py    — jit'd public wrapper (padding, dtype plumbing, platform switch)
   ref.py    — pure-jnp oracle used by the allclose test sweeps
 
-Kernels run natively on TPU; on CPU (this container) they execute under
-``interpret=True`` which evaluates the kernel body block-by-block — bitwise
+Kernels run natively by default (``interpret=False``), so no caller on a TPU
+runs one interpreted by accident. On the CPU they run only under
+``interpret=True``, which evaluates the kernel body block-by-block — bitwise
 semantics, no MXU. ``ops`` defaults to the jnp reference on CPU for speed and
-to the kernel on TPU; tests force ``interpret=True`` to validate the bodies.
+to the kernel on TPU; tests pass ``interpret=True`` to validate the bodies.
 """
 
 

@@ -18,6 +18,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_SUBLANES = 8
 
 
 def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, acc_ref, m_ref, l_ref):
@@ -30,7 +31,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, acc_ref, m_ref, l_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32)          # (G, hd)
+    q = q_ref[0].astype(jnp.float32)          # (Gp, hd)
     k = k_ref[0].astype(jnp.float32)          # (bk, hd)
     v = v_ref[0].astype(jnp.float32)
     valid = valid_ref[...][:, 0] != 0          # (bk,)
@@ -38,7 +39,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, acc_ref, m_ref, l_ref)
     scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                  # (G, bk)
+    ) * scale                                  # (Gp, bk)
     scores = jnp.where(valid[None, :], scores, NEG_INF)
 
     m_prev, l_prev = m_ref[...], l_ref[...]
@@ -65,13 +66,17 @@ def decode_attention(
     valid: jnp.ndarray,   # (C,) bool — precomputed ring-slot validity
     *,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     B, KV, G, hd = q.shape
     C = k.shape[1]
     block_k = min(block_k, C)
     assert C % block_k == 0, (C, block_k)
-    qf = q.reshape(B * KV, G, hd)
+    # Mosaic cannot reduce a score tile of fewer than 8 sublanes (G = 1 for
+    # every MHA model), so the query tile is padded to whole sublane groups
+    # with zero rows; their outputs are sliced away below
+    Gp = -(-G // _SUBLANES) * _SUBLANES
+    qf = jnp.pad(q, ((0, 0), (0, 0), (0, Gp - G), (0, 0))).reshape(B * KV, Gp, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * KV, C, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * KV, C, hd)
     validf = valid.astype(jnp.int32)[:, None]
@@ -80,18 +85,18 @@ def decode_attention(
         _decode_kernel,
         grid=(B * KV, C // block_k),
         in_specs=[
-            pl.BlockSpec((1, G, hd), lambda b, c: (b, 0, 0)),
+            pl.BlockSpec((1, Gp, hd), lambda b, c: (b, 0, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, block_k, hd), lambda b, c: (b, c, 0)),
             pl.BlockSpec((block_k, 1), lambda b, c: (c, 0)),
         ],
-        out_specs=pl.BlockSpec((1, G, hd), lambda b, c: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, Gp, hd), lambda b, c: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B * KV, Gp, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((Gp, hd), jnp.float32),
+            pltpu.VMEM((Gp, 1), jnp.float32),
+            pltpu.VMEM((Gp, 1), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf, validf)
-    return out.reshape(B, KV, G, hd)
+    return out[:, :G].reshape(B, KV, G, hd)

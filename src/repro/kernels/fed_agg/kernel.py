@@ -35,9 +35,11 @@ BK = 64    # client-axis tile: wider fleets stream K in BK-row stripes
 
 
 def _wsum(w, x):
-    # (1, K) @ (K, BN) — lands on the MXU; f32 accumulation
+    # (1, K) @ (K, BN) — lands on the MXU; full f32 passes, since the result
+    # is a model's weights and the kernel is bound by HBM, not by the MXU
     return jax.lax.dot_general(
-        w.T, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        w.T, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
 
 
@@ -59,7 +61,7 @@ def _fed_agg_acc_kernel(w_ref, x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def fed_agg(stacked: jnp.ndarray, weights: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def fed_agg(stacked: jnp.ndarray, weights: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
     """stacked: (K, N) f32; weights: (K,) f32 → (N,) f32 = weightsᵀ·stacked."""
     K, N = stacked.shape
     stacked = stacked.astype(jnp.float32)
@@ -144,7 +146,7 @@ def _fed_opt_apply_kernel(a_ref, p_ref, m_ref, v_ref, po_ref, mo_ref, vo_ref,
 def fed_opt(stacked: jnp.ndarray, weights: jnp.ndarray, x: jnp.ndarray,
             m: jnp.ndarray, v: jnp.ndarray, *, lr: float, b1: float, b2: float,
             tau: float, variant: str = "adam",
-            interpret: bool = True) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+            interpret: bool = False) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Fused FedAdam/FedYogi/FedAdagrad step (Reddi et al. 2021):
 
         avg = weightsᵀ·stacked;  Δ = x − avg

@@ -82,7 +82,7 @@ def flash_attention(
     window: int = 0,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     B, S, KV, G, hd = q.shape
     assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)

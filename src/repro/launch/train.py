@@ -32,7 +32,7 @@ from repro.core import (
 )
 from repro.core.partition import partition_sequence_dataset
 from repro.data import lm_batch_iterator, make_synthetic_wikitext
-from repro.launch.mesh import make_host_mesh
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.optim import adamw, with_accumulation
 from repro.training import Trainer
@@ -71,7 +71,8 @@ def evaluate_lm(cfg, params, tokens, *, seq_len, batch_size=8, max_batches=8):
     return {"eval_loss": float(np.mean(losses)), "eval_accuracy": float(np.mean(accs))}
 
 
-def run_client(cfg, node_id, folder, args, tokens_shard, eval_tokens):
+def run_client(cfg, node_id, index, folder, args, tokens_shard, eval_tokens):
+    """Train silo ``node_id``; ``index`` (its place among the silos) seeds it."""
     strategy = get_strategy(args.strategy)
     if args.mode == "sync":
         node = SyncFederatedNode(strategy=strategy, shared_folder=folder, node_id=node_id,
@@ -80,7 +81,7 @@ def run_client(cfg, node_id, folder, args, tokens_shard, eval_tokens):
         node = AsyncFederatedNode(strategy=strategy, shared_folder=folder, node_id=node_id)
     trainer, data_fn = make_lm_trainer(
         cfg, tokens_shard, seq_len=args.seq_len, batch_size=args.batch_size,
-        seed=args.seed + hash(node_id) % 1000, lr=args.lr, accum=args.accum,
+        seed=args.seed + index, lr=args.lr, accum=args.accum,
         name=node_id,
     )
     steps = args.steps_per_epoch
@@ -94,6 +95,7 @@ def run_client(cfg, node_id, folder, args, tokens_shard, eval_tokens):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="pythia-14m")
     ap.add_argument("--mode", default="async", choices=["async", "sync"])
@@ -106,7 +108,8 @@ def main(argv=None):
     ap.add_argument("--steps-per-epoch", type=int, default=50)
     ap.add_argument("--batch-size", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=128)
-    ap.add_argument("--vocab", type=int, default=512)
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="cut the vocab to this size (default: the config's own)")
     ap.add_argument("--lr", type=float, default=2e-5)
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--timeout", type=float, default=300.0)
@@ -118,7 +121,8 @@ def main(argv=None):
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    cfg = cfg.replace(vocab_size=min(cfg.vocab_size, args.vocab))
+    if args.vocab is not None:
+        cfg = cfg.replace(vocab_size=min(cfg.vocab_size, args.vocab))
 
     data = make_synthetic_wikitext(vocab_size=cfg.vocab_size, seed=args.seed)
     num_nodes = args.simulate or args.num_nodes
@@ -128,7 +132,7 @@ def main(argv=None):
         folder = InMemoryFolder() if args.store == "memory://" else make_folder(args.store)
         args.num_nodes = num_nodes
         fns = [
-            (lambda i=i: run_client(cfg, f"node{i}", folder, args, shards[i], data.test_tokens))
+            (lambda i=i: run_client(cfg, f"node{i}", i, folder, args, shards[i], data.test_tokens))
             for i in range(num_nodes)
         ]
         results = run_threaded(fns, names=[f"node{i}" for i in range(num_nodes)])
@@ -137,13 +141,14 @@ def main(argv=None):
                 print(f"[{r.node_id}] FAILED: {r.error}")
             else:
                 print(json.dumps(r.result))
-        return 0
+        return 1 if any(r.error for r in results) else 0
 
     if args.node_id is None:
         ap.error("need --node-id (production) or --simulate N")
     idx = int(args.node_id[-1]) if args.node_id[-1].isdigit() else 0
     folder = make_folder(args.store)
-    result = run_client(cfg, args.node_id, folder, args, shards[idx % num_nodes], data.test_tokens)
+    result = run_client(cfg, args.node_id, idx, folder, args, shards[idx % num_nodes],
+                        data.test_tokens)
     print(json.dumps(result))
     return 0
 

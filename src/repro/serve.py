@@ -19,9 +19,11 @@ import time
 import numpy as np
 
 from repro.api import connect, serve
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main(argv=None) -> int:
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--store", required=True, help="weight-store URI (see repro.api)")
     ap.add_argument("--arch", required=True, help="arch name from repro.configs")
@@ -73,7 +75,11 @@ def main(argv=None) -> int:
             )
             if args.requests and served >= args.requests:
                 break
-        print("SLO", json.dumps(node.stats()))
+        stats = node.stats()
+        print("SLO", json.dumps(stats))
+        if stats["deploy_failures"]:
+            print(f"serve: {stats['deploy_failures']} deployment(s) failed")
+            return 1
         return 0
     finally:
         node.stop()

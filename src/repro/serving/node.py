@@ -176,6 +176,7 @@ class ServingNode:
 
         self._lock = threading.Lock()
         self._swaps = 0
+        self._deploy_failures = 0
         self._requests = 0
         self._tokens = 0
         self._serve_seconds = 0.0
@@ -226,14 +227,24 @@ class ServingNode:
         """One freshness sweep (also callable inline, e.g. from tests).
         Returns True when a new deployment was swapped in."""
         swapped = False
+        dep = None
         try:
             with self.telemetry.span("serve.poll"):
                 dep = self.watcher.poll()
-            if dep is not None:
-                self.deploy(dep)
-                swapped = True
         except Exception:
             _log.warning("serving node %s: poll failed", self.node_id, exc_info=True)
+        if dep is not None:
+            # the watcher thread must outlive a failed deploy, but serving
+            # then stays on older weights: counted in stats() so that callers
+            # can fail on it instead of reading a clean run
+            try:
+                self.deploy(dep)
+                swapped = True
+            except Exception:
+                with self._lock:
+                    self._deploy_failures += 1
+                _log.error("serving node %s: deploy of %s@%d failed", self.node_id,
+                           dep.source, dep.counter, exc_info=True)
         d = self._deployed
         if d is not None and self.watcher.last_max_counter is not None:
             behind = max(0, self.watcher.last_max_counter - d.counter)
@@ -418,6 +429,7 @@ class ServingNode:
             swap_sorted = sorted(self._swap_ms)
             stale = list(self._stale_recent)
             swaps, requests, tokens = self._swaps, self._requests, self._tokens
+            deploy_failures = self._deploy_failures
             serve_seconds = self._serve_seconds
         d = self._deployed
         return {
@@ -425,6 +437,7 @@ class ServingNode:
             "source": d.source if d else "",
             "counter": d.counter if d else -1,
             "swaps": swaps,
+            "deploy_failures": deploy_failures,
             "requests": requests,
             "tokens": tokens,
             "tokens_per_sec": round(tokens / serve_seconds, 3) if serve_seconds > 0 else 0.0,

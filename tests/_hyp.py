@@ -1,100 +1,2 @@
-"""Hypothesis shim: real hypothesis when installed, deterministic fallback
-otherwise.
-
-The seed container ships without ``hypothesis``, which used to break
-collection of every module importing it. Test modules import ``given`` /
-``settings`` / ``strategies`` from here instead; when hypothesis is missing,
-the fallback replays each property test over a fixed number of
-pseudo-randomly drawn examples (seeded per test name, so failures
-reproduce). No shrinking, no database — but the property coverage survives.
-"""
-from __future__ import annotations
-
-import hashlib
-
-try:  # pragma: no cover - exercised only where hypothesis is installed
-    from hypothesis import given, settings, strategies  # noqa: F401
-
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    import numpy as np
-
-    HAVE_HYPOTHESIS = False
-    _DEFAULT_MAX_EXAMPLES = 20
-
-    class _Strategy:
-        def __init__(self, draw):
-            self._draw = draw
-
-        def draw(self, rng):
-            return self._draw(rng)
-
-    class strategies:  # noqa: N801 - mirrors the hypothesis module name
-        @staticmethod
-        def integers(min_value, max_value):
-            return _Strategy(lambda rng: int(rng.integers(min_value, max_value + 1)))
-
-        @staticmethod
-        def floats(min_value, max_value):
-            return _Strategy(lambda rng: float(rng.uniform(min_value, max_value)))
-
-        @staticmethod
-        def lists(elements, *, min_size=0, max_size=10):
-            def draw(rng):
-                size = int(rng.integers(min_size, max_size + 1))
-                return [elements.draw(rng) for _ in range(size)]
-
-            return _Strategy(draw)
-
-        @staticmethod
-        def permutations(values):
-            values = list(values)
-            return _Strategy(lambda rng: [values[i] for i in rng.permutation(len(values))])
-
-        @staticmethod
-        def sampled_from(values):
-            values = list(values)
-            return _Strategy(lambda rng: values[int(rng.integers(len(values)))])
-
-        @staticmethod
-        def booleans():
-            return _Strategy(lambda rng: bool(rng.integers(2)))
-
-    def settings(*, max_examples=_DEFAULT_MAX_EXAMPLES, **_ignored):
-        def deco(fn):
-            fn._hyp_max_examples = max_examples
-            return fn
-
-        return deco
-
-    def given(*arg_strategies, **kwarg_strategies):
-        def deco(fn):
-            # No functools.wraps: pytest must see a zero-arg signature, not the
-            # original one (whose params would be mistaken for fixtures).
-            def wrapper():
-                # check both the wrapper (settings above given) and the bare fn
-                # (settings below given) — real hypothesis accepts either order
-                n = getattr(wrapper, "_hyp_max_examples",
-                            getattr(fn, "_hyp_max_examples", _DEFAULT_MAX_EXAMPLES))
-                seed = int.from_bytes(
-                    hashlib.sha256(fn.__qualname__.encode()).digest()[:4], "big"
-                )
-                rng = np.random.default_rng(seed)
-                for example in range(n):
-                    drawn_args = tuple(s.draw(rng) for s in arg_strategies)
-                    drawn_kwargs = {k: s.draw(rng) for k, s in kwarg_strategies.items()}
-                    try:
-                        fn(*drawn_args, **drawn_kwargs)
-                    except AssertionError as e:
-                        raise AssertionError(
-                            f"fallback example {example}: args={drawn_args} "
-                            f"kwargs={drawn_kwargs}: {e}"
-                        ) from e
-
-            wrapper.__name__ = fn.__name__
-            wrapper.__qualname__ = fn.__qualname__
-            wrapper.__doc__ = fn.__doc__
-            wrapper.__module__ = fn.__module__
-            return wrapper
-
-        return deco
+"""The property tests' one import point for hypothesis."""
+from hypothesis import given, settings, strategies  # noqa: F401

@@ -379,12 +379,6 @@ def test_npz_compressed_envelope_roundtrips_and_counts_bytes(tmp_path):
 
 
 def test_zstd_envelope_gated_or_roundtrips(tmp_path):
-    from repro.core.serialize import _zstd_module
-
-    if _zstd_module() is None:
-        with pytest.raises(ImportError):
-            WeightStore(InMemoryFolder(), compress="zstd")
-        return
     store = WeightStore(InMemoryFolder(), compress="zstd")
     params = {"w": np.zeros((2048,), np.float32)}
     store.push(NodeUpdate(params, num_examples=1, node_id="n", counter=0))

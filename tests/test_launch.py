@@ -154,3 +154,55 @@ def test_roofline_terms_bottleneck():
     assert t["bottleneck"] == "compute_s"
     t2 = C.roofline_terms(total_flops=1e12, total_bytes=1e14, coll_bytes=0.0, chips=256)
     assert t2["bottleneck"] == "memory_s"
+
+
+# ---------------------------------------------------------------------------
+# the training launcher and the compile cache
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("failing,extra,vocab,rc", [
+    (None, [], 50304, 0),                   # the config's own vocab by default
+    ("node1", [], 50304, 1),                # a failed silo fails the run
+    (None, ["--vocab", "512"], 512, 0),     # an explicit cut still applies
+])
+def test_train_simulate_vocab_and_exit_code(monkeypatch, failing, extra, vocab, rc):
+    from repro.launch import train
+
+    seen = {}
+
+    def fake_client(cfg, node_id, index, folder, args, tokens_shard, eval_tokens):
+        seen[node_id] = (cfg.vocab_size, index)
+        if node_id == failing:
+            raise RuntimeError("injected")
+        return {"node": node_id}
+
+    monkeypatch.setattr(train, "run_client", fake_client)
+    monkeypatch.setattr(train, "use_compile_cache", lambda: None)
+    assert train.main(["--arch", "pythia-14m", "--simulate", "2", *extra]) == rc
+    assert seen == {"node0": (vocab, 0), "node1": (vocab, 1)}
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_location(monkeypatch, tmp_path, from_env):
+    from pathlib import Path
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        where = use_compile_cache()
+        if from_env:
+            # JAX reads the variable itself: nothing is set in code
+            assert where == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            repo_root = Path(__file__).resolve().parents[1]
+            assert where == str(repo_root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == where
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -422,3 +422,20 @@ def test_supervisor_cancel_scheduled_kills_lets_client_finish(tmp_path):
         sup.shutdown()
     assert not any(isinstance(t, threading.Timer) and t.is_alive()
                    for t in threading.enumerate())
+
+
+def _jax_platform():
+    import jax
+
+    return os.environ.get("JAX_PLATFORMS"), jax.config.jax_platforms, jax.default_backend()
+
+
+def test_supervised_children_run_jax_on_the_host_cpu(monkeypatch):
+    """On a TPU host a child that reached for the chip would hold it against
+    its siblings; supervised children are pinned to the host CPU, and pass
+    the pin on to the processes they start."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    res = run_multiprocess([_jax_platform, _jax_platform], join_timeout=120.0)
+    for r in res:
+        assert r.error is None, r.traceback
+        assert r.result == ("cpu", "cpu", "cpu")

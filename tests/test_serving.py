@@ -152,6 +152,31 @@ def test_watcher_picks_freshest_and_dedups(smoke_cfg):
     assert dep is not None and dep.source == "a" and dep.counter == 6
 
 
+def test_failed_deploy_is_counted(smoke_cfg, monkeypatch):
+    """A deploy that raises (say, the device runs out of memory while the
+    standby leaves are written) keeps the watcher alive but is counted, so a
+    caller can fail on it instead of serving older weights unawares."""
+    params = build_model(smoke_cfg).init(jax.random.PRNGKey(0))
+    store = WeightStore(InMemoryFolder())
+    _push(store, params, counter=0)
+    node = ServingNode(store, smoke_cfg)
+
+    def out_of_memory(idx, buf):
+        raise RuntimeError("RESOURCE_EXHAUSTED: injected")
+
+    with monkeypatch.context() as m:
+        m.setattr(node, "_materialize_chunked", out_of_memory)
+        assert not node.poll_once()
+    assert node.stats()["deploy_failures"] == 1
+    assert not node.stats()["deployed"]
+
+    _push(store, params, counter=1)
+    assert node.poll_once()
+    stats = node.stats()
+    assert stats["deployed"] and stats["counter"] == 1
+    assert stats["deploy_failures"] == 1
+
+
 def test_serving_node_rejects_encdec():
     with pytest.raises(ValueError, match="decoder-only"):
         ServingNode(WeightStore(InMemoryFolder()), "seamless-m4t-medium",
@@ -161,7 +186,7 @@ def test_serving_node_rejects_encdec():
 def test_stats_keys(smoke_cfg):
     node = ServingNode(WeightStore(InMemoryFolder()), smoke_cfg)
     stats = node.stats()
-    for key in ("deployed", "source", "counter", "swaps", "requests", "tokens",
+    for key in ("deployed", "source", "counter", "swaps", "deploy_failures", "requests", "tokens",
                 "tokens_per_sec", "swap_ms_p50", "swap_ms_p99", "swap_ms_max",
                 "staleness_mean", "staleness_max", "skipped_incompatible"):
         assert key in stats
