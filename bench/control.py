@@ -1,0 +1,108 @@
+#!/usr/bin/env python3
+"""The control and the planted fault that the limits of ``correct`` are set
+against, at a cell's own size, judged by the cell's own limits. The
+benchmark's runs never run this.
+
+    python3 bench/control.py --workload <cell> --seeds 1 2 3
+
+Per seed, with no program run: the float32 reference's first three steps
+against
+
+- the same reference computed in float8 with per-tensor scales (the
+  control, ``reference.lm.fp8``),
+- the reference given half of each batch, the mean taken over the rest (the
+  planted fault),
+
+read with the numbers the driver compares; and, for the control, the
+aggregation of the seed's silos' weights with each update rounded to float8
+first, against the float64 mean.
+
+Prints one JSON line per seed with each number beside its limit. Exits 1
+when the control or the fault passes every limit on some seed: the limits
+could then not tell it from a sound run.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+from pathlib import Path
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+
+from bench import common  # noqa: E402
+
+
+def train_controls(cell, seed: int) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bench.drivers import train
+    from bench.reference import lm as ref
+    from repro.data import make_synthetic_wikitext
+
+    arch = ref.Arch(cell.config)
+    tf = cell.traffic
+    opt = train.optimizer_of(tf)
+    weights = jax.device_get(ref.make_weights(arch, seed))
+    tokens = make_synthetic_wikitext(vocab_size=arch.vocab, seed=seed).train_tokens
+    batches = train.first_batches(tokens, tf, seed)
+    want = ref.train_reference(arch, weights, batches, opt)
+    low = ref.train_reference(arch, weights, batches, opt, low=True)
+    half = ref.train_reference(arch, weights, batches, opt, half_batch=True)
+
+    peers = [jax.device_get(ref.make_weights(arch, seed, salt=i + 1))
+             for i in range(tf["silos"] - 1)]
+    fp8 = jax.jit(ref.fp8)
+    worst = 0.0
+    for path, x in weights.items():
+        parts = [np.asarray(x, np.float64)] + [np.asarray(p[path], np.float64) for p in peers]
+        mean = sum(parts) / len(parts)
+        low_parts = [np.asarray(fp8(jnp.asarray(p, jnp.float32)), np.float64) for p in parts]
+        got = ref.bf16_round((sum(low_parts) / len(low_parts)).astype(np.float32))
+        want_agg = ref.bf16_round(mean.astype(np.float32))
+        worst = max(worst, float(np.linalg.norm(got - want_agg))
+                    / max(float(np.linalg.norm(mean)), 1e-30))
+    return {"control": {**train.compare_steps(low, want), "agg_gap": worst},
+            "half_batch": train.compare_steps(half, want),
+            "reference_losses": want["losses"]}
+
+
+def judge(cell, numbers: dict) -> tuple[bool, dict]:
+    """Whether ``numbers`` fail some limit of the cell, and each beside it."""
+    checks = [common.Check(k, v, cell.limits[k]) for k, v in numbers.items()]
+    return (not all(c.ok for c in checks),
+            {c.name: {"value": c.value, "limit": c.limit, "ok": c.ok} for c in checks})
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    args = ap.parse_args(argv)
+    common.prepare_process()
+    cell = common.load_cell(args.workload)
+    if cell.traffic["driver"] != "train":
+        raise ValueError(f"no control known for driver {cell.traffic['driver']!r}")
+    common.devices(cell.chips)
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
+    passed = []
+    for seed in args.seeds:
+        out = train_controls(cell, seed)
+        line = {"workload": args.workload, "seed": seed,
+                "reference_losses": out["reference_losses"]}
+        for name in ("control", "half_batch"):
+            failed, line[name] = judge(cell, out[name])
+            if not failed:
+                passed.append((seed, name))
+        print(json.dumps(line), flush=True)
+    for seed, name in passed:
+        print(f"control: {name} passed every limit on seed {seed}", file=sys.stderr)
+    return 1 if passed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
