@@ -134,7 +134,6 @@ def connect(
     if telemetry:
         tel = telemetry if isinstance(telemetry, Telemetry) else Telemetry(enabled=True)
         store.attach_telemetry(tel)
-        store.telemetry = tel
 
     if prefetch:
         if isinstance(prefetch, tuple):

@@ -13,6 +13,8 @@ from __future__ import annotations
 from collections import deque
 from typing import Any
 
+import jax
+
 from .node import AsyncFederatedNode, SyncFederatedNode
 from .tree import PyTree
 
@@ -58,11 +60,20 @@ class FederatedCallback(Callback):
             # (one of the two sampling semantics described in the paper).
             self.history.append({"epoch": epoch, "federated": False, "sampled": False})
             return
-        new_params: PyTree | None = self.node.update_parameters(
-            trainer.host_params(), num_examples=self.num_examples_per_epoch, metrics=dict(logs)
-        )
-        if new_params is not None:
-            trainer.set_params(new_params)
+        tel = self.node.telemetry
+        with tel.span("fed_step"):
+            with tel.span("host_params"):
+                params = trainer.host_params()
+            new_params: PyTree | None = self.node.update_parameters(
+                params, num_examples=self.num_examples_per_epoch, metrics=dict(logs)
+            )
+            if new_params is not None:
+                with tel.span("set_params"):
+                    trainer.set_params(new_params)
+                    if tel.enabled:
+                        # the span ends when the placed parameters are ready;
+                        # untraced, the next step's dispatch overlaps the copy
+                        jax.block_until_ready(trainer.params)
         self.history.append(
             {"epoch": epoch, "federated": new_params is not None, "sampled": True}
         )

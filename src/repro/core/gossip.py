@@ -1321,6 +1321,11 @@ class ShardedWeightStore:
         for store in stores:
             store.attach_telemetry(telemetry)
 
+    @property
+    def telemetry(self):
+        """The attached ``Telemetry``, or None."""
+        return self._telemetry
+
     def push_obs(self, node_id: str, seq: int, payload: dict, *,
                  keep: int | None = None) -> None:
         self._store(self.group_of(node_id)).push_obs(

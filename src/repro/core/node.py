@@ -120,10 +120,12 @@ class _BaseNode:
             self.telemetry = Telemetry(enabled=telemetry)
         if not self.telemetry.node_id:
             self.telemetry.node_id = self.node_id
-        if self.telemetry.enabled and self._owns_store:
-            # Only a store this node built is exclusively its own traffic; a
-            # caller-provided store may be shared, and its spans would
-            # conflate nodes.
+        if self.telemetry.enabled and store.telemetry is None:
+            # A store this node built, or one handed over bare (``connect``),
+            # records its codec and folder spans here. A store that already
+            # carries a telemetry may be shared with the node that attached
+            # it, and two nodes' spans in one ring would conflate them, so it
+            # is left alone.
             store.attach_telemetry(self.telemetry)
         self.counter = 0  # local epoch counter; there is no global round
         self._last_state_hash: str | None = None

@@ -736,6 +736,11 @@ class WeightStore:
         ``Telemetry`` instance (put/get/encode/decode spans)."""
         self._ctx.telemetry = telemetry
 
+    @property
+    def telemetry(self):
+        """The attached ``Telemetry``, or None."""
+        return self._ctx.telemetry
+
     def push_obs(self, node_id: str, seq: int, payload: dict, *,
                  keep: int | None = None) -> None:
         """Deposit one telemetry snapshot under ``obs/<node>/<seq>``.

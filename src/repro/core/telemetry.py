@@ -6,23 +6,31 @@ the ``obs_of`` envelope in serialize.py), excluded from ``state_hash`` exactly
 like ``fleet/`` control blobs, and any peer can assemble the fleet-wide
 picture read-only (``python -m repro.obs watch``/``trace``).
 
-Two layers, no dependencies beyond the stdlib:
+Two layers, no dependencies beyond the stdlib (and JAX's profiler where JAX is
+installed):
 
   * ``SpanRecorder`` — a monotonic-clock flight recorder: ``with rec.span("pull")``
-    records ``(name, t0, dur)`` into a bounded ring (old events drop, a counter
-    remembers how many) and folds every span into cumulative per-phase
-    aggregates (count/total/min/max) that never grow.
+    records ``(name, t0, dur, parent, round)`` into a bounded ring (old events
+    drop, a counter remembers how many) and folds every span into cumulative
+    per-phase aggregates (count/total/self/min/max) that never grow. A span
+    opened inside another on the same thread is its child: it records the
+    parent's name and inherits its round, and its time is taken out of the
+    parent's self time.
   * ``Telemetry`` — the per-node aggregator. Nodes, the store context, codecs,
     the trainer, and gossip all call ``tel.span(...)`` / ``tel.observe_staleness``
     through it; every ``flush_every`` rounds ``snapshot()`` packages phase
-    latencies, the staleness distribution (the FedAsync signal), bytes-per-round
-    and chain depth deltas from ``PipelineStats``, prefetch hit rate, trainer
-    throughput, and the drained span ring into one JSON-safe payload for
-    ``WeightStore.push_obs``.
+    latencies, the staleness distribution (the FedAsync signal), transport
+    deltas from ``PipelineStats``, prefetch hit rate, and the drained span ring
+    into one JSON-safe payload for ``WeightStore.push_obs``.
+
+Every recorded span also opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<name>`` (with its parent and round as arguments), so a profiler trace
+shows the program's phases on the host plane, on the device's clock. Outside a
+profiler session an annotation costs one or two microseconds.
 
 When disabled, ``span()`` returns a shared no-op context manager and every
 hook is a single attribute check — instrumented code stays on the hot path
-unconditionally (``BENCH_obs.json`` holds the measured overhead).
+unconditionally. PERF.md records what a span costs enabled and disabled.
 
 Timestamps: spans are recorded on the monotonic clock (immune to NTP steps);
 each ``Telemetry`` notes one ``(time.time(), clock())`` anchor pair at birth
@@ -31,6 +39,7 @@ lets ``chrome_trace`` merge rings from different nodes onto one timeline.
 """
 from __future__ import annotations
 
+import functools
 import os
 import threading
 import time
@@ -70,21 +79,58 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("_recorder", "_name", "_t0")
+@functools.lru_cache(maxsize=None)
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation``, imported on first use; None where
+    JAX is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
-    def __init__(self, recorder: "SpanRecorder", name: str):
+
+class _Span:
+    __slots__ = ("_recorder", "_name", "_round", "_stack", "_parent", "_t0",
+                 "_child_s", "_annotation")
+
+    def __init__(self, recorder: "SpanRecorder", name: str, round_: int):
         self._recorder = recorder
         self._name = name
+        self._round = round_
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._recorder.clock()
+        rec = self._recorder
+        stack = self._stack = rec._open_spans()
+        parent = stack[-1] if stack else None
+        if parent is not None:
+            self._round = parent._round
+        self._parent = parent
+        self._child_s = 0.0
+        stack.append(self)
+        annotation = _trace_annotation()
+        if annotation is not None:
+            args = {"round": self._round}
+            if parent is not None:
+                args["parent"] = parent._name
+            annotation = annotation("repro." + self._name, **args)
+            annotation.__enter__()
+        self._annotation = annotation
+        self._t0 = rec.clock()
         return self
 
     def __exit__(self, *exc: object) -> bool:
         rec = self._recorder
         t0 = self._t0
-        rec.record(self._name, t0, rec.clock() - t0)
+        dur = rec.clock() - t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self._stack.pop()
+        parent = self._parent
+        if parent is not None:
+            parent._child_s += dur
+        rec.record(self._name, t0, dur, parent._name if parent is not None else None,
+                   self._round, dur - self._child_s)
         return False
 
 
@@ -95,7 +141,8 @@ class SpanRecorder:
     ones drop; ``dropped`` counts them), while the per-phase aggregates fold
     every span ever recorded — so latency breakdowns stay exact even when the
     flight recorder wraps. Thread-safe: the node thread, prefetcher thread,
-    and trainer all record into one instance.
+    and trainer all record into one instance; each thread nests its own
+    spans.
     """
 
     def __init__(self, capacity: int = 2048, *, clock: Callable[[], float] = time.perf_counter):
@@ -104,30 +151,45 @@ class SpanRecorder:
         self.dropped = 0
         self.total_recorded = 0
         self._lock = threading.Lock()
-        self._events: deque[tuple[str, float, float]] = deque(maxlen=self.capacity)
+        self._local = threading.local()
+        self._events: deque[tuple[str, float, float, str | None, int]] = deque(
+            maxlen=self.capacity)
         self._phases: dict[str, list[float]] = {}
 
-    def span(self, name: str) -> _Span:
-        return _Span(self, name)
+    def _open_spans(self) -> list[_Span]:
+        """This thread's open spans, innermost last."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
-    def record(self, name: str, t0: float, dur: float) -> None:
+    def span(self, name: str, round_: int = 0) -> _Span:
+        """Time one phase; ``round_`` identifies it unless it opens inside
+        another span, whose round it shares."""
+        return _Span(self, name, round_)
+
+    def record(self, name: str, t0: float, dur: float, parent: str | None, round_: int,
+               self_s: float) -> None:
+        """Record one finished span; ``self_s`` is the part of it that no
+        child span covered."""
         with self._lock:
             if len(self._events) == self.capacity:
                 self.dropped += 1
-            self._events.append((name, t0, dur))
+            self._events.append((name, t0, dur, parent, round_))
             self.total_recorded += 1
             agg = self._phases.get(name)
             if agg is None:
-                self._phases[name] = [1, dur, dur, dur]
+                self._phases[name] = [1, dur, self_s, dur, dur]
             else:
                 agg[0] += 1
                 agg[1] += dur
-                if dur < agg[2]:
-                    agg[2] = dur
-                if dur > agg[3]:
+                agg[2] += self_s
+                if dur < agg[3]:
                     agg[3] = dur
+                if dur > agg[4]:
+                    agg[4] = dur
 
-    def drain(self) -> list[tuple[str, float, float]]:
+    def drain(self) -> list[tuple[str, float, float, str | None, int]]:
         """Pop and return the ring's events (aggregates are untouched)."""
         with self._lock:
             events = list(self._events)
@@ -140,11 +202,12 @@ class SpanRecorder:
                 name: {
                     "count": int(count),
                     "total_s": total,
+                    "self_s": self_total,
                     "mean_s": total / count,
                     "min_s": lo,
                     "max_s": hi,
                 }
-                for name, (count, total, lo, hi) in self._phases.items()
+                for name, (count, total, self_total, lo, hi) in self._phases.items()
             }
 
     def __len__(self) -> int:
@@ -186,8 +249,6 @@ class Telemetry:
         self._stale_sum = 0.0
         self._stale_max = 0.0
         self._stale_recent: deque[float] = deque(maxlen=max(1, int(staleness_window)))
-        self._train_steps = 0
-        self._train_seconds = 0.0
         self._last_transport: dict[str, float] = {}
         self._rounds_at_flush = 0
         self._time_at_flush = time.time()
@@ -198,7 +259,7 @@ class Telemetry:
         """Context manager timing one phase; shared no-op when disabled."""
         if not self.enabled:
             return _NULL_SPAN
-        return self.recorder.span(name)
+        return self.recorder.span(name, self.rounds)
 
     def count(self, name: str, n: float = 1) -> None:
         if not self.enabled:
@@ -217,13 +278,6 @@ class Telemetry:
             if value > self._stale_max:
                 self._stale_max = value
             self._stale_recent.append(value)
-
-    def note_train(self, steps: int, seconds: float) -> None:
-        if not self.enabled:
-            return
-        with self._lock:
-            self._train_steps += int(steps)
-            self._train_seconds += float(seconds)
 
     def end_round(self, *, aggregated: bool) -> None:
         self.rounds += 1
@@ -277,19 +331,18 @@ class Telemetry:
 
         Cumulative signals (phase aggregates, staleness, counters, transport
         stats) carry the full history — readers only need each node's latest
-        blob. The span ring drains here; ``transport_delta`` and the derived
-        bytes-per-round / round rate cover just the window since last flush.
+        blob. The span ring drains here; ``transport_delta`` and the round
+        rate cover just the window since last flush.
         """
         now_unix = time.time()
         transport = dict(transport_stats or {})
         events = self.recorder.drain()
         spans = [
-            [name, self._to_unix_us(t0), int(round(dur * 1e6))]
-            for name, t0, dur in events
+            [name, self._to_unix_us(t0), int(round(dur * 1e6)), parent, round_]
+            for name, t0, dur, parent, round_ in events
         ]
         with self._lock:
             counters = dict(self._counters)
-            train_steps, train_seconds = self._train_steps, self._train_seconds
             last_transport = self._last_transport
             rounds_at_flush = self._rounds_at_flush
             time_at_flush = self._time_at_flush
@@ -311,20 +364,12 @@ class Telemetry:
             "phases": self.recorder.phase_stats(),
             "staleness": self.staleness_stats(),
             "counters": counters,
-            "train": {
-                "steps": train_steps,
-                "seconds": train_seconds,
-                "steps_per_sec": train_steps / train_seconds if train_seconds > 0 else 0.0,
-            },
             "transport": transport,
             "transport_delta": delta,
             "window": {
                 "rounds": window_rounds,
                 "seconds": window_seconds,
                 "rounds_per_sec": window_rounds / window_seconds,
-                "bytes_written_per_round": (
-                    delta.get("bytes_written", 0) / window_rounds if window_rounds else 0.0
-                ),
             },
             "chain_depth": transport.get("chain_depth", 0),
             "prefetch_hit_rate": hits / (hits + misses) if (hits + misses) else 0.0,
@@ -427,9 +472,6 @@ def telemetry_rollups(obs_by_node: dict[str, list[dict[str, Any]]]) -> dict[str,
             "bytes_read": transport.get("bytes_read", 0),
             "chain_depth": last.get("chain_depth", 0),
             "prefetch_hit_rate": round(float(last.get("prefetch_hit_rate", 0.0)), 4),
-            "train_steps_per_sec": round(
-                float((last.get("train") or {}).get("steps_per_sec", 0.0)), 3
-            ),
             "dropped_spans": last.get("dropped_spans", 0),
         }
         if last.get("serve"):
@@ -462,8 +504,8 @@ def chrome_trace(obs_by_node: dict[str, list[dict[str, Any]]]) -> dict[str, Any]
 
     Each node becomes a process (integer pid + a ``process_name`` metadata
     event); spans become ``ph: "X"`` complete events with wall-clock-anchored
-    microsecond timestamps, so Perfetto / chrome://tracing lays the whole
-    fleet on one timeline.
+    microsecond timestamps and each span's parent and round in ``args``, so
+    Perfetto / chrome://tracing lays the whole fleet on one timeline.
     """
     events: list[dict[str, Any]] = []
     for pid, (node_id, payloads) in enumerate(sorted(obs_by_node.items())):
@@ -488,6 +530,7 @@ def chrome_trace(obs_by_node: dict[str, list[dict[str, Any]]]) -> dict[str, Any]
                         "dur": max(0, dur_us),
                         "pid": pid,
                         "tid": 0,
+                        "args": dict(zip(("parent", "round"), span[3:])),
                     }
                 )
     return {"traceEvents": events, "displayTimeUnit": "ms"}

@@ -7,6 +7,7 @@ the same Trainer accepts a Mesh + shardings (see repro.launch.train).
 """
 from __future__ import annotations
 
+import contextlib
 import random as _random
 import time
 from dataclasses import dataclass
@@ -62,9 +63,8 @@ class Trainer:
         heterogeneous hardware.
 
         ``telemetry``: an optional ``repro.core.telemetry.Telemetry`` — each
-        ``run_epoch`` then records a ``train`` span and feeds step throughput
-        into the node's ``obs/`` snapshots. Usually the same instance the
-        federated node carries."""
+        ``run_epoch`` then records a ``train`` span. Usually the same instance
+        the federated node carries."""
         self.optimizer = optimizer
         self.telemetry = telemetry
         self.eval_fn = eval_fn
@@ -102,33 +102,29 @@ class Trainer:
     def run_epoch(self, batches: Iterable, steps: int | None = None) -> dict:
         # Metric values stay on device for the whole epoch: a per-step
         # float(v) would block on each step's result and serialize JAX's
-        # async dispatch. One device_get at the end pays one sync.
+        # async dispatch. One device_get at the end pays one sync, and the
+        # ``train`` span ends with it.
         tel = self.telemetry
-        t_epoch = tel.clock() if tel is not None and tel.enabled else None
         step_metrics: list[dict] = []
         count = 0
-        for i, batch in enumerate(batches):
-            if steps is not None and i >= steps:
-                break
-            self.rng, step_rng = jax.random.split(self.rng)
-            self.params, self.opt_state, metrics = self._train_step(
-                self.params, self.opt_state, batch, step_rng
-            )
-            if self.slowdown:
-                time.sleep(self.slowdown)
-            self.step += 1
-            count += 1
-            step_metrics.append(metrics)
+        with tel.span("train") if tel is not None else contextlib.nullcontext():
+            for i, batch in enumerate(batches):
+                if steps is not None and i >= steps:
+                    break
+                self.rng, step_rng = jax.random.split(self.rng)
+                self.params, self.opt_state, metrics = self._train_step(
+                    self.params, self.opt_state, batch, step_rng
+                )
+                if self.slowdown:
+                    time.sleep(self.slowdown)
+                self.step += 1
+                count += 1
+                step_metrics.append(metrics)
+            step_metrics = jax.device_get(step_metrics)
         metrics_acc: dict[str, float] = {}
-        for metrics in jax.device_get(step_metrics):
+        for metrics in step_metrics:
             for k, v in metrics.items():
                 metrics_acc[k] = metrics_acc.get(k, 0.0) + float(v)
-        if t_epoch is not None:
-            # one span per epoch, recorded after the epoch's single device
-            # sync — no extra mid-epoch host round-trips
-            dur = tel.clock() - t_epoch
-            tel.recorder.record("train", t_epoch, dur)
-            tel.note_train(count, dur)
         return {k: v / max(1, count) for k, v in metrics_acc.items()}
 
     def fit(

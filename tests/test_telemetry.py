@@ -13,6 +13,7 @@ import logging
 import sys
 import threading
 import time
+import uuid
 
 import numpy as np
 import pytest
@@ -36,6 +37,7 @@ from repro.core import (
     serialize_obs_blob,
     telemetry_rollups,
 )
+from repro.api import connect
 from repro.core.telemetry import _NULL_SPAN, env_enabled
 
 
@@ -92,8 +94,41 @@ class TestSpanRecorder:
         span.__enter__()
         t[0] = 2.5
         span.__exit__(None, None, None)
-        (name, t0, dur), = rec.drain()
-        assert (name, t0, dur) == ("x", 0.0, 2.5)
+        (name, t0, dur, parent, round_), = rec.drain()
+        assert (name, t0, dur, parent, round_) == ("x", 0.0, 2.5, None, 0)
+
+
+    def test_self_time_excludes_children(self):
+        t = [0.0]
+        rec = SpanRecorder(capacity=8, clock=lambda: t[0])
+        with rec.span("outer", round_=4):
+            t[0] = 1.0
+            with rec.span("inner", round_=9):  # a child shares its parent's round
+                t[0] = 3.0
+            t[0] = 3.5
+        stats = rec.phase_stats()
+        assert stats["outer"]["total_s"] == 3.5 and stats["outer"]["self_s"] == 1.5
+        assert stats["inner"]["total_s"] == stats["inner"]["self_s"] == 2.0
+        inner, outer = rec.drain()
+        assert inner == ("inner", 1.0, 2.0, "outer", 4)
+        assert outer == ("outer", 0.0, 3.5, None, 4)
+
+    def test_each_thread_nests_its_own_spans(self):
+        rec = SpanRecorder()
+
+        def other():
+            with rec.span("other", round_=2):
+                time.sleep(0.01)
+
+        with rec.span("outer", round_=1):
+            th = threading.Thread(target=other)
+            th.start()
+            th.join(timeout=10)
+        assert not th.is_alive()
+        events = {e[0]: e for e in rec.drain()}
+        assert events["other"][3:] == (None, 2)
+        stats = rec.phase_stats()["outer"]
+        assert stats["self_s"] == stats["total_s"]
 
 
 class TestTelemetry:
@@ -107,6 +142,33 @@ class TestTelemetry:
         tel.observe_staleness(3)
         tel.count("x")
         assert tel.staleness_stats()["count"] == 0
+
+    def test_enabled_span_is_a_profiler_annotation(self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        on, off = Telemetry("n", enabled=True), Telemetry("n", enabled=False)
+        for _ in range(7):
+            on.end_round(aggregated=False)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with on.span("fed_step"):
+                with on.span("push"):
+                    pass
+            with off.span("pull"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        path, = tmp_path.rglob("*.xplane.pb")
+        found = {ev.name: {k: v for k, v in ev.stats}
+                 for plane in ProfileData.from_file(str(path)).planes
+                 for line in plane.lines for ev in line.events
+                 if ev.name.startswith("repro.")}
+        assert found == {"repro.fed_step": {"round": 7},
+                         "repro.push": {"parent": "fed_step", "round": 7}}
 
     def test_env_gating(self, monkeypatch):
         monkeypatch.delenv("REPRO_OBS", raising=False)
@@ -142,8 +204,9 @@ class TestTelemetry:
         assert p0["phases"]["pull"]["count"] == 1
         assert p0["prefetch_hit_rate"] == pytest.approx(0.75)
         assert len(p0["spans"]) == 1
-        name, ts_us, dur_us = p0["spans"][0]
+        name, ts_us, dur_us, parent, round_ = p0["spans"][0]
         assert name == "pull" and isinstance(ts_us, int) and dur_us >= 0
+        assert parent is None and round_ == 0
         # wall-anchored: within a minute of now
         assert abs(ts_us / 1e6 - time.time()) < 60
         tel.end_round(aggregated=False)
@@ -159,7 +222,8 @@ class TestTelemetry:
         with tel.span("push"):
             pass
         tel.observe_staleness(2)
-        tel.note_train(10, 0.5)
+        with tel.span("train"):
+            pass
         tel.end_round(aggregated=True)
         payload = tel.snapshot({"bytes_written": 10})
         json.dumps(payload)  # must not raise
@@ -265,6 +329,56 @@ class TestNodeIntegration:
         # b's telemetry=True default cadence hasn't flushed yet
         assert a.store.pull_obs("b") == []
 
+    @pytest.mark.parametrize("scheme", ["memory://", "shard1+memory://"])
+    def test_store_from_connect_records_codec_spans(self, scheme):
+        uri = f"{scheme}tel-{uuid.uuid4().hex}"
+        tel = Telemetry(enabled=True)
+        store = connect(uri)
+        node = AsyncFederatedNode(store=store, node_id="a", telemetry=tel)
+        assert store.telemetry is tel
+        AsyncFederatedNode(store=connect(uri), node_id="b").update_parameters(
+            _params(seed=1), 1)
+        assert node.update_parameters(_params(), 1) is not None
+        pairs = {(name, parent) for name, _t0, _dur, parent, _round in tel.recorder.drain()}
+        assert {"encode", "put", "get", "decode", "aggregate"} <= {name for name, _ in pairs}
+        assert ("encode", "push") in pairs
+        # the flat store decodes as it pulls; the sharded one as its push
+        # refreshes the group summary, and then pulls from its cache
+        assert {parent for name, parent in pairs if name == "decode"} \
+            == {"pull" if scheme == "memory://" else "gossip.l0.refresh"}
+        # a store that carries another node's telemetry is left alone
+        other = Telemetry(enabled=True)
+        shared = connect(uri, telemetry=other)
+        AsyncFederatedNode(store=shared, node_id="c", telemetry=True)
+        assert shared.telemetry is other
+
+    def test_callback_records_the_federation_step(self):
+        import jax.numpy as jnp
+
+        from repro.optim import get_optimizer
+        from repro.training import Trainer
+
+        uri = f"memory://tel-cb-{uuid.uuid4().hex}"
+        AsyncFederatedNode(store=connect(uri), node_id="peer").update_parameters(
+            {"w": np.ones(4, np.float32)}, 1)
+        tel = Telemetry(enabled=True)
+        node = AsyncFederatedNode(store=connect(uri), node_id="a", telemetry=tel)
+        trainer = Trainer(loss_fn=lambda p, batch, rng: (jnp.sum(p["w"] ** 2), {}),
+                          optimizer=get_optimizer("sgd", 0.1),
+                          init_params={"w": jnp.zeros(4, jnp.float32)})
+        cb = FederatedCallback(node, num_examples_per_epoch=1)
+        trainer.fit([None], epochs=1, callbacks=[cb])
+        events = tel.recorder.drain()
+        children = {name for name, _t0, _dur, parent, _round in events
+                    if parent == "fed_step"}
+        assert children == {"host_params", "push", "pull", "aggregate", "set_params"}
+        (fed_round,) = {r for name, *_, r in events if name == "fed_step"}
+        assert {r for *_, r in events} == {fed_round}
+        stats = tel.recorder.phase_stats()
+        covered = sum(stats[c]["total_s"] for c in children)
+        assert stats["fed_step"]["self_s"] == pytest.approx(
+            stats["fed_step"]["total_s"] - covered, abs=1e-9)
+
     def test_default_is_off_and_costs_nothing(self, monkeypatch):
         monkeypatch.delenv("REPRO_OBS", raising=False)
         node = AsyncFederatedNode(shared_folder=InMemoryFolder(), node_id="n")
@@ -357,6 +471,7 @@ class TestHistoryCap:
     class _StubNode:
         def __init__(self):
             self.store = TestHistoryCap._StubStore()
+            self.telemetry = Telemetry(enabled=False)
 
         def update_parameters(self, params, num_examples, metrics=None):
             return None
@@ -440,13 +555,15 @@ class TestRollups:
                 "time_unix": t,
                 "phases": {"pull": {"count": rounds, "total_s": 0.01 * rounds,
                                     "mean_s": 0.01, "min_s": 0.01,
-                                    "max_s": 0.01}},
+                                    "max_s": 0.01},
+                           "train": {"count": rounds, "total_s": 0.2 * rounds,
+                                     "mean_s": 0.2, "min_s": 0.2,
+                                     "max_s": 0.2}},
                 "staleness": {"count": rounds, "mean": stale_mean,
                               "p50": stale_mean, "p90": stale_mean,
                               "max": stale_mean},
                 "transport": {"bytes_written": 100 * rounds},
                 "window": {"rounds_per_sec": 1.0},
-                "train": {"steps_per_sec": 5.0},
             }
 
         obs = {
@@ -460,6 +577,7 @@ class TestRollups:
         assert roll["nodes"]["a"]["rounds"] == 6
         assert roll["fleet"]["staleness_mean"] == pytest.approx(2.0)
         assert roll["fleet"]["phase_ms"]["pull"] == pytest.approx(10.0)
+        assert roll["nodes"]["b"]["phase_ms"]["train"] == pytest.approx(200.0)
         assert roll["fleet"]["bytes_written"] == 1000
 
     def test_empty_rollups(self):
