@@ -6,18 +6,31 @@ single npz blob: leaves stored under their key-path strings, metadata under a
 reserved ``__meta__`` JSON entry. Key-path keyed storage (instead of pickling
 a treedef) keeps the format language- and process-agnostic — the store really
 could be an S3 bucket written by heterogeneous clients.
+
+Readers of the flat hot path (``decode_params_flat``) read a stored envelope
+(``compress`` 'none', or 'zstd' once its frame is undone) in place: each leaf
+is viewed in the blob's own bytes, its member's CRC-32 checked against the zip
+directory as ``zipfile`` would, and copied once into the flat vector. A
+deflated envelope ('npz') goes through ``np.load``.
 """
 from __future__ import annotations
 
 import hashlib
 import io
 import json
+import os
+import struct
+import threading
+import zipfile
+import zlib
+from concurrent.futures import ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import numpy as np
 import zstandard
+from numpy.lib import format as npy_format
 
 from .tree import LeafSpec, PyTree, path_str
 
@@ -182,11 +195,164 @@ def deserialize_params(blob: bytes) -> tuple[PyTree, dict[str, Any]]:
 # interned in a caller-owned table, so every update a store decodes for the
 # same model shares one spec instance and aggregation can stack flats with an
 # identity check instead of a structural comparison.
+#
+# A stored envelope is read in place (``NpzEnvelope``): each member's extent
+# comes from the zip directory and its array's shape and dtype from the
+# ``.npy`` header, each leaf is a view of the blob's own bytes, and the only
+# pass over the data is one copy into the flat plus the CRC-32 check against
+# the directory that ``zipfile`` makes — the same ``zipfile.BadZipFile`` on a
+# mismatch. Big copies and checks run on a small thread pool (``zlib.crc32``
+# and numpy's copy release the GIL). A deflated envelope, or a member that
+# cannot be viewed in place (encrypted, object, structured or Fortran-ordered),
+# goes through ``np.load`` instead; both readers fill the same flat.
 
 
 class FlatDecodeUnsupported(ValueError):
     """Blob holds leaves a flat f32 vector cannot represent losslessly
     (int/f64 wire arrays) — callers fall back to the per-leaf tree decode."""
+
+
+# zip local file header: signature, 22 bytes of fields the central directory
+# repeats, then the lengths of the name and of the extra field that precede
+# the member's data
+_LOCAL_HEADER = struct.Struct("<4s22xHH")
+_NPY_HEADER_READERS = {(1, 0): npy_format.read_array_header_1_0,
+                       (2, 0): npy_format.read_array_header_2_0}
+_NPY_PREAMBLE_MAX = 1 << 14  # bytes sliced to parse one .npy header
+_INLINE_BYTES = 1 << 20      # smaller checks and copies run on the caller's thread
+_PIECE_BYTES = 1 << 25       # big leaves are copied in pieces of this many bytes of the flat
+
+_pool: ThreadPoolExecutor | None = None
+_pool_lock = threading.Lock()
+
+
+def _decode_pool() -> ThreadPoolExecutor:
+    """The process's decode threads, built on first use: one per usable core,
+    at most 8."""
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            cores = (len(os.sched_getaffinity(0)) if hasattr(os, "sched_getaffinity")
+                     else os.cpu_count() or 1)
+            _pool = ThreadPoolExecutor(max_workers=min(8, cores),
+                                       thread_name_prefix="npz-decode")
+        return _pool
+
+
+class _Member(NamedTuple):
+    """Where one stored member lies in its blob."""
+
+    start: int          # first byte of the member's data (its .npy file)
+    stop: int
+    crc: int            # CRC-32 of blob[start:stop], from the zip directory
+    offset: int         # first byte of the array's payload
+    dtype: np.dtype
+    shape: tuple
+
+
+def _stored_members(blob: bytes) -> dict[str, _Member] | None:
+    """Member key → extent, for an npz whose every member can be viewed in
+    place; None when one cannot, which leaves the blob to ``np.load``. Reads
+    only the zip directory and the .npy headers; raises
+    ``zipfile.BadZipFile`` on a zip that is not whole."""
+    if blob[:4] != b"PK\x03\x04":
+        return None  # not a zip: np.load raises what it always has
+    with zipfile.ZipFile(io.BytesIO(blob)) as zf:
+        infos = zf.infolist()
+    members = {}
+    for zi in infos:
+        if (zi.compress_type != zipfile.ZIP_STORED or zi.flag_bits & 0x1
+                or not zi.filename.endswith(".npy")):
+            return None
+        if zi.header_offset + _LOCAL_HEADER.size > len(blob):
+            raise zipfile.BadZipFile(f"member {zi.filename!r} lies past the end of the blob")
+        magic, name_len, extra_len = _LOCAL_HEADER.unpack_from(blob, zi.header_offset)
+        if magic != b"PK\x03\x04":
+            raise zipfile.BadZipFile(f"bad local header for member {zi.filename!r}")
+        start = zi.header_offset + _LOCAL_HEADER.size + name_len + extra_len
+        stop = start + zi.compress_size
+        if stop > len(blob):
+            raise zipfile.BadZipFile(f"member {zi.filename!r} runs past the end of the blob")
+        preamble = io.BytesIO(blob[start:min(stop, start + _NPY_PREAMBLE_MAX)])
+        try:
+            reader = _NPY_HEADER_READERS.get(npy_format.read_magic(preamble))
+            if reader is None:
+                return None
+            shape, fortran_order, dtype = reader(preamble)
+        except ValueError:
+            return None
+        offset = start + preamble.tell()
+        if (fortran_order or dtype.kind not in "biufc" or dtype.subdtype is not None
+                or offset + int(np.prod(shape)) * dtype.itemsize != stop):
+            return None
+        members[zi.filename[:-len(".npy")]] = _Member(
+            start, stop, zi.CRC, offset, dtype, tuple(shape))
+    return members
+
+
+def _copy_leaf(dst: np.ndarray, src: np.ndarray, scale) -> None:
+    if scale is None:
+        dst[...] = src
+    else:  # int8-quantized leaf
+        np.multiply(src, scale, out=dst, dtype=np.float32, casting="unsafe")
+
+
+def _copy_serial(jobs, _keys) -> None:
+    for dst, src, scale in jobs:
+        _copy_leaf(dst, src, scale)
+
+
+class NpzEnvelope:
+    """One parse of a serialized blob's envelope: the zstd frame undone, the
+    ``__meta__`` entry read, and, for a stored npz, where each member's array
+    lies in the blob (``direct``). ``meta`` is shared: copy before mutating."""
+
+    def __init__(self, blob: bytes):
+        self.blob = maybe_decompress(blob)
+        self._members = _stored_members(self.blob)
+        self.direct = self._members is not None
+        if self.direct:
+            self._check(_META_KEY)
+            self.meta = json.loads(self.view(_META_KEY).tobytes().decode())
+        else:
+            self.meta = peek_meta(self.blob)
+
+    def view(self, key: str) -> np.ndarray:
+        """A read-only view of member ``key``'s array in the blob, not yet
+        checked against its CRC."""
+        m = self._members[key]
+        count = int(np.prod(m.shape))
+        return np.frombuffer(self.blob, m.dtype, count, m.offset).reshape(m.shape)
+
+    def _check(self, key: str) -> None:
+        m = self._members[key]
+        if zlib.crc32(memoryview(self.blob)[m.start:m.stop]) != m.crc:
+            raise zipfile.BadZipFile(f"Bad CRC-32 for file {key}.npy")
+
+    def copy_checked(self, jobs, keys) -> None:
+        """Run ``jobs`` (dst, src view, scale) and check the CRC of every
+        member in ``keys``; big pieces go to the decode pool. A failure is
+        raised once every piece has finished, so nothing writes into a
+        destination after the call returns."""
+        tasks = [(self._members[k].stop - self._members[k].start, self._check, (k,))
+                 for k in keys]
+        for dst, src, scale in jobs:
+            step = _PIECE_BYTES // dst.itemsize
+            for i in range(0, dst.size, step):
+                tasks.append((min(step, dst.size - i) * dst.itemsize, _copy_leaf,
+                              (dst[i:i + step], src[i:i + step], scale)))
+        tasks.sort(key=lambda t: -t[0])
+        pool = _decode_pool()
+        futures = [pool.submit(fn, *args) for nbytes, fn, args in tasks
+                   if nbytes >= _INLINE_BYTES]
+        try:
+            for nbytes, fn, args in tasks:
+                if nbytes < _INLINE_BYTES:
+                    fn(*args)
+        finally:
+            wait(futures)
+        for f in futures:
+            f.result()
 
 
 def _restored_dtype(name: str) -> np.dtype:
@@ -235,67 +401,80 @@ def _wire_keys(spec: LeafSpec) -> tuple[str, ...]:
     return keys
 
 
-def decode_params_flat(blob: bytes, specs: dict) -> tuple[LeafSpec, np.ndarray, dict]:
+def decode_params_flat(blob: "bytes | NpzEnvelope",
+                       specs: dict) -> tuple[LeafSpec, np.ndarray, dict]:
     """Decode a full or quantized npz blob straight into one preallocated flat
-    f32 vector — no nested-dict rebuild. ``specs`` is a caller-owned interning
+    f32 vector — no nested-dict rebuild. ``blob`` may be the bytes or their
+    already-parsed ``NpzEnvelope``. ``specs`` is a caller-owned interning
     table (structure key → LeafSpec); pass the same dict across calls so all
     updates of one model share a spec. Raises ``FlatDecodeUnsupported`` for
-    blobs whose wire arrays don't embed losslessly in f32."""
-    with np.load(io.BytesIO(maybe_decompress(blob))) as data:
-        meta = json.loads(bytes(data[_META_KEY].tobytes()).decode())
-        order = meta.pop("__order__")
-        dtypes = meta.pop("__dtypes__", {})
-        if "delta_of" in meta:
-            raise ValueError("delta blob: use deserialize_update_delta_flat")
-        quantized = bool(meta.get("quantized"))
-        if quantized:
-            # order lists the packed {"q":..., "s":...} tree; the spec
-            # describes the original structure (q-keys with prefix stripped)
-            leaf_keys = [k[2:] for k in order if k.startswith("q" + _SEP)]
-            arrays = {k: data["q" + _SEP + k] for k in leaf_keys}
+    blobs whose wire arrays don't embed losslessly in f32, and
+    ``zipfile.BadZipFile`` for a torn or corrupted blob."""
+    env = blob if isinstance(blob, NpzEnvelope) else NpzEnvelope(blob)
+    if env.direct:
+        return _flat_from_arrays(env.meta, env.view, env.copy_checked, specs)
+    with np.load(io.BytesIO(env.blob)) as data:
+        return _flat_from_arrays(env.meta, data.__getitem__, _copy_serial, specs)
+
+
+def _flat_from_arrays(meta: dict, get, copy, specs: dict) -> tuple[LeafSpec, np.ndarray, dict]:
+    """The body of ``decode_params_flat`` over either reader: ``get(key)``
+    gives a member's array, ``copy(jobs, keys)`` fills the flat from the
+    (dst, src, scale) jobs, checking the members named by ``keys`` where the
+    reader has not already."""
+    meta = dict(meta)
+    order = meta.pop("__order__")
+    dtypes = meta.pop("__dtypes__", {})
+    if "delta_of" in meta:
+        raise ValueError("delta blob: use deserialize_update_delta_flat")
+    quantized = bool(meta.get("quantized"))
+    if quantized:
+        # order lists the packed {"q":..., "s":...} tree; the spec
+        # describes the original structure (q-keys with prefix stripped)
+        leaf_keys = [k[2:] for k in order if k.startswith("q" + _SEP)]
+        arrays = {k: get("q" + _SEP + k) for k in leaf_keys}
+        scales = {k: np.float32(get("s" + _SEP + k)) for k in leaf_keys}
+        read = [p + _SEP + k for k in leaf_keys for p in "qs"]
+    else:
+        leaf_keys = list(order)
+        arrays = {k: get(k) for k in leaf_keys}
+        scales = {}
+        read = leaf_keys
+        for k, a in arrays.items():
+            if a.dtype.kind != "f" or a.dtype.itemsize > 4:
+                raise FlatDecodeUnsupported(
+                    f"leaf {k!r} has wire dtype {a.dtype} (not f32-exact)")
+    wire_dtypes = () if quantized else tuple(arrays[k].dtype.str for k in leaf_keys)
+    skey = _spec_table_key(order, dtypes, quantized, wire_dtypes)
+    spec = specs.get(skey)
+    drifted = False
+    if spec is not None:
+        # verify shapes still match the interned layout; drift → rebuild
+        # (dtypes are part of the table key, so only shapes can drift)
+        wire = _wire_keys(spec)
+        if len(wire) != len(leaf_keys) or any(
+            tuple(arrays[k].shape) != spec.shapes[spec.index[k.replace(_SEP, "/")]]
+            for k in leaf_keys
+        ):
+            spec, drifted = None, True
+    if spec is None:
+        shapes_by_key = {k: (tuple(a.shape), a.dtype) for k, a in arrays.items()}
+        spec = _build_wire_spec(order, dtypes, shapes_by_key, quantized)
+        if drifted:
+            specs[skey] = spec  # replace the stale layout
         else:
-            leaf_keys = list(order)
-            arrays = {k: data[k] for k in leaf_keys}
-            for k, a in arrays.items():
-                if a.dtype.kind != "f" or a.dtype.itemsize > 4:
-                    raise FlatDecodeUnsupported(
-                        f"leaf {k!r} has wire dtype {a.dtype} (not f32-exact)")
-        wire_dtypes = () if quantized else tuple(arrays[k].dtype.str for k in leaf_keys)
-        skey = _spec_table_key(order, dtypes, quantized, wire_dtypes)
-        spec = specs.get(skey)
-        drifted = False
-        if spec is not None:
-            # verify shapes still match the interned layout; drift → rebuild
-            # (dtypes are part of the table key, so only shapes can drift)
-            wire = _wire_keys(spec)
-            if len(wire) != len(leaf_keys) or any(
-                tuple(arrays[k].shape) != spec.shapes[spec.index[k.replace(_SEP, "/")]]
-                for k in leaf_keys
-            ):
-                spec, drifted = None, True
-        if spec is None:
-            shapes_by_key = {k: (tuple(a.shape), a.dtype) for k, a in arrays.items()}
-            spec = _build_wire_spec(order, dtypes, shapes_by_key, quantized)
-            if drifted:
-                specs[skey] = spec  # replace the stale layout
-            else:
-                # setdefault: a concurrent decode (the prefetch thread racing
-                # a pull) must not intern two spec instances for one structure
-                # — spec identity is what makes the stack cache zero-copy
-                spec = specs.setdefault(skey, spec)
-        flat = spec.empty_flat()
-        index, offsets, sizes = spec.index, spec.offsets, spec.sizes
-        if quantized:
-            for k in leaf_keys:
-                i = index[k.replace(_SEP, "/")]
-                o, n = offsets[i], sizes[i]
-                np.multiply(arrays[k].reshape(-1), np.float32(data["s" + _SEP + k]),
-                            out=flat[o:o + n], dtype=np.float32, casting="unsafe")
-        else:
-            for k in leaf_keys:
-                i = index[k.replace(_SEP, "/")]
-                o, n = offsets[i], sizes[i]
-                flat[o:o + n] = arrays[k].reshape(-1)
+            # setdefault: a concurrent decode (the prefetch thread racing
+            # a pull) must not intern two spec instances for one structure
+            # — spec identity is what makes the stack cache zero-copy
+            spec = specs.setdefault(skey, spec)
+    flat = spec.empty_flat()
+    index, offsets, sizes = spec.index, spec.offsets, spec.sizes
+    jobs = []
+    for k in leaf_keys:
+        i = index[k.replace(_SEP, "/")]
+        o, n = offsets[i], sizes[i]
+        jobs.append((flat[o:o + n], arrays[k].reshape(-1), scales.get(k)))
+    copy(jobs, read)
     return spec, flat, meta
 
 

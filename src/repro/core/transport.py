@@ -81,6 +81,7 @@ import numpy as np
 from .serialize import (
     FlatDecodeUnsupported,
     NodeUpdate,
+    NpzEnvelope,
     apply_update_delta_flat,
     canonicalize_params,
     content_hash,
@@ -468,6 +469,10 @@ class PipelineStats:
         # folder's listing token was unchanged and the parsed summary index
         # was reused without re-splitting every key
         "summary_index_hits", "summary_index_misses",
+        # flat decodes of full/quantized blobs (the delta codec's bases
+        # included) by reader: read in place from a stored envelope, or
+        # through np.load (deflated, or a member not viewable in place)
+        "direct_decodes", "npz_decodes",
     )
     _FLOAT_FIELDS = ("residual_norm", "topk_fraction_effective")
 
@@ -624,6 +629,14 @@ class Codec:
         """Drop per-node writer state (store.clear)."""
 
 
+def _decode_flat(env: NpzEnvelope, ctx: StoreContext):
+    """``decode_params_flat`` on ``ctx``'s spec table, counted by the reader
+    the blob's envelope took."""
+    out = decode_params_flat(env, ctx.specs)
+    ctx.stats.incr("direct_decodes" if env.direct else "npz_decodes")
+    return out
+
+
 class FullCodec(Codec):
     name = "full"
 
@@ -633,12 +646,12 @@ class FullCodec(Codec):
         return blob, False
 
     @staticmethod
-    def decode_wire(blob: bytes, meta: dict, ctx: StoreContext) -> NodeUpdate:
+    def decode_wire(env: NpzEnvelope, ctx: StoreContext) -> NodeUpdate:
         try:
-            spec, flat, m = decode_params_flat(blob, ctx.specs)
+            spec, flat, m = _decode_flat(env, ctx)
             return flat_update_from_meta(spec, flat, m)
         except FlatDecodeUnsupported:
-            return deserialize_update(blob)
+            return deserialize_update(env.blob)
 
 
 class QuantizedCodec(Codec):
@@ -650,12 +663,12 @@ class QuantizedCodec(Codec):
         return blob, False
 
     @staticmethod
-    def decode_wire(blob: bytes, meta: dict, ctx: StoreContext) -> NodeUpdate:
+    def decode_wire(env: NpzEnvelope, ctx: StoreContext) -> NodeUpdate:
         try:
-            spec, flat, m = decode_params_flat(blob, ctx.specs)
+            spec, flat, m = _decode_flat(env, ctx)
             return flat_update_from_meta(spec, flat, m)
         except FlatDecodeUnsupported:
-            return deserialize_update_quantized(blob)
+            return deserialize_update_quantized(env.blob)
 
 
 class _ChainState:
@@ -836,12 +849,12 @@ class DeltaCodec(Codec):
             raw = ctx.get(f"base/{node_id}/{cur}")
             # hash the RAW fetched bytes — writers hash what they deposit
             if raw is not None and content_hash(raw) == cur:
-                blob = maybe_decompress(raw)
+                env = NpzEnvelope(raw)
                 try:
-                    spec, flat, _ = decode_params_flat(blob, ctx.specs)
+                    spec, flat, _ = _decode_flat(env, ctx)
                     state = (spec, flat)
                 except FlatDecodeUnsupported:
-                    state = (None, deserialize_update(blob).params)
+                    state = (None, deserialize_update(env.blob).params)
                 ctx.decoded_bases.put(cur, state)
                 break
             raw = ctx.get(f"chain/{node_id}/{cur}")
@@ -1208,21 +1221,20 @@ class TransportPipeline:
         return self._decode(blob, node_id, ctx)
 
     def _decode(self, blob: bytes, node_id: str, ctx: StoreContext) -> NodeUpdate | None:
-        raw = blob
-        # Decompress exactly once up front: peek_meta and every decode below
-        # call maybe_decompress themselves, which is a no-op on raw npz bytes
-        # but a full second (or third) zstd pass on a still-wrapped blob.
-        blob = maybe_decompress(blob)
-        meta = peek_meta(blob)
+        # One parse of the envelope up front: it undoes the zstd frame once
+        # (every reader below would otherwise decompress again) and reads
+        # the zip directory and meta that the flat decode reuses.
+        env = NpzEnvelope(blob)
+        meta = env.meta
         if meta.get("delta_of"):
             # content-hash the raw bytes only for deltas: a chain link's
             # successor references this blob's hash (full blobs are big and
             # never referenced by latest-hash — their identity is base/<h>)
-            return DeltaCodec.decode_wire(blob, meta, ctx, node_id,
-                                          raw_hash=content_hash(raw))
+            return DeltaCodec.decode_wire(env.blob, meta, ctx, node_id,
+                                          raw_hash=content_hash(blob))
         if meta.get("quantized"):
-            return QuantizedCodec.decode_wire(blob, meta, ctx)
-        return FullCodec.decode_wire(blob, meta, ctx)
+            return QuantizedCodec.decode_wire(env, ctx)
+        return FullCodec.decode_wire(env, ctx)
 
     def reset(self) -> None:
         self.policy.reset()

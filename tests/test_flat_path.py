@@ -23,6 +23,7 @@ from repro.core import (
     WeightStore,
 )
 from repro.core.serialize import (
+    NpzEnvelope,
     content_hash,
     decode_params_flat,
     deserialize_update,
@@ -30,6 +31,7 @@ from repro.core.serialize import (
     deserialize_update_quantized,
     peek_meta,
     serialize_update,
+    serialize_update_quantized,
 )
 from repro.core.strategies import STRATEGIES, FedAvg, FedAvgM, get_strategy
 from repro.core.strategies_ref import REF_STRATEGIES, get_ref_strategy
@@ -508,3 +510,163 @@ def test_fedavgm_state_is_flat_vectors():
     assert isinstance(strat.x, np.ndarray) and strat.x.ndim == 1
     assert strat.x.size == spec.num_params
     assert isinstance(strat.buf, np.ndarray) and strat.buf.dtype == np.float32
+
+
+# --- the in-place reader of stored envelopes ----------------------------------
+
+
+def _bf16_tree():
+    import ml_dtypes
+
+    return {"w": np.linspace(-3, 3, 24, dtype=np.float32).reshape(4, 6)
+                 .astype(ml_dtypes.bfloat16),
+            "b": np.arange(6, dtype=np.float32).astype(ml_dtypes.bfloat16)}
+
+
+# name -> (blob writer taking compress=, whether the stored blob is read in place)
+READER_CASES = {
+    "f32": (lambda c: serialize_update(NodeUpdate(tree_of([1.5, -0.5]), 3, "n"),
+                                       compress=c), True),
+    "bf16_widened": (lambda c: serialize_update(NodeUpdate(_bf16_tree(), 3, "n"),
+                                                compress=c), True),
+    "f16": (lambda c: serialize_update(NodeUpdate(
+        {"w": np.linspace(-2, 2, 30, dtype=np.float16).reshape(5, 6)}, 3, "n"),
+        compress=c), True),
+    "quantized": (lambda c: serialize_update_quantized(
+        NodeUpdate(tree_of([2.0, 1.0]), 3, "n"), compress=c), True),
+    "zstd": (lambda c: serialize_update(NodeUpdate(tree_of([0.25, 2.0]), 3, "n"),
+                                        compress="zstd" if c == "none" else c), True),
+    "node_id_slash_dunder": (lambda c: serialize_update(
+        NodeUpdate(tree_of([1.0, 1.0]), 3, "site/a__b"), compress=c), True),
+    # above the inline and piece sizes: pool tasks and a leaf cut in pieces
+    "big_leaf": (lambda c: serialize_update(NodeUpdate(
+        {"big": np.arange(9 * 2**20, dtype=np.float32) * np.float32(1e-3),
+         "small": np.ones((3,), np.float32)}, 3, "n"), compress=c), True),
+    "fortran_leaf": (lambda c: serialize_update(NodeUpdate(
+        {"w": np.asfortranarray(np.arange(12, dtype=np.float32).reshape(3, 4))},
+        3, "n"), compress=c), False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(READER_CASES))
+def test_direct_reader_matches_np_load_bitwise(case):
+    """A stored blob read in place and the same update deflated (read by
+    np.load) give bitwise-equal flats, the same spec and the same meta."""
+    write, direct = READER_CASES[case]
+    stored, deflated = NpzEnvelope(write("none")), NpzEnvelope(write("npz"))
+    assert stored.direct is direct and deflated.direct is False
+    spec_a, flat_a, meta_a = decode_params_flat(stored, {})
+    spec_b, flat_b, meta_b = decode_params_flat(deflated, {})
+    assert flat_a.dtype == flat_b.dtype == np.float32
+    assert flat_a.view(np.uint32).tobytes() == flat_b.view(np.uint32).tobytes()
+    assert (spec_a.key, spec_a.paths, spec_a.shapes, spec_a.dtypes) == \
+        (spec_b.key, spec_b.paths, spec_b.shapes, spec_b.dtypes)
+    assert meta_a == meta_b
+
+
+def _flip_inside(blob: bytes, payload: bytes) -> bytes:
+    at = blob.find(payload)
+    assert at >= 0 and blob.find(payload, at + 1) < 0
+    at += len(payload) // 2
+    return blob[:at] + bytes([blob[at] ^ 0x40]) + blob[at + 1:]
+
+
+@pytest.mark.parametrize("where", ["small_leaf", "big_leaf", "meta"])
+def test_direct_reader_rejects_a_flipped_byte(where):
+    import zipfile
+
+    big = np.arange(2**19, dtype=np.float32) + np.float32(0.5)  # 2 MiB: the pool's
+    small = np.asarray([7.25, -1.5, 3.0], np.float32)
+    blob = serialize_update(NodeUpdate({"big": big, "small": small}, 3, "node-x"))
+    payload = {"small_leaf": small.tobytes(), "big_leaf": big.tobytes(),
+               "meta": b'"node-x"'}[where]
+    with pytest.raises(zipfile.BadZipFile):
+        decode_params_flat(_flip_inside(blob, payload), {})
+
+
+def test_direct_reader_rejects_a_truncated_blob():
+    import zipfile
+
+    blob = serialize_update(NodeUpdate(
+        {"w": np.arange(4096, dtype=np.float32)}, 3, "n"))
+    cut = blob.find(np.arange(4096, dtype=np.float32).tobytes()) + 1000
+    with pytest.raises(zipfile.BadZipFile):
+        decode_params_flat(blob[:cut], {})
+
+
+def test_non_zip_bytes_raise_as_np_load_does():
+    with pytest.raises(ValueError):
+        decode_params_flat(b"neither a zip nor an array", {})
+
+
+def test_direct_reader_int_leaves_still_unsupported():
+    from repro.core.serialize import FlatDecodeUnsupported
+
+    blob = serialize_update(NodeUpdate(
+        {"ids": np.asarray([2**40 + 3, 7], np.int64),
+         "w": np.ones((2,), np.float32)}, 1, "n"))
+    env = NpzEnvelope(blob)
+    assert env.direct
+    with pytest.raises(FlatDecodeUnsupported):
+        decode_params_flat(env, {})
+
+
+@pytest.mark.parametrize("transport,compress", [
+    ("full", "none"), ("full", "zstd"), ("quantized", "none"), ("delta", "none"),
+    ("full", "npz"),
+])
+def test_store_pull_counts_decodes_by_reader(transport, compress):
+    """Every flat decode a store makes is counted once, by the reader its
+    blob's envelope took: in place for stored envelopes, np.load for
+    deflated ones."""
+    from repro.api import connect
+
+    uri = f"memory://flat-reader-{transport}-{compress}"
+    writer = connect(uri, transport=transport, compress=compress)
+    reader = connect(uri, transport=transport, compress=compress)
+    for ctr in range(2):
+        for node in ("a", "b"):
+            writer.push(NodeUpdate(tree_of([1.0 + ctr, 0.5]), num_examples=1,
+                                   node_id=node, counter=ctr))
+        assert len(reader.pull()) == 2
+    stats = reader.transport_stats()
+    counted, silent = (("npz_decodes", "direct_decodes") if compress == "npz"
+                       else ("direct_decodes", "npz_decodes"))
+    assert stats[silent] == 0
+    assert stats[counted] >= stats["decodes"] == 4
+
+
+def test_concurrent_direct_decodes_share_the_pool():
+    """Many threads decoding at once through the one decode pool each get
+    their own complete, correct flat."""
+    import sys
+    import threading
+
+    tree = {"a": np.arange(2**19, dtype=np.float32),
+            "b": -np.arange(3 * 2**18, dtype=np.float32),
+            "c": np.ones((5,), np.float32)}
+    blob = serialize_update(NodeUpdate(tree, 1, "n"))
+    _spec, want, _meta = decode_params_flat(blob, {})
+    results, errors = [], []
+
+    def work():
+        try:
+            for _ in range(4):
+                results.append(decode_params_flat(blob, {})[1])
+        except Exception as e:  # noqa: BLE001 - reported by the assertion
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and not errors
+    assert len(results) == 48
+    for flat in results:
+        np.testing.assert_array_equal(flat, want)
