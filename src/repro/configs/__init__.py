@@ -1,6 +1,6 @@
 """Architecture config registry: ``get_config("<arch-id>")``.
 
-The 10 assigned architectures (public-literature pool) plus the paper's own
+The assigned architectures (public-literature pool) plus the paper's own
 experiment models. Every config cites its source in the module docstring and
 ``ModelConfig.source``.
 """
@@ -21,6 +21,7 @@ ARCH_IDS = [
     "granite-3-2b",
     "seamless-m4t-medium",
     "qwen3-14b",
+    "moonlight-16b-a3b",
 ]
 
 PAPER_IDS = ["pythia-14m"]

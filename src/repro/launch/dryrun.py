@@ -213,7 +213,7 @@ def main(argv=None) -> int:
     ap.add_argument("--no-remat", action="store_true")
     ap.add_argument("--microbatches", type=int, default=8)
     ap.add_argument("--set", action="append", default=[],
-                    help="config override key=value (repeatable), e.g. --set moe_dispatch=gather")
+                    help="config override key=value (repeatable), e.g. --set remat_policy=dots")
     ap.add_argument("--tag", default="", help="label for §Perf iteration records")
     ap.add_argument("--out", default=None, help="append JSONL records here")
     args = ap.parse_args(argv)

@@ -290,11 +290,16 @@ def init_mla(rng, cfg: ModelConfig) -> dict:
     dt = cfg.jdtype
     ks = jax.random.split(rng, 8)
     qk_dim = cfg.qk_nope_dim + cfg.qk_rope_dim
-    p = {
+    if cfg.q_lora_rank:
         # query path: down-project → norm → up-project to per-head (nope+rope)
-        "wq_a": dense_init(ks[0], cfg.d_model, cfg.q_lora_rank, dt),
-        "q_a_norm": rmsnorm_init(cfg.q_lora_rank, dt),
-        "wq_b": dense_init(ks[1], cfg.q_lora_rank, cfg.n_heads * qk_dim, dt),
+        p = {
+            "wq_a": dense_init(ks[0], cfg.d_model, cfg.q_lora_rank, dt),
+            "q_a_norm": rmsnorm_init(cfg.q_lora_rank, dt),
+            "wq_b": dense_init(ks[1], cfg.q_lora_rank, cfg.n_heads * qk_dim, dt),
+        }
+    else:  # no low-rank query path (DeepSeek-V2-Lite, Moonlight): one projection
+        p = {"wq": dense_init(ks[0], cfg.d_model, cfg.n_heads * qk_dim, dt)}
+    p.update({
         # kv path: shared compressed latent + shared rope key
         "wkv_a": dense_init(ks[2], cfg.d_model, cfg.kv_lora_rank, dt),
         "kv_a_norm": rmsnorm_init(cfg.kv_lora_rank, dt),
@@ -302,15 +307,19 @@ def init_mla(rng, cfg: ModelConfig) -> dict:
         "wk_b": dense_init(ks[4], cfg.kv_lora_rank, cfg.n_heads * cfg.qk_nope_dim, dt),
         "wv_b": dense_init(ks[5], cfg.kv_lora_rank, cfg.n_heads * cfg.v_head_dim, dt),
         "wo": dense_init(ks[6], cfg.n_heads * cfg.v_head_dim, cfg.d_model, dt),
-    }
+    })
     return p
 
 
 def _mla_q(p, cfg, x, positions):
     B, S, _ = x.shape
     qk_dim = cfg.qk_nope_dim + cfg.qk_rope_dim
-    q_lat = rmsnorm_apply(p["q_a_norm"], dense_apply(p["wq_a"], x), cfg.norm_eps)
-    q = dense_apply(p["wq_b"], q_lat).reshape(B, S, cfg.n_heads, qk_dim)
+    if "wq" in p:
+        q = dense_apply(p["wq"], x)
+    else:
+        q = dense_apply(p["wq_b"], rmsnorm_apply(p["q_a_norm"], dense_apply(p["wq_a"], x),
+                                                 cfg.norm_eps))
+    q = q.reshape(B, S, cfg.n_heads, qk_dim)
     q_nope, q_rope = q[..., : cfg.qk_nope_dim], q[..., cfg.qk_nope_dim :]
     q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
     return q_nope, q_rope
@@ -364,8 +373,13 @@ def mla_full_absorbed(p: dict, cfg: ModelConfig, x: jnp.ndarray, *, window: int 
 
 
 def mla_full(p: dict, cfg: ModelConfig, x: jnp.ndarray, *, window: int | None = None) -> jnp.ndarray:
-    if cfg.mla_absorb:
-        return mla_full_absorbed(p, cfg, x, window=window)
+    with jax.named_scope("mla"):
+        if cfg.mla_absorb:
+            return mla_full_absorbed(p, cfg, x, window=window)
+        return _mla_full_naive(p, cfg, x, window=window)
+
+
+def _mla_full_naive(p: dict, cfg: ModelConfig, x: jnp.ndarray, *, window: int | None) -> jnp.ndarray:
     B, S, _ = x.shape
     window = cfg.sliding_window if window is None else window
     positions = jnp.arange(S)[None, :]

@@ -49,13 +49,19 @@ class ModelConfig:
     # activation
     activation: str = "swiglu"  # swiglu | geglu | gelu
 
-    # MoE
+    # MoE (models/moe.py): a router over num_experts, of which the layer holds
+    # experts [first_expert, first_expert + experts_held) (0 → all of them)
     num_experts: int = 0
     experts_per_token: int = 0
-    moe_capacity_factor: float = 1.25
+    experts_held: int = 0
+    first_expert: int = 0
+    moe_d_ff: int = 0          # routed and shared experts' width (0 → d_ff)
     num_shared_experts: int = 0
+    router_score: str = "softmax"  # softmax | sigmoid (DeepSeek-V3: selection bias,
+                                   # sequence-wise balance loss)
+    routed_scaling: float = 1.0    # factor on the normalised top-k weights
     router_aux_weight: float = 0.01
-    moe_dispatch: str = "einsum"  # einsum (GShard-style baseline) | gather (§Perf H1)
+    first_dense_layers: int = 0    # leading dense layers before the MoE stack
 
     # MLA (MiniCPM3 / DeepSeek-style)
     q_lora_rank: int = 0
@@ -88,6 +94,7 @@ class ModelConfig:
                                 # cuts remat recompute FLOPs for more memory)
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
+    embed_scale: float = 0.0   # embedding multiplier (0 → sqrt(d_model))
     vocab_pad_to: int = 256
     dtype: str = "bfloat16"
     # long-context decode support: dense archs flip this on for long_500k
@@ -116,6 +123,11 @@ class ModelConfig:
         return ("moe_attn",) if self.num_experts else ("attn",)
 
     @property
+    def held_experts(self) -> tuple[int, int]:
+        """(first, count) of the routed experts this layer holds."""
+        return self.first_expert, self.experts_held or self.num_experts
+
+    @property
     def jdtype(self):
         return jnp.dtype(self.dtype)
 
@@ -123,13 +135,16 @@ class ModelConfig:
         return dataclasses.replace(self, **kw)
 
     def reduced(self) -> "ModelConfig":
-        """Smoke-test variant: ≤2 pattern units of layers, d_model ≤ 256, ≤4 experts."""
+        """Smoke-test variant: ≤1 leading dense layer and ≤2 pattern units of
+        layers, d_model ≤ 256, ≤4 experts (all held)."""
         unit = len(self.pattern)
         d_model = min(self.d_model, 256)
         n_heads = min(self.n_heads, 4)
+        first_dense = min(self.first_dense_layers, 1)
         return self.replace(
             name=self.name + "-reduced",
-            n_layers=min(self.n_layers, 2 * unit),
+            n_layers=min(self.n_layers, first_dense + 2 * unit),
+            first_dense_layers=first_dense,
             encoder_layers=min(self.encoder_layers, 2) if self.encoder_layers else 0,
             d_model=d_model,
             n_heads=n_heads,
@@ -139,6 +154,9 @@ class ModelConfig:
             vocab_size=min(self.vocab_size, 512),
             num_experts=min(self.num_experts, 4) if self.num_experts else 0,
             experts_per_token=min(self.experts_per_token, 2) if self.experts_per_token else 0,
+            experts_held=0,
+            first_expert=0,
+            moe_d_ff=min(self.moe_d_ff, 128) if self.moe_d_ff else 0,
             q_lora_rank=min(self.q_lora_rank, 64) if self.q_lora_rank else 0,
             kv_lora_rank=min(self.kv_lora_rank, 32) if self.kv_lora_rank else 0,
             ssm_state=min(self.ssm_state, 32) if self.ssm_state else 0,

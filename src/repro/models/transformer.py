@@ -3,12 +3,15 @@
 Layers are grouped by the config's repeating ``pattern`` (e.g. RecurrentGemma's
 ("rglru","rglru","attn")); each pattern position has its params stacked over a
 leading group axis and the whole stack is consumed by one ``jax.lax.scan`` —
-a 64-layer grok-1 lowers to a single compact scanned HLO body. A remainder
-(n_layers % len(pattern)) is applied unstacked as a tail.
+a 64-layer grok-1 lowers to a single compact scanned HLO body. Leading dense
+layers (``first_dense_layers``, DeepSeek-V3's ``first_k_dense_replace``) are
+applied unstacked before the scan as a head, and a remainder
+((n_layers − first_dense_layers) % len(pattern)) after it as a tail.
 
 Block types:
   attn      — pre-norm attention (GQA or MLA) + pre-norm MLP
-  moe_attn  — pre-norm attention + pre-norm MoE (aux loss accumulated)
+  moe_attn  — pre-norm attention (GQA or MLA) + pre-norm MoE (aux loss and
+              routing counters accumulated)
   ssm       — pre-norm Mamba2 SSD block (no separate MLP)
   rglru     — pre-norm RG-LRU recurrent block + pre-norm MLP
 
@@ -45,10 +48,28 @@ from .common import (
 # ---------------------------------------------------------------------------
 
 
+# Routing counters of the MoE blocks, accumulated over the layers of a
+# forward pass: summed, except the load ratio, which keeps the largest layer's.
+_SUMMED = ("aux", "moe_rows", "moe_rows_alloc")
+MOE_STATS = _SUMMED + ("moe_load_max",)
+
+
+def _zero_stats() -> dict:
+    return {k: jnp.zeros((), jnp.float32) for k in MOE_STATS}
+
+
+def _add_stats(total: dict, stats: dict) -> dict:
+    if not stats:
+        return total
+    out = {k: total[k] + stats[k] for k in _SUMMED}
+    out["moe_load_max"] = jnp.maximum(total["moe_load_max"], stats["moe_load_max"])
+    return out
+
+
 def _init_block(rng, cfg: ModelConfig, kind: str) -> dict:
     k1, k2, k3 = jax.random.split(rng, 3)
+    attn_init = attn_mod.init_mla if cfg.attention == "mla" else attn_mod.init_attention
     if kind == "attn":
-        attn_init = attn_mod.init_mla if cfg.attention == "mla" else attn_mod.init_attention
         return {
             "norm1": rmsnorm_init(cfg.d_model, cfg.jdtype),
             "attn": attn_init(k1, cfg),
@@ -58,7 +79,7 @@ def _init_block(rng, cfg: ModelConfig, kind: str) -> dict:
     if kind == "moe_attn":
         return {
             "norm1": rmsnorm_init(cfg.d_model, cfg.jdtype),
-            "attn": attn_mod.init_attention(k1, cfg),
+            "attn": attn_init(k1, cfg),
             "norm2": rmsnorm_init(cfg.d_model, cfg.jdtype),
             "moe": moe_mod.init_moe(k2, cfg),
         }
@@ -78,29 +99,28 @@ def _init_block(rng, cfg: ModelConfig, kind: str) -> dict:
 
 
 def _apply_block_full(p, cfg: ModelConfig, kind: str, x, *, window_override=None, use_flash=False):
-    """Full-sequence forward. Returns (x, aux)."""
-    aux = jnp.zeros((), jnp.float32)
+    """Full-sequence forward. Returns (x, stats, route): an MoE block's
+    routing counters and chosen experts (B·S, k); ({}, None) for others."""
     if kind in ("attn", "moe_attn"):
         h = rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
-        if cfg.attention == "mla" and kind == "attn":
+        if cfg.attention == "mla":
             a = attn_mod.mla_full(p["attn"], cfg, h, window=window_override)
         else:
             a = attn_mod.attn_full(p["attn"], cfg, h, window=window_override, use_flash=use_flash)
         x = x + a
         h = rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
         if kind == "moe_attn":
-            out, aux = moe_mod.moe_apply(p["moe"], cfg, h)
-        else:
-            out = mlp_apply(p["mlp"], h, cfg.activation)
-        return x + out, aux
+            out, stats, route = moe_mod.moe_apply(p["moe"], cfg, h)
+            return x + out, stats, route
+        return x + mlp_apply(p["mlp"], h, cfg.activation), {}, None
     if kind == "ssm":
         h = rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
-        return x + ssm_mod.mamba2_full(p["ssm"], cfg, h), aux
+        return x + ssm_mod.mamba2_full(p["ssm"], cfg, h), {}, None
     if kind == "rglru":
         h = rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
         x = x + rglru_mod.rglru_full(p["rglru"], cfg, h)
         h = rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
-        return x + mlp_apply(p["mlp"], h, cfg.activation), aux
+        return x + mlp_apply(p["mlp"], h, cfg.activation), {}, None
     raise ValueError(kind)
 
 
@@ -108,7 +128,7 @@ def _init_block_cache(cfg: ModelConfig, kind: str, batch: int, capacity: int):
     if kind in ("attn", "moe_attn"):
         window = cfg.sliding_window
         cap = min(capacity, window) if window else capacity
-        if cfg.attention == "mla" and kind == "attn":
+        if cfg.attention == "mla":
             return attn_mod.init_mla_cache(cfg, batch, cap)
         return attn_mod.init_attn_cache(cfg, batch, cap)
     if kind == "ssm":
@@ -121,7 +141,7 @@ def _init_block_cache(cfg: ModelConfig, kind: str, batch: int, capacity: int):
 def _apply_block_decode(p, cfg: ModelConfig, kind: str, x, cache, pos, *, window_override=None):
     if kind in ("attn", "moe_attn"):
         h = rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
-        if cfg.attention == "mla" and kind == "attn":
+        if cfg.attention == "mla":
             a, cache = attn_mod.mla_decode(p["attn"], cfg, h, cache, pos, window=window_override,
                                            absorb=cfg.mla_absorb)
         else:
@@ -129,7 +149,7 @@ def _apply_block_decode(p, cfg: ModelConfig, kind: str, x, cache, pos, *, window
         x = x + a
         h = rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
         if kind == "moe_attn":
-            out, _ = moe_mod.moe_apply(p["moe"], cfg, h)
+            out = moe_mod.moe_apply(p["moe"], cfg, h)[0]
         else:
             out = mlp_apply(p["mlp"], h, cfg.activation)
         return x + out, cache
@@ -152,14 +172,14 @@ def _apply_block_prefill(p, cfg: ModelConfig, kind: str, x, cache, *, window_ove
     ``_apply_block_full``'s cost. ``cache`` must be fresh."""
     if kind in ("attn", "moe_attn"):
         h = rmsnorm_apply(p["norm1"], x, cfg.norm_eps)
-        if cfg.attention == "mla" and kind == "attn":
+        if cfg.attention == "mla":
             a, cache = attn_mod.mla_prefill(p["attn"], cfg, h, cache, window=window_override)
         else:
             a, cache = attn_mod.attn_prefill(p["attn"], cfg, h, cache, window=window_override)
         x = x + a
         h = rmsnorm_apply(p["norm2"], x, cfg.norm_eps)
         if kind == "moe_attn":
-            out, _ = moe_mod.moe_apply(p["moe"], cfg, h)
+            out = moe_mod.moe_apply(p["moe"], cfg, h)[0]
         else:
             out = mlp_apply(p["mlp"], h, cfg.activation)
         return x + out, cache
@@ -186,24 +206,31 @@ class DecoderLM:
         self.cfg = cfg
         self.pattern = cfg.pattern
         U = len(self.pattern)
-        self.n_groups = cfg.n_layers // U
-        self.tail = tuple(self.pattern[: cfg.n_layers % U])
+        rest = cfg.n_layers - cfg.first_dense_layers
+        self.n_groups = rest // U
+        self.head = ("attn",) * cfg.first_dense_layers
+        self.tail = tuple(self.pattern[: rest % U])
+
+    def _unstacked(self, part: str) -> list[tuple[str, str]]:
+        """(key, kind) of the head's or the tail's blocks, in order."""
+        kinds = self.head if part == "head" else self.tail
+        return [(f"{part[0]}{i}_{kind}", kind) for i, kind in enumerate(kinds)]
 
     # -- init ----------------------------------------------------------------
     def init(self, rng) -> dict:
         cfg = self.cfg
         k_emb, k_layers, k_tail = jax.random.split(rng, 3)
         params = {"embed": embedding_init(k_emb, cfg.padded_vocab, cfg.d_model, cfg.jdtype)}
+        for part, key in (("head", jax.random.fold_in(rng, 3)), ("tail", k_tail)):
+            blocks = self._unstacked(part)
+            if blocks:
+                params[part] = {name: _init_block(jax.random.fold_in(key, i), cfg, kind)
+                                for i, (name, kind) in enumerate(blocks)}
         blocks = {}
         for u, kind in enumerate(self.pattern):
             ks = jax.random.split(jax.random.fold_in(k_layers, u), self.n_groups)
             blocks[f"u{u}_{kind}"] = jax.vmap(lambda k, kind=kind: _init_block(k, cfg, kind))(ks)
         params["blocks"] = blocks
-        if self.tail:
-            params["tail"] = {
-                f"t{i}_{kind}": _init_block(jax.random.fold_in(k_tail, i), cfg, kind)
-                for i, kind in enumerate(self.tail)
-            }
         params["final_norm"] = rmsnorm_init(cfg.d_model, cfg.jdtype)
         if not cfg.tie_embeddings:
             k_un = jax.random.fold_in(k_emb, 7)
@@ -214,7 +241,7 @@ class DecoderLM:
     def _embed(self, params, tokens, extra_embeds=None):
         cfg = self.cfg
         x = embedding_apply(params["embed"], tokens) * jnp.asarray(
-            cfg.d_model**0.5, cfg.jdtype
+            cfg.embed_scale or cfg.d_model**0.5, cfg.jdtype
         )
         if extra_embeds is not None:
             # VLM / audio-LM: prepend stub modality embeddings
@@ -222,52 +249,78 @@ class DecoderLM:
         return x
 
     # -- full forward ----------------------------------------------------------
-    def apply(self, params, tokens, extra_embeds=None, *, window_override=None,
-              remat: bool = False, use_flash: bool = False):
-        """→ (logits (B,S_text,padded_vocab), aux_loss)."""
+    def forward(self, params, tokens, extra_embeds=None, *, window_override=None,
+                remat: bool = False, use_flash: bool = False):
+        """→ (logits (B,S_text,padded_vocab), stats, routes): the MoE blocks'
+        counters (``MOE_STATS``, zeros without experts) and the experts each
+        MoE layer chose, (MoE layers, B·S, k) in layer order (None without)."""
         cfg = self.cfg
         x = self._embed(params, tokens, extra_embeds)
         n_text = tokens.shape[1]
+        kw = dict(window_override=window_override, use_flash=use_flash)
+        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+                  if cfg.remat_policy == "dots" else None)
+
+        def unstacked(part, x, stats, routes):
+            for key, kind in self._unstacked(part):
+                block = lambda p, x, kind=kind: _apply_block_full(p, cfg, kind, x, **kw)
+                if remat:
+                    block = jax.checkpoint(block, policy=policy)
+                x, s, r = block(params[part][key], x)
+                stats = _add_stats(stats, s)
+                if r is not None:
+                    routes.append(r[None])
+            return x, stats
 
         def group_body(carry, group_params):
-            x, aux = carry
+            x, stats = carry
+            group_routes = []
             for u, kind in enumerate(self.pattern):
-                x, a = _apply_block_full(
-                    group_params[f"u{u}_{kind}"], cfg, kind, x,
-                    window_override=window_override, use_flash=use_flash,
-                )
-                aux = aux + a
-            return (x, aux), None
+                x, s, r = _apply_block_full(group_params[f"u{u}_{kind}"], cfg, kind, x, **kw)
+                stats = _add_stats(stats, s)
+                if r is not None:
+                    group_routes.append(r)
+            return (x, stats), (jnp.stack(group_routes) if group_routes else None)
 
-        if remat:
-            if cfg.remat_policy == "dots":
-                policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                body = jax.checkpoint(group_body, policy=policy)
-            else:
-                body = jax.checkpoint(group_body)
-        else:
-            body = group_body
-        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), params["blocks"])
-        for i, kind in enumerate(self.tail):
-            x, a = _apply_block_full(
-                params["tail"][f"t{i}_{kind}"], cfg, kind, x,
-                window_override=window_override, use_flash=use_flash,
-            )
-            aux = aux + a
+        routes: list = []
+        x, stats = unstacked("head", x, _zero_stats(), routes)
+        body = jax.checkpoint(group_body, policy=policy) if remat else group_body
+        (x, stats), scanned = jax.lax.scan(body, (x, stats), params["blocks"])
+        if scanned is not None:  # (groups, MoE units, N, k) → layer order
+            routes.append(scanned.reshape((-1,) + scanned.shape[2:]))
+        x, stats = unstacked("tail", x, stats, routes)
         x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
         x = x[:, -n_text:]  # only text positions produce logits (VLM prefix)
         logits = unembed_apply(params.get("unembed", params["embed"]), x)
-        return logits, aux
+        return logits, stats, (jnp.concatenate(routes) if routes else None)
+
+    def apply(self, params, tokens, extra_embeds=None, *, window_override=None,
+              remat: bool = False, use_flash: bool = False):
+        """→ (logits (B,S_text,padded_vocab), aux_loss)."""
+        logits, stats, _ = self.forward(params, tokens, extra_embeds, window_override=window_override,
+                                        remat=remat, use_flash=use_flash)
+        return logits, stats["aux"]
+
+    def routes(self, params, tokens, extra_embeds=None):
+        """The experts each MoE layer chose for each token: (MoE layers, B·S, k)."""
+        return self.forward(params, tokens, extra_embeds)[2]
 
     # -- loss -------------------------------------------------------------------
     def loss(self, params, batch, rng=None, *, remat: bool = False, use_flash: bool = False):
+        """Mean cross-entropy plus the weighted balance loss. The metrics of an
+        MoE model add the routing counters: ``moe_rows``, ``moe_rows_alloc``
+        (summed over layers) and ``moe_load_max`` (the largest layer's)."""
         cfg = self.cfg
-        logits, aux = self.apply(
+        logits, stats, _ = self.forward(
             params, batch["tokens"], batch.get("embeds"), remat=remat, use_flash=use_flash
         )
         ce = softmax_cross_entropy(logits, batch["labels"], valid_vocab=cfg.vocab_size)
-        loss = ce.mean() + cfg.router_aux_weight * aux
-        return loss, {"ce": ce.mean(), "aux": aux, "accuracy": token_accuracy(logits, batch["labels"])}
+        loss = ce.mean() + cfg.router_aux_weight * stats["aux"]
+        metrics = {"ce": ce.mean(), "aux": stats["aux"],
+                   "accuracy": token_accuracy(logits, batch["labels"])}
+        if cfg.num_experts:
+            metrics.update({k: stats[k] for k in MOE_STATS if k != "aux"})
+        return loss, metrics
 
     # -- decode -------------------------------------------------------------------
     def init_cache(self, batch: int, capacity: int, *, window_override=None) -> dict:
@@ -279,42 +332,48 @@ class DecoderLM:
             caches[f"u{u}_{kind}"] = jax.tree.map(
                 lambda l: jnp.broadcast_to(l[None], (self.n_groups,) + l.shape), one
             )
-        if self.tail:
-            caches["tail"] = {
-                f"t{i}_{kind}": _init_block_cache(eff_cfg, kind, batch, capacity)
-                for i, kind in enumerate(self.tail)
-            }
+        for part in ("head", "tail"):
+            blocks = self._unstacked(part)
+            if blocks:
+                caches[part] = {key: _init_block_cache(eff_cfg, kind, batch, capacity)
+                                for key, kind in blocks}
         return caches
+
+    def _through_cache(self, block_fn, params, x, cache):
+        """Every layer in order, each through ``block_fn(p, kind, x, cache) →
+        (x, cache)``: the head, the scanned groups, the tail."""
+        new_cache = {}
+
+        def unstacked(part, x):
+            if self._unstacked(part):
+                new_cache[part] = {}
+            for key, kind in self._unstacked(part):
+                x, new_cache[part][key] = block_fn(params[part][key], kind, x, cache[part][key])
+            return x
+
+        def group_body(x, scanned):
+            group_params, group_cache = scanned
+            out = {}
+            for u, kind in enumerate(self.pattern):
+                key = f"u{u}_{kind}"
+                x, out[key] = block_fn(group_params[key], kind, x, group_cache[key])
+            return x, out
+
+        x = unstacked("head", x)
+        scan_cache = {k: v for k, v in cache.items() if k not in ("head", "tail")}
+        x, scanned = jax.lax.scan(group_body, x, (params["blocks"], scan_cache))
+        new_cache.update(scanned)
+        x = unstacked("tail", x)
+        return x, new_cache
 
     def decode_step(self, params, token, cache, pos, *, window_override=None):
         """token: (B,) int32; pos: scalar int32 → (logits (B,padded_vocab), cache)."""
         cfg = self.cfg
         x = self._embed(params, token[:, None])
-
-        def group_body(x, scanned):
-            group_params, group_cache = scanned
-            new_cache = {}
-            for u, kind in enumerate(self.pattern):
-                key = f"u{u}_{kind}"
-                x, new_cache[key] = _apply_block_decode(
-                    group_params[key], cfg, kind, x, group_cache[key], pos,
-                    window_override=window_override,
-                )
-            return x, new_cache
-
-        tail_cache = cache.get("tail") if isinstance(cache, dict) else None
-        scan_cache = {k: v for k, v in cache.items() if k != "tail"}
-        x, new_scan_cache = jax.lax.scan(group_body, x, (params["blocks"], scan_cache))
-        new_cache = dict(new_scan_cache)
-        if self.tail:
-            new_tail = {}
-            for i, kind in enumerate(self.tail):
-                key = f"t{i}_{kind}"
-                x, new_tail[key] = _apply_block_decode(
-                    params["tail"][key], cfg, kind, x, tail_cache[key], pos,
-                    window_override=window_override,
-                )
-            new_cache["tail"] = new_tail
+        x, new_cache = self._through_cache(
+            lambda p, kind, x, c: _apply_block_decode(p, cfg, kind, x, c, pos,
+                                                      window_override=window_override),
+            params, x, cache)
         x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
         logits = unembed_apply(params.get("unembed", params["embed"]), x[:, 0])
         return logits, new_cache
@@ -327,31 +386,10 @@ class DecoderLM:
         occupies positions 0..P-1). → (logits (B, padded_vocab), cache)."""
         cfg = self.cfg
         x = self._embed(params, tokens, extra_embeds)
-
-        def group_body(x, scanned):
-            group_params, group_cache = scanned
-            new_cache = {}
-            for u, kind in enumerate(self.pattern):
-                key = f"u{u}_{kind}"
-                x, new_cache[key] = _apply_block_prefill(
-                    group_params[key], cfg, kind, x, group_cache[key],
-                    window_override=window_override,
-                )
-            return x, new_cache
-
-        tail_cache = cache.get("tail") if isinstance(cache, dict) else None
-        scan_cache = {k: v for k, v in cache.items() if k != "tail"}
-        x, new_cache = jax.lax.scan(group_body, x, (params["blocks"], scan_cache))
-        new_cache = dict(new_cache)
-        if self.tail:
-            new_tail = {}
-            for i, kind in enumerate(self.tail):
-                key = f"t{i}_{kind}"
-                x, new_tail[key] = _apply_block_prefill(
-                    params["tail"][key], cfg, kind, x, tail_cache[key],
-                    window_override=window_override,
-                )
-            new_cache["tail"] = new_tail
+        x, new_cache = self._through_cache(
+            lambda p, kind, x, c: _apply_block_prefill(p, cfg, kind, x, c,
+                                                       window_override=window_override),
+            params, x, cache)
         x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
         logits = unembed_apply(params.get("unembed", params["embed"]), x[:, -1])
         return logits, new_cache
