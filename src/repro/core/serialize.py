@@ -12,9 +12,15 @@ Readers of the flat hot path (``decode_params_flat``) read a stored envelope
 is viewed in the blob's own bytes, its member's CRC-32 checked against the zip
 directory as ``zipfile`` would, and copied once into the flat vector. A
 deflated envelope ('npz') goes through ``np.load``.
+
+The writer mirrors it: a stored envelope is laid out member by member and
+filled in one pass (``_write_stored``), each leaf cast straight into its slot
+and CRC'd as it is written, giving the bytes ``np.savez`` would. A deflated
+envelope, or an array the stored layout cannot hold, goes through ``np.savez``.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import io
 import json
@@ -24,6 +30,8 @@ import threading
 import zipfile
 import zlib
 from concurrent.futures import ThreadPoolExecutor, wait
+from contextlib import contextmanager
+from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Any, NamedTuple
 
@@ -110,14 +118,21 @@ class FlatUpdate(NodeUpdate):
                 f"num_examples={self.num_examples}, spec={self.spec!r})")
 
 
+def _wire_dtype(dtype: np.dtype) -> tuple[np.dtype, str | None]:
+    """The dtype a leaf of ``dtype`` ships as. numpy cannot round-trip
+    ml_dtypes through npz, so those ship as f32 (aggregation is f32 anyway);
+    returns (wire dtype, original dtype name to restore on load — None when
+    unneeded)."""
+    if dtype.kind == "V" or dtype.name in ("bfloat16", "float8_e4m3fn", "float8_e5m2"):
+        return np.dtype(np.float32), dtype.name
+    return dtype, None
+
+
 def _wire_leaf(leaf) -> tuple[np.ndarray, str | None]:
-    """Convert a leaf to its on-wire array. numpy cannot round-trip ml_dtypes
-    through npz, so those ship as f32 (aggregation is f32 anyway); returns
-    (array, original dtype name to restore on load — None when unneeded)."""
+    """A leaf as its on-wire array, and the dtype name to restore on load."""
     arr = np.asarray(leaf)
-    if arr.dtype.kind == "V" or arr.dtype.name in ("bfloat16", "float8_e4m3fn", "float8_e5m2"):
-        return arr.astype(np.float32), arr.dtype.name
-    return arr, None
+    wire, restored = _wire_dtype(arr.dtype)
+    return (arr.astype(wire) if restored else arr), restored
 
 
 def _rebuild_tree(order, dtypes, get_leaf) -> dict:
@@ -138,11 +153,29 @@ def _rebuild_tree(order, dtypes, get_leaf) -> dict:
     return tree
 
 
+# Which writer packed each envelope, counted on the stats of whoever set it
+# (``TransportPipeline`` does, around its encodes): ``direct_encodes`` for
+# ``_write_stored``, ``savez_encodes`` for ``np.savez``.
+_writer_counter: ContextVar = ContextVar("npz_writer_counter", default=None)
+
+
+@contextmanager
+def counting_writers(incr):
+    """Within the block, every envelope packed on this thread calls
+    ``incr(field)`` with the name of the writer that packed it."""
+    token = _writer_counter.set(incr)
+    try:
+        yield
+    finally:
+        _writer_counter.reset(token)
+
+
 def _pack_npz(arrays: dict[str, np.ndarray], order: list[str], dtypes: dict[str, str],
               meta: dict[str, Any] | None, *, compress: str = "none") -> bytes:
     """The one wire envelope: leaf arrays + __order__/__dtypes__ under a JSON
     __meta__ entry, zipped into an npz. Full and delta blobs both go through
-    here so envelope changes cannot desynchronize the two formats.
+    here so envelope changes cannot desynchronize the two formats. Arrays may
+    come in their own dtype: each ships as ``_wire_dtype`` says.
 
     ``compress``: 'none' (stored npz), 'npz' (deflate via savez_compressed —
     np.load decodes it natively), or 'zstd' (whole-blob zstd frame, sniffed by
@@ -153,9 +186,15 @@ def _pack_npz(arrays: dict[str, np.ndarray], order: list[str], dtypes: dict[str,
     meta_blob["__order__"] = order
     meta_blob["__dtypes__"] = dtypes
     arrays[_META_KEY] = np.frombuffer(json.dumps(meta_blob).encode(), dtype=np.uint8)
-    buf = io.BytesIO()
-    (np.savez_compressed if compress == "npz" else np.savez)(buf, **arrays)
-    blob = buf.getvalue()
+    blob = None if compress == "npz" else _write_stored(arrays)
+    incr = _writer_counter.get()
+    if incr is not None:
+        incr("savez_encodes" if blob is None else "direct_encodes")
+    if blob is None:
+        buf = io.BytesIO()
+        (np.savez_compressed if compress == "npz" else np.savez)(
+            buf, **{k: _wire_leaf(a)[0] for k, a in arrays.items()})
+        blob = buf.getvalue()
     if compress == "zstd":
         blob = _zstd_compress(blob)
     return blob
@@ -169,7 +208,8 @@ def serialize_params(params: PyTree, meta: dict[str, Any] | None = None, *,
     dtypes: dict[str, str] = {}
     for path, leaf in leaves_with_paths:
         key = path_str(path).replace("/", _SEP)
-        arr, original_dtype = _wire_leaf(leaf)
+        arr = np.asarray(leaf)  # widened as it is written, not here
+        original_dtype = _wire_dtype(arr.dtype)[1]
         if original_dtype:
             dtypes[key] = original_dtype
         arrays[key] = arr
@@ -220,22 +260,22 @@ _NPY_HEADER_READERS = {(1, 0): npy_format.read_array_header_1_0,
                        (2, 0): npy_format.read_array_header_2_0}
 _NPY_PREAMBLE_MAX = 1 << 14  # bytes sliced to parse one .npy header
 _INLINE_BYTES = 1 << 20      # smaller checks and copies run on the caller's thread
-_PIECE_BYTES = 1 << 25       # big leaves are copied in pieces of this many bytes of the flat
+_PIECE_BYTES = 1 << 25       # big leaves are copied in pieces of this many bytes of the output
 
 _pool: ThreadPoolExecutor | None = None
 _pool_lock = threading.Lock()
 
 
-def _decode_pool() -> ThreadPoolExecutor:
-    """The process's decode threads, built on first use: one per usable core,
-    at most 8."""
+def _codec_pool() -> ThreadPoolExecutor:
+    """The process's codec threads, shared by the in-place reader and the
+    stored writer, built on first use: one per usable core, at most 8."""
     global _pool
     with _pool_lock:
         if _pool is None:
             cores = (len(os.sched_getaffinity(0)) if hasattr(os, "sched_getaffinity")
                      else os.cpu_count() or 1)
             _pool = ThreadPoolExecutor(max_workers=min(8, cores),
-                                       thread_name_prefix="npz-decode")
+                                       thread_name_prefix="npz-codec")
         return _pool
 
 
@@ -331,9 +371,7 @@ class NpzEnvelope:
 
     def copy_checked(self, jobs, keys) -> None:
         """Run ``jobs`` (dst, src view, scale) and check the CRC of every
-        member in ``keys``; big pieces go to the decode pool. A failure is
-        raised once every piece has finished, so nothing writes into a
-        destination after the call returns."""
+        member in ``keys``; big pieces go to the codec pool (``_run_tasks``)."""
         tasks = [(self._members[k].stop - self._members[k].start, self._check, (k,))
                  for k in keys]
         for dst, src, scale in jobs:
@@ -341,18 +379,229 @@ class NpzEnvelope:
             for i in range(0, dst.size, step):
                 tasks.append((min(step, dst.size - i) * dst.itemsize, _copy_leaf,
                               (dst[i:i + step], src[i:i + step], scale)))
-        tasks.sort(key=lambda t: -t[0])
-        pool = _decode_pool()
-        futures = [pool.submit(fn, *args) for nbytes, fn, args in tasks
-                   if nbytes >= _INLINE_BYTES]
+        _run_tasks(tasks)
+
+
+def _run_tasks(tasks: list) -> list:
+    """Run ``tasks`` (bytes, fn, args), biggest first: those of
+    ``_INLINE_BYTES`` or more on the codec pool, the rest on the caller's
+    thread. Returns their results in order. A failure is raised once every
+    task has finished, so nothing writes into a destination after the call
+    returns."""
+    order = sorted(range(len(tasks)), key=lambda i: -tasks[i][0])
+    pool = _codec_pool()
+    futures = {i: pool.submit(tasks[i][1], *tasks[i][2]) for i in order
+               if tasks[i][0] >= _INLINE_BYTES}
+    results = [None] * len(tasks)
+    try:
+        for i in order:
+            if i not in futures:
+                results[i] = tasks[i][1](*tasks[i][2])
+    finally:
+        wait(futures.values())
+    for i, f in futures.items():
+        results[i] = f.result()
+    return results
+
+
+# --- the stored writer: arrays → the bytes np.savez would give ---------------
+#
+# The write side's mirror of ``NpzEnvelope``. ``np.savez`` hands each array to
+# ``zipfile`` in 16 MiB ``tobytes`` chunks, which it CRCs and appends to a
+# growing ``BytesIO``, all on one thread, and a bf16 leaf must first be widened
+# into an f32 temporary. Here each member's extent is laid out first (the zip64
+# local header ``np.savez`` forces, the ``.npy`` header ``numpy.lib.format``
+# writes, the payload), then one bytes object of the blob's final size is
+# filled in one pass: each leaf cast straight into its payload slot and CRC'd
+# as it is written, big leaves in pieces on the codec pool; the local headers,
+# which carry the CRCs, and the central directory come last.
+# ``ZipFile.open(name, "w")`` stamps every member 1980-01-01 00:00, so the
+# bytes do not depend on the clock. Left to ``np.savez``: an array it would
+# pickle or refuse (object or structured), a ``.npy`` header past format 1.0,
+# and a member over ``zipfile.ZIP64_LIMIT`` (2 GiB), whose sizes the central
+# directory would carry in a zip64 field. Offsets past that limit, which a
+# blob over 2 GiB has, are written as ``zipfile`` writes them.
+
+
+def _crc_multmodp(a: int, b: int) -> int:
+    """a·b modulo the CRC-32 polynomial, bit-reflected (zlib's multmodp);
+    ``a`` is nonzero."""
+    m, p = 1 << 31, 0
+    while True:
+        if a & m:
+            p ^= b
+            if not a & (m - 1):
+                return p
+        m >>= 1
+        b = (b >> 1) ^ 0xEDB88320 if b & 1 else b >> 1
+
+
+_CRC_X2N = [1 << 30]  # x^(2^k) modulo the polynomial, k = 0..31
+for _ in range(31):
+    _CRC_X2N.append(_crc_multmodp(_CRC_X2N[-1], _CRC_X2N[-1]))
+
+
+@functools.lru_cache(maxsize=64)
+def _crc_shift(nbytes: int) -> int:
+    """x^(8·nbytes) modulo the polynomial: what appending ``nbytes`` bytes
+    does to a CRC."""
+    p, k = 1 << 31, 3
+    while nbytes:
+        if nbytes & 1:
+            p = _crc_multmodp(_CRC_X2N[k & 31], p)
+        nbytes >>= 1
+        k += 1
+    return p
+
+
+def _crc32_combine(crc1: int, crc2: int, len2: int) -> int:
+    """CRC-32 of A + B from crc32(A), crc32(B) and len(B) (zlib's
+    crc32_combine), so pieces of one member can be CRC'd apart."""
+    return _crc_multmodp(_crc_shift(len2), crc1) ^ crc2
+
+
+class _Storage:
+    """A bytes object's storage as a writable array interface; holds the
+    object, so views of it keep it alive."""
+
+    def __init__(self, blob: bytes):
+        self.blob = blob
+        addr = np.frombuffer(blob, np.uint8).__array_interface__["data"][0]
+        self.__array_interface__ = {"data": (addr, False), "shape": (len(blob),),
+                                    "typestr": "|u1", "version": 3}
+
+
+def _unfilled_bytes(nbytes: int) -> tuple[bytes, np.ndarray]:
+    """A new bytes object of ``nbytes`` (nonzero) and a writable uint8
+    view of its storage, which the caller fills before anyone else sees the
+    object. ``bytes(n)`` comes from calloc, so a big one is fresh pages no
+    thread has touched yet: each is first touched by the thread that fills
+    it, where a ``BytesIO`` grown to size zeroes the whole blob on one."""
+    blob = bytes(nbytes)
+    return blob, np.asarray(_Storage(blob))
+
+
+def _stored_payload(arr: np.ndarray) -> tuple[np.ndarray, np.dtype, bool] | None:
+    """``arr`` as ``np.savez`` stores it: its payload in file order as a 1-D
+    view, the dtype it ships as, and the ``.npy`` header's fortran_order;
+    None where ``np.savez`` must write it."""
+    wire, restored = _wire_dtype(arr.dtype)
+    if (arr.dtype.fields is not None or not wire.itemsize
+            or not (restored or arr.dtype.kind in "biufcmMSU")):
+        return None
+    if arr.flags.c_contiguous:
+        return arr.reshape(-1), wire, False
+    if arr.flags.f_contiguous:
+        return arr.T.reshape(-1), wire, True
+    # np.savez writes a strided array in C order; a widened one is the f32
+    # copy ``astype`` makes, which keeps the array's own order
+    return _stored_payload(arr.astype(wire) if restored else np.ascontiguousarray(arr))
+
+
+def _fill_piece(dst: np.ndarray, src: np.ndarray, written: np.ndarray) -> int:
+    """Cast ``src`` into ``dst`` and return the CRC-32 of ``written``, the
+    bytes ``dst`` covers."""
+    np.copyto(dst, src, casting="unsafe")
+    return zlib.crc32(written)
+
+
+def _encoded_name(info: zipfile.ZipInfo) -> tuple[bytes, int]:
+    """The member name's bytes and flag bits, as ``zipfile`` encodes them."""
+    try:
+        return info.filename.encode("ascii"), info.flag_bits
+    except UnicodeEncodeError:
+        return info.filename.encode("utf-8"), info.flag_bits | 0x800
+
+
+def _zip_directory(infos: list[zipfile.ZipInfo], start: int) -> bytes:
+    """The central directory and end record ``zipfile`` writes after members
+    ``infos``, the directory starting at byte ``start`` of the blob."""
+    parts = []
+    for zi in infos:
+        offset, extra = zi.header_offset, b""
+        if offset > zipfile.ZIP64_LIMIT:
+            offset, extra = 0xFFFFFFFF, struct.pack("<HHQ", 1, 8, zi.header_offset)
+        name, flags = _encoded_name(zi)
+        y, mo, d, h, mi, sec = zi.date_time
+        parts += [struct.pack(
+            zipfile.structCentralDir, zipfile.stringCentralDir, zi.create_version,
+            zi.create_system, zi.extract_version, zi.reserved, flags, zi.compress_type,
+            h << 11 | mi << 5 | sec // 2, (y - 1980) << 9 | mo << 5 | d, zi.CRC,
+            zi.compress_size, zi.file_size, len(name), len(extra), 0, 0,
+            zi.internal_attr, zi.external_attr, offset), name, extra]
+    count, size = len(infos), sum(map(len, parts))
+    if (count > zipfile.ZIP_FILECOUNT_LIMIT or start > zipfile.ZIP64_LIMIT
+            or size > zipfile.ZIP64_LIMIT):
+        parts.append(struct.pack(
+            zipfile.structEndArchive64, zipfile.stringEndArchive64,
+            zipfile.sizeEndCentDir64 - 12, 45, 45, 0, 0, count, count, size, start))
+        parts.append(struct.pack(zipfile.structEndArchive64Locator,
+                                 zipfile.stringEndArchive64Locator, 0, start + size, 1))
+        count, size, start = min(count, 0xFFFF), min(size, 0xFFFFFFFF), min(start, 0xFFFFFFFF)
+    parts.append(struct.pack(zipfile.structEndArchive, zipfile.stringEndArchive,
+                             0, 0, count, count, size, start, 0))
+    return b"".join(parts)
+
+
+class _Slot(NamedTuple):
+    """One member of a stored npz being written."""
+
+    info: zipfile.ZipInfo
+    start: int          # first byte of the member's data (its .npy file)
+    head: bytes         # the .npy header
+    src: np.ndarray     # the payload in file order, in the array's own dtype
+    wire: np.dtype      # the dtype it ships as
+
+
+def _write_stored(arrays: dict[str, np.ndarray]) -> bytes | None:
+    """The stored npz ``np.savez(buf, **arrays)`` writes, each array shipped
+    as ``_wire_dtype`` says, or None where ``np.savez`` must write it."""
+    slots, pos = [], 0
+    for key, arr in arrays.items():
+        arr = np.asarray(arr)
+        payload = _stored_payload(arr)
+        if payload is None:
+            return None
+        src, wire, fortran = payload
+        head = io.BytesIO()
         try:
-            for nbytes, fn, args in tasks:
-                if nbytes < _INLINE_BYTES:
-                    fn(*args)
-        finally:
-            wait(futures)
-        for f in futures:
-            f.result()
+            npy_format.write_array_header_1_0(head, {
+                "descr": npy_format.dtype_to_descr(wire), "fortran_order": fortran,
+                "shape": arr.shape})
+        except ValueError:
+            return None  # a header only .npy format 2.0 holds
+        info = zipfile.ZipInfo(key + ".npy")
+        info.external_attr, info.CRC = 0o600 << 16, 0
+        info.file_size = info.compress_size = head.tell() + src.size * wire.itemsize
+        if info.file_size > zipfile.ZIP64_LIMIT:
+            return None
+        info.header_offset = pos
+        start = pos + len(info.FileHeader(True))
+        slots.append(_Slot(info, start, head.getvalue(), src, wire))
+        pos = start + info.file_size
+    infos = [s.info for s in slots]
+    blob, out = _unfilled_bytes(pos + len(_zip_directory(infos, pos)))
+    tasks, pieces = [], []  # pieces: per member, (task index, bytes) in file order
+    for s in slots:
+        out[s.start:s.start + len(s.head)] = np.frombuffer(s.head, np.uint8)
+        written = out[s.start + len(s.head):s.start + s.info.file_size]
+        dst, size = written.view(s.wire), s.wire.itemsize
+        step = max(1, _PIECE_BYTES // size)
+        pieces.append([])
+        for j in range(0, s.src.size, step):
+            n = min(step, s.src.size - j) * size
+            pieces[-1].append((len(tasks), n))
+            tasks.append((n, _fill_piece, (dst[j:j + step], s.src[j:j + step],
+                                           written[j * size:j * size + n])))
+    crcs = _run_tasks(tasks)
+    for s, member in zip(slots, pieces):
+        crc = zlib.crc32(s.head)
+        for t, n in member:
+            crc = _crc32_combine(crc, crcs[t], n)
+        s.info.CRC = crc
+        out[s.info.header_offset:s.start] = np.frombuffer(s.info.FileHeader(True), np.uint8)
+    out[pos:] = np.frombuffer(_zip_directory(infos, pos), np.uint8)
+    return blob
 
 
 def _restored_dtype(name: str) -> np.dtype:
@@ -920,7 +1169,7 @@ def serialize_update_delta_from_flat(
     for i, key in enumerate(keys):
         order.append(key)
         dt = spec.dtypes[i]
-        wire_dt, restored = _wire_leaf(np.empty((0,), dt))
+        wire_dt, restored = _wire_dtype(np.dtype(dt))
         if restored:
             dtypes[key] = restored
         o, n = spec.offsets[i], spec.sizes[i]
@@ -942,11 +1191,11 @@ def serialize_update_delta_from_flat(
             continue
         if seg.size > density_threshold * n:
             arrays[_DENSE + key] = np.asarray(
-                flat[o:o + n], dtype=wire_dt.dtype).reshape(spec.shapes[i])
+                flat[o:o + n], dtype=wire_dt).reshape(spec.shapes[i])
             continue
         idx = (seg - o).astype(np.int64 if n > 2**31 else np.int32)
         arrays[_IDX + key] = idx
-        arrays[_VAL + key] = np.asarray(flat[seg], dtype=wire_dt.dtype)
+        arrays[_VAL + key] = np.asarray(flat[seg], dtype=wire_dt)
     meta = _update_meta(update, delta_of=base_hash, **(extra_meta or {}))
     return _pack_npz(arrays, order, dtypes, meta, compress=compress)
 

@@ -85,6 +85,7 @@ from .serialize import (
     apply_update_delta_flat,
     canonicalize_params,
     content_hash,
+    counting_writers,
     decode_params_flat,
     deserialize_update,
     deserialize_update_delta,
@@ -473,6 +474,10 @@ class PipelineStats:
         # included) by reader: read in place from a stored envelope, or
         # through np.load (deflated, or a member not viewable in place)
         "direct_decodes", "npz_decodes",
+        # envelopes the pipeline wrote (pushes with their bases, history
+        # and summaries) by writer: filled in one pass as a stored npz, or
+        # through np.savez (deflated, or an array the stored layout cannot hold)
+        "direct_encodes", "savez_encodes",
     )
     _FLOAT_FIELDS = ("residual_norm", "topk_fraction_effective")
 
@@ -1187,22 +1192,26 @@ class TransportPipeline:
     def push(self, update: NodeUpdate, ctx: StoreContext) -> tuple[bytes, bool]:
         self.stats.incr("encodes")
         tel = ctx.telemetry
-        if tel is not None and tel.enabled:
-            with tel.span("encode"):
-                return self.policy.encode(update, ctx)
-        return self.policy.encode(update, ctx)
+        with counting_writers(self.stats.incr):
+            if tel is not None and tel.enabled:
+                with tel.span("encode"):
+                    return self.policy.encode(update, ctx)
+            return self.policy.encode(update, ctx)
 
     def encode_history(self, update: NodeUpdate) -> bytes:
         """Self-contained (and, for lossy policies, exact) history blob."""
-        return serialize_update(update, compress=self.compress_arg)
+        with counting_writers(self.stats.incr):
+            return serialize_update(update, compress=self.compress_arg)
 
     def encode_summary(self, summary) -> bytes:
         """Gossip group summaries ride the pipeline's envelope."""
-        return serialize_group_summary(summary, compress=self.compress_arg)
+        with counting_writers(self.stats.incr):
+            return serialize_group_summary(summary, compress=self.compress_arg)
 
     def encode_super_summary(self, summary) -> bytes:
         """Hierarchical-gossip tier folds ride the same envelope."""
-        return serialize_super_summary(summary, compress=self.compress_arg)
+        with counting_writers(self.stats.incr):
+            return serialize_super_summary(summary, compress=self.compress_arg)
 
     @property
     def compress_arg(self) -> str:

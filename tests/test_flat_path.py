@@ -670,3 +670,294 @@ def test_concurrent_direct_decodes_share_the_pool():
     assert len(results) == 48
     for flat in results:
         np.testing.assert_array_equal(flat, want)
+
+
+# --- the stored writer ----------------------------------------------------------
+
+
+def _wide_bf16(n):
+    import ml_dtypes
+
+    return np.linspace(-4, 4, n, dtype=np.float32).astype(ml_dtypes.bfloat16)
+
+
+def _float8_arrays():
+    import ml_dtypes
+
+    return {"e4m3": np.linspace(-8, 8, 3000, dtype=np.float32).astype(ml_dtypes.float8_e4m3fn),
+            "e5m2": np.linspace(-8, 8, 1100, dtype=np.float32).astype(
+                ml_dtypes.float8_e5m2).reshape(11, 100)}
+
+
+# name -> arrays as serialize_params's callers hand them over (leaves in their
+# own dtype); several span more than one piece at the low pool thresholds
+ARRAY_CASES = {
+    "zero_d_and_empty": lambda: {"s": np.asarray(2.5, np.float32),
+                                 "e": np.zeros((0, 3), np.float32),
+                                 "e_bf16": _wide_bf16(0)},
+    "fortran": lambda: {"w": np.asfortranarray(
+        np.arange(2000, dtype=np.float32).reshape(40, 50))},
+    "fortran_bf16": lambda: {"w": np.asfortranarray(_wide_bf16(2000).reshape(40, 50))},
+    "strided": lambda: {"w": np.arange(6000, dtype=np.float32).reshape(60, 100)[::2, 1::3],
+                        "w_bf16": np.asfortranarray(
+                            _wide_bf16(6000).reshape(60, 100))[::3, ::2]},
+    "int8_with_f32_scales": lambda: {"q|w": np.arange(10_000).astype(np.int8),
+                                     "s|w": np.asarray(np.float32(0.0123))},
+    "bf16_multi_piece": lambda: {"w": _wide_bf16(5000).reshape(50, 100),
+                                 "b": _wide_bf16(7)},
+    "float8": _float8_arrays,
+    "other_dtypes": lambda: {"big_endian": np.arange(9, dtype=">f4"),
+                             "f64": np.linspace(0, 1, 1500),
+                             "bool": np.arange(10) % 3 == 0,
+                             "text": np.asarray(["ab", "cde"]),
+                             "uint8": np.arange(256, dtype=np.uint8)},
+    "non_ascii_key": lambda: {"wé|b": np.ones((3,), np.float32)},
+}
+
+
+def _meta_bytes(update, order, dtypes) -> bytes:
+    """The __meta__ member serialize_update writes for ``update``."""
+    import json
+
+    from repro.core.serialize import _update_meta
+
+    return json.dumps({**_update_meta(update), "__order__": order,
+                       "__dtypes__": dtypes}).encode()
+
+
+def _savez(arrays) -> bytes:
+    import io
+
+    from repro.core import serialize
+
+    buf = io.BytesIO()
+    np.savez(buf, **{k: serialize._wire_leaf(a)[0] for k, a in arrays.items()})
+    return buf.getvalue()
+
+
+@pytest.fixture(params=["inline", "pool"])
+def writer_sizes(request, monkeypatch):
+    """The writer as it is (small members on the caller's thread), or with
+    every piece sent to the pool and cut at 4 KiB, so the piece and CRC
+    combining paths run at test sizes. The clock is frozen at another date:
+    ``zipfile`` stamps what ``ZipFile.write`` adds with it, but not what
+    ``ZipFile.open(name, "w")`` adds, as np.savez does, so the bytes must
+    not depend on it."""
+    import time
+
+    from repro.core import serialize
+
+    frozen = time.struct_time((2031, 7, 9, 13, 14, 15, 2, 190, 0))
+    monkeypatch.setattr(time, "localtime", lambda *_: frozen)
+    if request.param == "pool":
+        monkeypatch.setattr(serialize, "_INLINE_BYTES", 0)
+        monkeypatch.setattr(serialize, "_PIECE_BYTES", 1 << 12)
+    return request.param
+
+
+@pytest.mark.parametrize("case", sorted(ARRAY_CASES))
+def test_stored_writer_matches_np_savez_bytes(case, writer_sizes):
+    from repro.core import serialize
+
+    arrays = ARRAY_CASES[case]()
+    arrays["__meta__"] = np.frombuffer(b'{"__order__": [], "x": 1}', np.uint8)
+    assert serialize._write_stored(dict(arrays)) == _savez(arrays)
+
+
+@pytest.mark.parametrize("case", sorted(READER_CASES) + ["fleet_meta_only"])
+def test_every_envelope_writer_matches_the_np_savez_path(case, writer_sizes, monkeypatch):
+    """Each blob writer's stored envelope, filled in one pass, is byte for
+    byte what the same call writes through np.savez."""
+    from repro.core import serialize
+
+    if case == "fleet_meta_only":  # no arrays: a lone uint8 __meta__
+        def write(c):
+            return serialize.serialize_fleet_blob("heartbeat", {"t": 1}, compress=c)
+    else:
+        write = READER_CASES[case][0]
+    counted = []
+    with serialize.counting_writers(counted.append):
+        direct = write("none")
+        monkeypatch.setattr(serialize, "_write_stored", lambda arrays: None)
+        savez = write("none")
+    assert direct == savez
+    assert counted == ["direct_encodes", "savez_encodes"]
+
+
+def test_stored_writer_hands_what_it_cannot_hold_to_np_savez():
+    from repro.core import serialize
+
+    assert serialize._write_stored(
+        {"rec": np.zeros(3, dtype=[("a", "<f4"), ("b", "<i2")])}) is None
+    arrays = {"o": np.asarray([1, "a"], dtype=object)}
+    assert serialize._write_stored(dict(arrays)) is None
+    counted = []
+    with serialize.counting_writers(counted.append):
+        blob = serialize._pack_npz(dict(arrays), ["o"], {}, None)
+    assert blob == _savez({**arrays, "__meta__": np.frombuffer(
+        b'{"__order__": ["o"], "__dtypes__": {}}', np.uint8)})
+    assert counted == ["savez_encodes"]
+
+
+@pytest.mark.parametrize("fallback", ["zip64_member", "deflate"])
+def test_fallbacks_write_np_savez_bytes_and_count_as_savez(fallback, monkeypatch):
+    """A member too big for the directory's 32-bit sizes (zipfile's
+    ZIP64_LIMIT, lowered here) and a deflated envelope go through np.savez,
+    and are counted so."""
+    import io
+    import zipfile
+
+    from repro.core import serialize
+
+    update = NodeUpdate({"w": np.arange(600, dtype=np.float32), "b": _wide_bf16(9)},
+                        3, "n")
+    if fallback == "zip64_member":
+        monkeypatch.setattr(zipfile, "ZIP64_LIMIT", 2000)
+    counted = []
+    with serialize.counting_writers(counted.append):
+        blob = serialize_update(update, compress="npz" if fallback == "deflate" else "none")
+    assert counted == ["savez_encodes"]
+    arrays = {"b": _wide_bf16(9), "w": np.arange(600, dtype=np.float32)}
+    arrays["__meta__"] = np.frombuffer(_meta_bytes(update, ["b", "w"], {"b": "bfloat16"}),
+                                       np.uint8)
+    buf = io.BytesIO()
+    (np.savez_compressed if fallback == "deflate" else np.savez)(
+        buf, **{k: serialize._wire_leaf(a)[0] for k, a in arrays.items()})
+    assert blob == buf.getvalue()
+
+
+def test_offsets_past_zip64_limit_are_written_directly(monkeypatch):
+    """A blob over zipfile's ZIP64_LIMIT (lowered here), with every member
+    under it, keeps the stored writer: zip64 offsets in the directory and a
+    zip64 end record, as np.savez writes them, and the reader reads it."""
+    import zipfile
+
+    from repro.core import serialize
+
+    monkeypatch.setattr(zipfile, "ZIP64_LIMIT", 1500)
+    tree = {f"l{i}": np.arange(150, dtype=np.float32) * np.float32(i) for i in range(12)}
+    counted = []
+    with serialize.counting_writers(counted.append):
+        blob = serialize_update(NodeUpdate(tree, 3, "n"))
+        monkeypatch.setattr(serialize, "_write_stored", lambda arrays: None)
+        savez = serialize_update(NodeUpdate(tree, 3, "n"))
+    assert counted == ["direct_encodes", "savez_encodes"] and blob == savez
+    assert len(blob) > 1500 and zipfile.ZipFile(__import__("io").BytesIO(blob)).testzip() is None
+    spec, flat, _meta = decode_params_flat(blob, {})
+    np.testing.assert_array_equal(spec.unflatten(flat)["l11"], tree["l11"])
+
+
+def _codec_params(ctr: int, node: str) -> dict:
+    """Big enough that the delta codecs ship sparse deltas after the first
+    push: three entries of ``w`` change a round."""
+    w = np.linspace(-1, 1, 4096, dtype=np.float32).reshape(64, 64) + np.float32(node == "b")
+    w[ctr, :3] += np.float32(ctr)
+    return {"w": w, "b": _wide_bf16(512)}
+
+
+# transport -> flat decodes in place a reader makes over three rounds of two
+# nodes: every full blob it pulls, and for the delta codecs each node's base
+CODEC_DIRECT_DECODES = {"full": 6, "quantized": 6, "delta": 4, "delta_q": 4, "topk": 4}
+
+
+@pytest.mark.parametrize("transport", sorted(CODEC_DIRECT_DECODES))
+def test_every_codec_writes_np_savez_bytes(transport, monkeypatch):
+    """Two stores take the same pushes, one through the stored writer and one
+    through np.savez: after every round the two folders hold the same
+    blobs, and a reader of the first still decodes every full blob in place."""
+    from repro.api import connect
+    from repro.core import serialize
+
+    uri = f"memory://writers-{transport}"
+    direct, reader = connect(uri, transport=transport), connect(uri, transport=transport)
+    savez = connect(uri + "-savez", transport=transport)
+    for ctr in range(3):
+        for store in (direct, savez):
+            with monkeypatch.context() as m:
+                if store is savez:
+                    m.setattr(serialize, "_write_stored", lambda arrays: None)
+                for node in ("a", "b"):
+                    store.push(NodeUpdate(_codec_params(ctr, node), num_examples=1,
+                                          node_id=node, counter=ctr, timestamp=float(ctr)))
+        keys = sorted(direct.folder.keys())
+        assert keys == sorted(savez.folder.keys())
+        for key in keys:
+            assert direct.folder.get(key) == savez.folder.get(key), (ctr, key)
+        assert len(reader.pull()) == 2
+    if transport not in ("full", "quantized"):
+        assert any("delta_of" in peek_meta(direct.folder.get(key)) for key in keys)
+    wrote = direct.transport_stats()
+    assert wrote["direct_encodes"] > 0 and wrote["savez_encodes"] == 0
+    assert savez.transport_stats()["savez_encodes"] == wrote["direct_encodes"]
+    read = reader.transport_stats()
+    assert read["npz_decodes"] == 0
+    assert read["direct_decodes"] == CODEC_DIRECT_DECODES[transport]
+
+
+def test_full_transport_node_on_bf16_model_writes_directly():
+    from repro.core import AsyncFederatedNode
+
+    node = AsyncFederatedNode(shared_folder=InMemoryFolder(), node_id="a")
+    for _ in range(2):
+        node.update_parameters({"w": _wide_bf16(64).reshape(8, 8), "b": _wide_bf16(8)},
+                               num_examples=1)
+    stats = node.transport_stats()
+    assert stats["direct_encodes"] > 0 and stats["savez_encodes"] == 0
+
+
+def test_crc32_combine_matches_zlib():
+    import zlib
+
+    from repro.core.serialize import _crc32_combine
+
+    rng = np.random.default_rng(5)
+    data = rng.integers(0, 256, 5000, dtype=np.uint8).tobytes()
+    for cut in (0, 1, 7, 2500, 4999, 5000):
+        a, b = data[:cut], data[cut:]
+        assert _crc32_combine(zlib.crc32(a), zlib.crc32(b), len(b)) == zlib.crc32(data)
+
+
+def test_concurrent_stored_writes_share_the_pool():
+    """Many threads writing through the one codec pool at once each get the
+    np.savez bytes of their own update."""
+    import sys
+    import threading
+
+    from repro.core import serialize
+
+    def update(i):
+        return NodeUpdate({"a": np.arange(2**19, dtype=np.float32) * np.float32(i),
+                           "b": _wide_bf16(3 * 2**18), "c": np.ones((5,), np.float32)},
+                          1, f"n{i}")
+
+    want = {}
+    for i in range(6):
+        arrays = {"a": update(i).params["a"], "b": update(i).params["b"],
+                  "c": update(i).params["c"]}
+        arrays["__meta__"] = np.frombuffer(
+            _meta_bytes(update(i), ["a", "b", "c"], {"b": "bfloat16"}), np.uint8)
+        want[i] = _savez(arrays)
+    results, errors = [], []
+
+    def work(i):
+        try:
+            for _ in range(3):
+                results.append((i, serialize_update(update(i))))
+        except Exception as e:  # noqa: BLE001 - reported by the assertion
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i % 6,)) for i in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and not errors
+    assert len(results) == 36
+    for i, blob in results:
+        assert blob == want[i]
+    assert serialize.NpzEnvelope(results[0][1]).direct
