@@ -7,16 +7,15 @@ reserved ``__meta__`` JSON entry. Key-path keyed storage (instead of pickling
 a treedef) keeps the format language- and process-agnostic — the store really
 could be an S3 bucket written by heterogeneous clients.
 
-Readers of the flat hot path (``decode_params_flat``) read a stored envelope
-(``compress`` 'none', or 'zstd' once its frame is undone) in place: each leaf
-is viewed in the blob's own bytes, its member's CRC-32 checked against the zip
-directory as ``zipfile`` would, and copied once into the flat vector. A
-deflated envelope ('npz') goes through ``np.load``.
-
-The writer mirrors it: a stored envelope is laid out member by member and
-filled in one pass (``_write_stored``), each leaf cast straight into its slot
-and CRC'd as it is written, giving the bytes ``np.savez`` would. A deflated
-envelope, or an array the stored layout cannot hold, goes through ``np.savez``.
+One writer and one reader handle every blob. The writer (``_write_stored``)
+lays the envelope out member by member and fills it in one pass, each leaf
+cast straight into its slot and CRC'd as it is written, or deflated whole on
+the codec pool: the bytes ``np.savez`` or ``np.savez_compressed`` would give.
+The reader (``NpzEnvelope``) views each member's array in the blob's own bytes
+(a deflated member in its bytes inflated once), checks the member's CRC-32
+against the zip directory as ``zipfile`` would, and copies it once into the
+flat vector or an array of its own. 'zstd' wraps the whole envelope in one
+frame, undone before either looks at it.
 """
 from __future__ import annotations
 
@@ -30,8 +29,6 @@ import threading
 import zipfile
 import zlib
 from concurrent.futures import ThreadPoolExecutor, wait
-from contextlib import contextmanager
-from contextvars import ContextVar
 from dataclasses import dataclass, field
 from typing import Any, NamedTuple
 
@@ -58,8 +55,7 @@ def _zstd_compress(blob: bytes) -> bytes:
 
 
 def maybe_decompress(blob: bytes) -> bytes:
-    """Undo the optional zstd wire wrapping; readers stay format-agnostic.
-    (``savez_compressed`` needs no sniffing — np.load handles it natively.)"""
+    """Undo the optional zstd wire wrapping; readers stay format-agnostic."""
     if blob[:4] != _ZSTD_MAGIC:
         return blob
     return zstandard.ZstdDecompressor().decompress(blob)
@@ -153,23 +149,6 @@ def _rebuild_tree(order, dtypes, get_leaf) -> dict:
     return tree
 
 
-# Which writer packed each envelope, counted on the stats of whoever set it
-# (``TransportPipeline`` does, around its encodes): ``direct_encodes`` for
-# ``_write_stored``, ``savez_encodes`` for ``np.savez``.
-_writer_counter: ContextVar = ContextVar("npz_writer_counter", default=None)
-
-
-@contextmanager
-def counting_writers(incr):
-    """Within the block, every envelope packed on this thread calls
-    ``incr(field)`` with the name of the writer that packed it."""
-    token = _writer_counter.set(incr)
-    try:
-        yield
-    finally:
-        _writer_counter.reset(token)
-
-
 def _pack_npz(arrays: dict[str, np.ndarray], order: list[str], dtypes: dict[str, str],
               meta: dict[str, Any] | None, *, compress: str = "none") -> bytes:
     """The one wire envelope: leaf arrays + __order__/__dtypes__ under a JSON
@@ -177,24 +156,16 @@ def _pack_npz(arrays: dict[str, np.ndarray], order: list[str], dtypes: dict[str,
     here so envelope changes cannot desynchronize the two formats. Arrays may
     come in their own dtype: each ships as ``_wire_dtype`` says.
 
-    ``compress``: 'none' (stored npz), 'npz' (deflate via savez_compressed —
-    np.load decodes it natively), or 'zstd' (whole-blob zstd frame, sniffed by
-    ``maybe_decompress``)."""
+    ``compress``: 'none' (stored npz), 'npz' (each member deflated, as
+    ``np.savez_compressed`` writes it), or 'zstd' (whole-blob zstd frame,
+    sniffed by ``maybe_decompress``)."""
     if compress not in COMPRESSIONS:
         raise ValueError(f"unknown compress {compress!r}; options: {COMPRESSIONS}")
     meta_blob = dict(meta or {})
     meta_blob["__order__"] = order
     meta_blob["__dtypes__"] = dtypes
     arrays[_META_KEY] = np.frombuffer(json.dumps(meta_blob).encode(), dtype=np.uint8)
-    blob = None if compress == "npz" else _write_stored(arrays)
-    incr = _writer_counter.get()
-    if incr is not None:
-        incr("savez_encodes" if blob is None else "direct_encodes")
-    if blob is None:
-        buf = io.BytesIO()
-        (np.savez_compressed if compress == "npz" else np.savez)(
-            buf, **{k: _wire_leaf(a)[0] for k, a in arrays.items()})
-        blob = buf.getvalue()
+    blob = _write_stored(arrays, deflate=compress == "npz")
     if compress == "zstd":
         blob = _zstd_compress(blob)
     return blob
@@ -219,32 +190,31 @@ def serialize_params(params: PyTree, meta: dict[str, Any] | None = None, *,
 
 def deserialize_params(blob: bytes) -> tuple[PyTree, dict[str, Any]]:
     """Returns (nested-dict params, meta). Key paths 'a|b|c' rebuild nesting."""
-    with np.load(io.BytesIO(maybe_decompress(blob))) as data:
-        meta = json.loads(bytes(data[_META_KEY].tobytes()).decode())
-        order = meta.pop("__order__")
-        dtypes = meta.pop("__dtypes__", {})
-        tree = _rebuild_tree(order, dtypes, lambda key: data[key])
-    return tree, meta
+    env = NpzEnvelope(blob)
+    meta, order, dtypes = env.contents()
+    return _rebuild_tree(order, dtypes, env.read(order).__getitem__), meta
 
 
-# --- flat decode: npz blob → one preallocated f32 vector ---------------------
+# --- the reader: npz envelope → arrays, in place ------------------------------
 #
-# The read side of the flat hot path. Instead of rebuilding a nested dict leaf
-# by leaf, a blob is decoded *directly into one flat f32 vector* laid out by a
-# LeafSpec derived from the blob's own __order__/__dtypes__ metadata. Specs are
-# interned in a caller-owned table, so every update a store decodes for the
-# same model shares one spec instance and aggregation can stack flats with an
-# identity check instead of a structural comparison.
+# ``NpzEnvelope`` reads every blob the program reads. The zip directory is
+# parsed once; a member's ``.npy`` header on first use. Its array is a view of
+# the blob's own bytes for a stored member, or of the member inflated once for
+# a deflated one, so the only pass over the data is one copy into the flat (or
+# an array of the caller's) plus the CRC-32 check against the directory that
+# ``zipfile`` makes — the same ``zipfile.BadZipFile`` on a mismatch. A deflated
+# member is checked as it is inflated, a stored one alongside its copy. Big
+# copies and checks run on a small thread pool (``zlib.crc32`` and numpy's copy
+# release the GIL). A member the writer cannot have produced (encrypted, not
+# ``.npy``, a header past format 1.0, a dtype outside ``biufcmMSU``) raises
+# ``ValueError``, as bytes that are not a zip do.
 #
-# A stored envelope is read in place (``NpzEnvelope``): each member's extent
-# comes from the zip directory and its array's shape and dtype from the
-# ``.npy`` header, each leaf is a view of the blob's own bytes, and the only
-# pass over the data is one copy into the flat plus the CRC-32 check against
-# the directory that ``zipfile`` makes — the same ``zipfile.BadZipFile`` on a
-# mismatch. Big copies and checks run on a small thread pool (``zlib.crc32``
-# and numpy's copy release the GIL). A deflated envelope, or a member that
-# cannot be viewed in place (encrypted, object, structured or Fortran-ordered),
-# goes through ``np.load`` instead; both readers fill the same flat.
+# The flat decode (``decode_params_flat``) reads a blob straight into one
+# preallocated f32 vector laid out by a LeafSpec derived from the blob's own
+# __order__/__dtypes__ metadata. Specs are interned in a caller-owned table,
+# so every update a store decodes for the same model shares one spec instance
+# and aggregation can stack flats with an identity check instead of a
+# structural comparison.
 
 
 class FlatDecodeUnsupported(ValueError):
@@ -256,19 +226,18 @@ class FlatDecodeUnsupported(ValueError):
 # repeats, then the lengths of the name and of the extra field that precede
 # the member's data
 _LOCAL_HEADER = struct.Struct("<4s22xHH")
-_NPY_HEADER_READERS = {(1, 0): npy_format.read_array_header_1_0,
-                       (2, 0): npy_format.read_array_header_2_0}
 _NPY_PREAMBLE_MAX = 1 << 14  # bytes sliced to parse one .npy header
 _INLINE_BYTES = 1 << 20      # smaller checks and copies run on the caller's thread
 _PIECE_BYTES = 1 << 25       # big leaves are copied in pieces of this many bytes of the output
+_DTYPE_KINDS = "biufcmMSU"   # what a member may hold: no objects, no records
 
 _pool: ThreadPoolExecutor | None = None
 _pool_lock = threading.Lock()
 
 
 def _codec_pool() -> ThreadPoolExecutor:
-    """The process's codec threads, shared by the in-place reader and the
-    stored writer, built on first use: one per usable core, at most 8."""
+    """The process's codec threads, shared by the reader and the writer,
+    built on first use: one per usable core, at most 8."""
     global _pool
     with _pool_lock:
         if _pool is None:
@@ -280,30 +249,37 @@ def _codec_pool() -> ThreadPoolExecutor:
 
 
 class _Member(NamedTuple):
-    """Where one stored member lies in its blob."""
+    """Where one member lies in its blob."""
 
-    start: int          # first byte of the member's data (its .npy file)
+    start: int          # first byte of the member's data
     stop: int
-    crc: int            # CRC-32 of blob[start:stop], from the zip directory
-    offset: int         # first byte of the array's payload
+    crc: int            # CRC-32 of its .npy file, from the zip directory
+    size: int           # bytes of its .npy file (stop - start unless deflated)
+    deflated: bool
+
+
+class _Array(NamedTuple):
+    """Where one member's array lies once its .npy header is read."""
+
+    buf: bytes          # the blob, or the member inflated
+    offset: int         # first byte of the array's payload in ``buf``
     dtype: np.dtype
     shape: tuple
+    order: str          # "F" for a Fortran-ordered array, else "C"
 
 
-def _stored_members(blob: bytes) -> dict[str, _Member] | None:
-    """Member key → extent, for an npz whose every member can be viewed in
-    place; None when one cannot, which leaves the blob to ``np.load``. Reads
-    only the zip directory and the .npy headers; raises
-    ``zipfile.BadZipFile`` on a zip that is not whole."""
+def _zip_members(blob: bytes) -> dict[str, _Member]:
+    """Member key → extent. Reads only the zip directory and the local
+    headers; raises ``zipfile.BadZipFile`` on a zip that is not whole."""
     if blob[:4] != b"PK\x03\x04":
-        return None  # not a zip: np.load raises what it always has
+        raise ValueError("not an npz envelope")
     with zipfile.ZipFile(io.BytesIO(blob)) as zf:
         infos = zf.infolist()
     members = {}
     for zi in infos:
-        if (zi.compress_type != zipfile.ZIP_STORED or zi.flag_bits & 0x1
-                or not zi.filename.endswith(".npy")):
-            return None
+        if (zi.compress_type not in (zipfile.ZIP_STORED, zipfile.ZIP_DEFLATED)
+                or zi.flag_bits & 0x1 or not zi.filename.endswith(".npy")):
+            raise ValueError(f"member {zi.filename!r} is not a plain .npy file")
         if zi.header_offset + _LOCAL_HEADER.size > len(blob):
             raise zipfile.BadZipFile(f"member {zi.filename!r} lies past the end of the blob")
         magic, name_len, extra_len = _LOCAL_HEADER.unpack_from(blob, zi.header_offset)
@@ -313,21 +289,20 @@ def _stored_members(blob: bytes) -> dict[str, _Member] | None:
         stop = start + zi.compress_size
         if stop > len(blob):
             raise zipfile.BadZipFile(f"member {zi.filename!r} runs past the end of the blob")
-        preamble = io.BytesIO(blob[start:min(stop, start + _NPY_PREAMBLE_MAX)])
-        try:
-            reader = _NPY_HEADER_READERS.get(npy_format.read_magic(preamble))
-            if reader is None:
-                return None
-            shape, fortran_order, dtype = reader(preamble)
-        except ValueError:
-            return None
-        offset = start + preamble.tell()
-        if (fortran_order or dtype.kind not in "biufc" or dtype.subdtype is not None
-                or offset + int(np.prod(shape)) * dtype.itemsize != stop):
-            return None
         members[zi.filename[:-len(".npy")]] = _Member(
-            start, stop, zi.CRC, offset, dtype, tuple(shape))
+            start, stop, zi.CRC, zi.file_size, zi.compress_type == zipfile.ZIP_DEFLATED)
     return members
+
+
+def _inflate(blob: bytes, m: _Member, key: str) -> bytes:
+    """Member ``m``'s .npy file inflated, checked against its CRC-32."""
+    try:
+        data = zlib.decompress(memoryview(blob)[m.start:m.stop], -15, m.size)
+    except zlib.error as e:
+        raise zipfile.BadZipFile(f"bad deflate stream for file {key}.npy: {e}") from None
+    if len(data) != m.size or zlib.crc32(data) != m.crc:
+        raise zipfile.BadZipFile(f"Bad CRC-32 for file {key}.npy")
+    return data
 
 
 def _copy_leaf(dst: np.ndarray, src: np.ndarray, scale) -> None:
@@ -337,36 +312,63 @@ def _copy_leaf(dst: np.ndarray, src: np.ndarray, scale) -> None:
         np.multiply(src, scale, out=dst, dtype=np.float32, casting="unsafe")
 
 
-def _copy_serial(jobs, _keys) -> None:
-    for dst, src, scale in jobs:
-        _copy_leaf(dst, src, scale)
-
-
 class NpzEnvelope:
     """One parse of a serialized blob's envelope: the zstd frame undone, the
-    ``__meta__`` entry read, and, for a stored npz, where each member's array
-    lies in the blob (``direct``). ``meta`` is shared: copy before mutating."""
+    zip directory and the ``__meta__`` entry read. ``meta`` is shared: copy
+    before mutating."""
 
     def __init__(self, blob: bytes):
         self.blob = maybe_decompress(blob)
-        self._members = _stored_members(self.blob)
-        self.direct = self._members is not None
-        if self.direct:
-            self._check(_META_KEY)
-            self.meta = json.loads(self.view(_META_KEY).tobytes().decode())
+        self._members = _zip_members(self.blob)
+        self._arrays: dict[str, _Array] = {}
+        self.meta = json.loads(self.read([_META_KEY])[_META_KEY].tobytes().decode())
+
+    @property
+    def files(self) -> tuple[str, ...]:
+        """Every member's key, in the blob's order."""
+        return tuple(self._members)
+
+    def contents(self) -> tuple[dict, list[str], dict[str, str]]:
+        """A copy of ``meta`` without the envelope's own entries, the leaf
+        keys in ``__order__`` and the ``__dtypes__`` they restore to."""
+        meta = dict(self.meta)
+        return meta, meta.pop("__order__"), meta.pop("__dtypes__", {})
+
+    def _array(self, key: str) -> _Array:
+        a = self._arrays.get(key)
+        if a is not None:
+            return a
+        m = self._members[key]
+        if m.deflated:
+            buf, start, stop = _inflate(self.blob, m, key), 0, m.size
         else:
-            self.meta = peek_meta(self.blob)
+            buf, start, stop = self.blob, m.start, m.stop
+        preamble = io.BytesIO(buf[start:min(stop, start + _NPY_PREAMBLE_MAX)])
+        try:
+            if npy_format.read_magic(preamble) != (1, 0):
+                raise ValueError("a .npy header past format 1.0")
+            shape, fortran_order, dtype = npy_format.read_array_header_1_0(preamble)
+        except ValueError as e:
+            raise ValueError(f"member {key!r}: {e}") from None
+        offset = start + preamble.tell()
+        if dtype.kind not in _DTYPE_KINDS or offset + int(np.prod(shape)) * dtype.itemsize != stop:
+            raise ValueError(f"member {key!r}: a {dtype} array of shape {shape} "
+                             f"in {stop - offset} bytes")
+        a = self._arrays[key] = _Array(buf, offset, dtype, tuple(shape),
+                                       "F" if fortran_order else "C")
+        return a
 
     def view(self, key: str) -> np.ndarray:
-        """A read-only view of member ``key``'s array in the blob, not yet
-        checked against its CRC."""
-        m = self._members[key]
-        count = int(np.prod(m.shape))
-        return np.frombuffer(self.blob, m.dtype, count, m.offset).reshape(m.shape)
+        """A read-only view of member ``key``'s array, checked against its
+        CRC only if the member was deflated."""
+        a = self._array(key)
+        return np.ndarray(a.shape, a.dtype, a.buf, a.offset, order=a.order)
 
     def _check(self, key: str) -> None:
         m = self._members[key]
-        if zlib.crc32(memoryview(self.blob)[m.start:m.stop]) != m.crc:
+        if m.deflated:
+            self._array(key)  # inflating it checked it
+        elif zlib.crc32(memoryview(self.blob)[m.start:m.stop]) != m.crc:
             raise zipfile.BadZipFile(f"Bad CRC-32 for file {key}.npy")
 
     def copy_checked(self, jobs, keys) -> None:
@@ -375,11 +377,22 @@ class NpzEnvelope:
         tasks = [(self._members[k].stop - self._members[k].start, self._check, (k,))
                  for k in keys]
         for dst, src, scale in jobs:
-            step = _PIECE_BYTES // dst.itemsize
+            step = max(1, _PIECE_BYTES // dst.itemsize)
             for i in range(0, dst.size, step):
                 tasks.append((min(step, dst.size - i) * dst.itemsize, _copy_leaf,
                               (dst[i:i + step], src[i:i + step], scale)))
         _run_tasks(tasks)
+
+    def read(self, keys) -> dict[str, np.ndarray]:
+        """Members ``keys`` as checked, writable arrays of their own: what
+        ``np.load`` gives for them."""
+        out, jobs = {}, []
+        for key in keys:
+            src = self.view(key)
+            dst = out[key] = np.empty_like(src)
+            jobs.append((dst.ravel("K"), src.ravel("K"), None))
+        self.copy_checked(jobs, out)
+        return out
 
 
 def _run_tasks(tasks: list) -> list:
@@ -404,23 +417,27 @@ def _run_tasks(tasks: list) -> list:
     return results
 
 
-# --- the stored writer: arrays → the bytes np.savez would give ---------------
+# --- the writer: arrays → the bytes np.savez would give ------------------------
 #
-# The write side's mirror of ``NpzEnvelope``. ``np.savez`` hands each array to
-# ``zipfile`` in 16 MiB ``tobytes`` chunks, which it CRCs and appends to a
-# growing ``BytesIO``, all on one thread, and a bf16 leaf must first be widened
-# into an f32 temporary. Here each member's extent is laid out first (the zip64
-# local header ``np.savez`` forces, the ``.npy`` header ``numpy.lib.format``
-# writes, the payload), then one bytes object of the blob's final size is
-# filled in one pass: each leaf cast straight into its payload slot and CRC'd
-# as it is written, big leaves in pieces on the codec pool; the local headers,
-# which carry the CRCs, and the central directory come last.
-# ``ZipFile.open(name, "w")`` stamps every member 1980-01-01 00:00, so the
-# bytes do not depend on the clock. Left to ``np.savez``: an array it would
-# pickle or refuse (object or structured), a ``.npy`` header past format 1.0,
-# and a member over ``zipfile.ZIP64_LIMIT`` (2 GiB), whose sizes the central
-# directory would carry in a zip64 field. Offsets past that limit, which a
-# blob over 2 GiB has, are written as ``zipfile`` writes them.
+# The write side's mirror of ``NpzEnvelope``, and the only writer of the
+# envelope. ``np.savez`` hands each array to ``zipfile`` in 16 MiB ``tobytes``
+# chunks, which it CRCs and appends to a growing ``BytesIO``, all on one
+# thread, and a bf16 leaf must first be widened into an f32 temporary. Here
+# each member is laid out first (the zip64 local header ``np.savez`` forces,
+# the ``.npy`` 1.0 header ``numpy.lib.format`` writes, the payload). A stored
+# envelope is then one bytes object of the blob's final size filled in one
+# pass: each leaf cast straight into its payload slot and CRC'd as it is
+# written, big leaves in pieces on the codec pool; the local headers, which
+# carry the CRCs, and the central directory come last. A deflated envelope
+# ('npz') deflates each member whole, members in parallel on the pool, with
+# the raw deflate ``zipfile`` uses at its default level: zlib's output does
+# not depend on how its input is cut, so the bytes are
+# ``np.savez_compressed``'s. ``ZipFile.open(name, "w")`` stamps every member
+# 1980-01-01 00:00, so the bytes do not depend on the clock. Sizes and
+# offsets past ``zipfile.ZIP64_LIMIT`` (2 GiB) go in zip64 fields, as
+# ``zipfile`` writes them. An object array (``np.savez`` would pickle it) or a
+# structured one raises ``ValueError`` naming its key: no caller makes one, and
+# ``np.load`` could not read a pickled one back anyway.
 
 
 def _crc_multmodp(a: int, b: int) -> int:
@@ -481,21 +498,20 @@ def _unfilled_bytes(nbytes: int) -> tuple[bytes, np.ndarray]:
     return blob, np.asarray(_Storage(blob))
 
 
-def _stored_payload(arr: np.ndarray) -> tuple[np.ndarray, np.dtype, bool] | None:
+def _stored_payload(key: str, arr: np.ndarray) -> tuple[np.ndarray, np.dtype, bool]:
     """``arr`` as ``np.savez`` stores it: its payload in file order as a 1-D
-    view, the dtype it ships as, and the ``.npy`` header's fortran_order;
-    None where ``np.savez`` must write it."""
+    view, the dtype it ships as, and the ``.npy`` header's fortran_order."""
     wire, restored = _wire_dtype(arr.dtype)
     if (arr.dtype.fields is not None or not wire.itemsize
-            or not (restored or arr.dtype.kind in "biufcmMSU")):
-        return None
+            or not (restored or arr.dtype.kind in _DTYPE_KINDS)):
+        raise ValueError(f"member {key!r}: a {arr.dtype} array has no place in the envelope")
     if arr.flags.c_contiguous:
         return arr.reshape(-1), wire, False
     if arr.flags.f_contiguous:
         return arr.T.reshape(-1), wire, True
     # np.savez writes a strided array in C order; a widened one is the f32
     # copy ``astype`` makes, which keeps the array's own order
-    return _stored_payload(arr.astype(wire) if restored else np.ascontiguousarray(arr))
+    return _stored_payload(key, arr.astype(wire) if restored else np.ascontiguousarray(arr))
 
 
 def _fill_piece(dst: np.ndarray, src: np.ndarray, written: np.ndarray) -> int:
@@ -503,6 +519,15 @@ def _fill_piece(dst: np.ndarray, src: np.ndarray, written: np.ndarray) -> int:
     bytes ``dst`` covers."""
     np.copyto(dst, src, casting="unsafe")
     return zlib.crc32(written)
+
+
+def _deflate_member(head: bytes, src: np.ndarray, wire: np.dtype) -> tuple[int, bytes]:
+    """The CRC-32 of a .npy file (``head`` then ``src`` as ``wire``) and the
+    file deflated as ``zipfile`` deflates it."""
+    payload = np.asarray(src, wire).view(np.uint8)
+    deflate = zlib.compressobj(zlib.Z_DEFAULT_COMPRESSION, zlib.DEFLATED, -15)
+    data = deflate.compress(head) + deflate.compress(payload) + deflate.flush()
+    return zlib.crc32(payload, zlib.crc32(head)), data
 
 
 def _encoded_name(info: zipfile.ZipInfo) -> tuple[bytes, int]:
@@ -516,22 +541,26 @@ def _encoded_name(info: zipfile.ZipInfo) -> tuple[bytes, int]:
 def _zip_directory(infos: list[zipfile.ZipInfo], start: int) -> bytes:
     """The central directory and end record ``zipfile`` writes after members
     ``infos``, the directory starting at byte ``start`` of the blob."""
-    parts = []
+    limit, parts = zipfile.ZIP64_LIMIT, []
     for zi in infos:
-        offset, extra = zi.header_offset, b""
-        if offset > zipfile.ZIP64_LIMIT:
-            offset, extra = 0xFFFFFFFF, struct.pack("<HHQ", 1, 8, zi.header_offset)
+        size, csize, offset, wide = zi.file_size, zi.compress_size, zi.header_offset, []
+        if size > limit or csize > limit:
+            wide += [size, csize]
+            size = csize = 0xFFFFFFFF
+        if offset > limit:
+            wide.append(offset)
+            offset = 0xFFFFFFFF
+        extra = struct.pack(f"<HH{len(wide)}Q", 1, 8 * len(wide), *wide) if wide else b""
         name, flags = _encoded_name(zi)
         y, mo, d, h, mi, sec = zi.date_time
         parts += [struct.pack(
             zipfile.structCentralDir, zipfile.stringCentralDir, zi.create_version,
             zi.create_system, zi.extract_version, zi.reserved, flags, zi.compress_type,
             h << 11 | mi << 5 | sec // 2, (y - 1980) << 9 | mo << 5 | d, zi.CRC,
-            zi.compress_size, zi.file_size, len(name), len(extra), 0, 0,
+            csize, size, len(name), len(extra), 0, 0,
             zi.internal_attr, zi.external_attr, offset), name, extra]
     count, size = len(infos), sum(map(len, parts))
-    if (count > zipfile.ZIP_FILECOUNT_LIMIT or start > zipfile.ZIP64_LIMIT
-            or size > zipfile.ZIP64_LIMIT):
+    if count > zipfile.ZIP_FILECOUNT_LIMIT or start > limit or size > limit:
         parts.append(struct.pack(
             zipfile.structEndArchive64, zipfile.stringEndArchive64,
             zipfile.sizeEndCentDir64 - 12, 45, 45, 0, 0, count, count, size, start))
@@ -544,47 +573,53 @@ def _zip_directory(infos: list[zipfile.ZipInfo], start: int) -> bytes:
 
 
 class _Slot(NamedTuple):
-    """One member of a stored npz being written."""
+    """One member of an npz being written."""
 
     info: zipfile.ZipInfo
-    start: int          # first byte of the member's data (its .npy file)
     head: bytes         # the .npy header
     src: np.ndarray     # the payload in file order, in the array's own dtype
     wire: np.dtype      # the dtype it ships as
 
 
-def _write_stored(arrays: dict[str, np.ndarray]) -> bytes | None:
-    """The stored npz ``np.savez(buf, **arrays)`` writes, each array shipped
-    as ``_wire_dtype`` says, or None where ``np.savez`` must write it."""
-    slots, pos = [], 0
+def _write_stored(arrays: dict[str, np.ndarray], *, deflate: bool = False) -> bytes:
+    """The npz ``np.savez`` writes for ``arrays`` (with ``deflate``, the one
+    ``np.savez_compressed`` writes), each array shipped as ``_wire_dtype``
+    says."""
+    slots = []
     for key, arr in arrays.items():
         arr = np.asarray(arr)
-        payload = _stored_payload(arr)
-        if payload is None:
-            return None
-        src, wire, fortran = payload
+        src, wire, fortran = _stored_payload(key, arr)
         head = io.BytesIO()
-        try:
-            npy_format.write_array_header_1_0(head, {
-                "descr": npy_format.dtype_to_descr(wire), "fortran_order": fortran,
-                "shape": arr.shape})
-        except ValueError:
-            return None  # a header only .npy format 2.0 holds
+        npy_format.write_array_header_1_0(head, {
+            "descr": npy_format.dtype_to_descr(wire), "fortran_order": fortran,
+            "shape": arr.shape})
         info = zipfile.ZipInfo(key + ".npy")
         info.external_attr, info.CRC = 0o600 << 16, 0
-        info.file_size = info.compress_size = head.tell() + src.size * wire.itemsize
-        if info.file_size > zipfile.ZIP64_LIMIT:
-            return None
-        info.header_offset = pos
-        start = pos + len(info.FileHeader(True))
-        slots.append(_Slot(info, start, head.getvalue(), src, wire))
-        pos = start + info.file_size
+        info.file_size = head.tell() + src.size * wire.itemsize
+        if deflate:
+            info.compress_type = zipfile.ZIP_DEFLATED
+        slots.append(_Slot(info, head.getvalue(), src, wire))
     infos = [s.info for s in slots]
+    if deflate:
+        members = _run_tasks([(s.src.size * s.wire.itemsize, _deflate_member,
+                               (s.head, s.src, s.wire)) for s in slots])
+        parts, pos = [], 0
+        for s, (crc, data) in zip(slots, members):
+            s.info.CRC, s.info.compress_size, s.info.header_offset = crc, len(data), pos
+            local = s.info.FileHeader(True)
+            parts += [local, data]
+            pos += len(local) + len(data)
+        return b"".join(parts + [_zip_directory(infos, pos)])
+    starts, pos = [], 0
+    for s in slots:
+        s.info.compress_size, s.info.header_offset = s.info.file_size, pos
+        starts.append(pos + len(s.info.FileHeader(True)))
+        pos = starts[-1] + s.info.file_size
     blob, out = _unfilled_bytes(pos + len(_zip_directory(infos, pos)))
     tasks, pieces = [], []  # pieces: per member, (task index, bytes) in file order
-    for s in slots:
-        out[s.start:s.start + len(s.head)] = np.frombuffer(s.head, np.uint8)
-        written = out[s.start + len(s.head):s.start + s.info.file_size]
+    for s, start in zip(slots, starts):
+        out[start:start + len(s.head)] = np.frombuffer(s.head, np.uint8)
+        written = out[start + len(s.head):start + s.info.file_size]
         dst, size = written.view(s.wire), s.wire.itemsize
         step = max(1, _PIECE_BYTES // size)
         pieces.append([])
@@ -594,12 +629,12 @@ def _write_stored(arrays: dict[str, np.ndarray]) -> bytes | None:
             tasks.append((n, _fill_piece, (dst[j:j + step], s.src[j:j + step],
                                            written[j * size:j * size + n])))
     crcs = _run_tasks(tasks)
-    for s, member in zip(slots, pieces):
+    for s, start, member in zip(slots, starts, pieces):
         crc = zlib.crc32(s.head)
         for t, n in member:
             crc = _crc32_combine(crc, crcs[t], n)
         s.info.CRC = crc
-        out[s.info.header_offset:s.start] = np.frombuffer(s.info.FileHeader(True), np.uint8)
+        out[s.info.header_offset:start] = np.frombuffer(s.info.FileHeader(True), np.uint8)
     out[pos:] = np.frombuffer(_zip_directory(infos, pos), np.uint8)
     return blob
 
@@ -660,20 +695,7 @@ def decode_params_flat(blob: "bytes | NpzEnvelope",
     blobs whose wire arrays don't embed losslessly in f32, and
     ``zipfile.BadZipFile`` for a torn or corrupted blob."""
     env = blob if isinstance(blob, NpzEnvelope) else NpzEnvelope(blob)
-    if env.direct:
-        return _flat_from_arrays(env.meta, env.view, env.copy_checked, specs)
-    with np.load(io.BytesIO(env.blob)) as data:
-        return _flat_from_arrays(env.meta, data.__getitem__, _copy_serial, specs)
-
-
-def _flat_from_arrays(meta: dict, get, copy, specs: dict) -> tuple[LeafSpec, np.ndarray, dict]:
-    """The body of ``decode_params_flat`` over either reader: ``get(key)``
-    gives a member's array, ``copy(jobs, keys)`` fills the flat from the
-    (dst, src, scale) jobs, checking the members named by ``keys`` where the
-    reader has not already."""
-    meta = dict(meta)
-    order = meta.pop("__order__")
-    dtypes = meta.pop("__dtypes__", {})
+    meta, order, dtypes = env.contents()
     if "delta_of" in meta:
         raise ValueError("delta blob: use deserialize_update_delta_flat")
     quantized = bool(meta.get("quantized"))
@@ -681,12 +703,12 @@ def _flat_from_arrays(meta: dict, get, copy, specs: dict) -> tuple[LeafSpec, np.
         # order lists the packed {"q":..., "s":...} tree; the spec
         # describes the original structure (q-keys with prefix stripped)
         leaf_keys = [k[2:] for k in order if k.startswith("q" + _SEP)]
-        arrays = {k: get("q" + _SEP + k) for k in leaf_keys}
-        scales = {k: np.float32(get("s" + _SEP + k)) for k in leaf_keys}
+        arrays = {k: env.view("q" + _SEP + k) for k in leaf_keys}
+        scales = {k: np.float32(env.view("s" + _SEP + k)) for k in leaf_keys}
         read = [p + _SEP + k for k in leaf_keys for p in "qs"]
     else:
         leaf_keys = list(order)
-        arrays = {k: get(k) for k in leaf_keys}
+        arrays = {k: env.view(k) for k in leaf_keys}
         scales = {}
         read = leaf_keys
         for k, a in arrays.items():
@@ -723,7 +745,7 @@ def _flat_from_arrays(meta: dict, get, copy, specs: dict) -> tuple[LeafSpec, np.
         i = index[k.replace(_SEP, "/")]
         o, n = offsets[i], sizes[i]
         jobs.append((flat[o:o + n], arrays[k].reshape(-1), scales.get(k)))
-    copy(jobs, read)
+    env.copy_checked(jobs, read)
     return spec, flat, meta
 
 
@@ -796,8 +818,7 @@ def content_hash(blob: bytes) -> str:
 def peek_meta(blob: bytes) -> dict[str, Any]:
     """Read only the ``__meta__`` entry of a serialized blob (cheap dispatch:
     full vs quantized vs delta) without materializing the weight arrays."""
-    with np.load(io.BytesIO(maybe_decompress(blob))) as data:
-        return json.loads(bytes(data[_META_KEY].tobytes()).decode())
+    return NpzEnvelope(blob).meta
 
 
 # --- group summaries (sharded gossip store) ---------------------------------
@@ -1082,41 +1103,38 @@ def apply_update_delta_flat(blob: bytes, spec: LeafSpec,
     copy instead of K. On a raised exception ``flat`` may be partially
     mutated — callers discard it (the exceptions signal a structure/dtype
     mismatch, never a transient)."""
-    with np.load(io.BytesIO(maybe_decompress(blob))) as data:
-        meta = json.loads(bytes(data[_META_KEY].tobytes()).decode())
-        if "delta_of" not in meta:
-            raise ValueError("not a delta blob")
-        order = meta.pop("__order__")
-        meta.pop("__dtypes__", None)
-        wire = _wire_keys(spec)
-        if len(order) != len(wire) or set(order) != set(wire):
-            raise ValueError("delta structure does not match the base spec")
-        files = set(data.files)
-        index, offsets, sizes = spec.index, spec.offsets, spec.sizes
-        for key in order:
-            i = index[key.replace(_SEP, "/")]
-            o, n = offsets[i], sizes[i]
-            if _DENSE + key in files:
-                arr = data[_DENSE + key]
-                if arr.size != n:
-                    raise ValueError(f"dense leaf {key!r}: {arr.size} vs {n}")
-                if _SCALE + key in files:  # dense int8-quantized leaf
-                    flat[o:o + n] = dequantize_leaf(
-                        arr.reshape(-1), data[_SCALE + key])
-                    continue
-                if arr.dtype.kind != "f" or arr.dtype.itemsize > 4:
-                    raise FlatDecodeUnsupported(
-                        f"leaf {key!r} has wire dtype {arr.dtype} (not f32-exact)")
-                flat[o:o + n] = arr.reshape(-1)
+    env = NpzEnvelope(blob)
+    meta, order, _dtypes = env.contents()
+    if "delta_of" not in meta:
+        raise ValueError("not a delta blob")
+    wire = _wire_keys(spec)
+    if len(order) != len(wire) or set(order) != set(wire):
+        raise ValueError("delta structure does not match the base spec")
+    data = env.read(env.files)
+    index, offsets, sizes = spec.index, spec.offsets, spec.sizes
+    for key in order:
+        i = index[key.replace(_SEP, "/")]
+        o, n = offsets[i], sizes[i]
+        if _DENSE + key in data:
+            arr = data[_DENSE + key]
+            if arr.size != n:
+                raise ValueError(f"dense leaf {key!r}: {arr.size} vs {n}")
+            if _SCALE + key in data:  # dense int8-quantized leaf
+                flat[o:o + n] = dequantize_leaf(arr.reshape(-1), data[_SCALE + key])
                 continue
-            idx = data[_IDX + key]
-            vals = data[_VAL + key]
-            if _SCALE + key in files:
-                vals = dequantize_leaf(vals, data[_SCALE + key])
-            elif vals.size and (vals.dtype.kind != "f" or vals.dtype.itemsize > 4):
+            if arr.dtype.kind != "f" or arr.dtype.itemsize > 4:
                 raise FlatDecodeUnsupported(
-                    f"leaf {key!r} delta values have wire dtype {vals.dtype}")
-            flat[o + idx] = vals
+                    f"leaf {key!r} has wire dtype {arr.dtype} (not f32-exact)")
+            flat[o:o + n] = arr.reshape(-1)
+            continue
+        idx = data[_IDX + key]
+        vals = data[_VAL + key]
+        if _SCALE + key in data:
+            vals = dequantize_leaf(vals, data[_SCALE + key])
+        elif vals.size and (vals.dtype.kind != "f" or vals.dtype.itemsize > 4):
+            raise FlatDecodeUnsupported(
+                f"leaf {key!r} delta values have wire dtype {vals.dtype}")
+        flat[o + idx] = vals
     return meta
 
 
@@ -1250,29 +1268,27 @@ def deserialize_update_delta(blob: bytes, base_params: PyTree) -> NodeUpdate:
     was diffed against (the caller is responsible for matching ``delta_of`` to
     the base blob's content hash; see WeightStore)."""
     base = _flat_wire(base_params)
-    with np.load(io.BytesIO(maybe_decompress(blob))) as data:
-        meta = json.loads(bytes(data[_META_KEY].tobytes()).decode())
-        if "delta_of" not in meta:
-            raise ValueError("not a delta blob")
-        order = meta.pop("__order__")
-        dtypes = meta.pop("__dtypes__", {})
+    env = NpzEnvelope(blob)
+    meta, order, dtypes = env.contents()
+    if "delta_of" not in meta:
+        raise ValueError("not a delta blob")
+    data = env.read(env.files)
 
-        def reconstruct(key: str) -> np.ndarray:
-            if _DENSE + key in data.files:
-                arr = data[_DENSE + key]
-                if _SCALE + key in data.files:  # dense int8-quantized leaf
-                    arr = dequantize_leaf(arr, data[_SCALE + key])
-                return arr
-            if key not in base:
-                raise DeltaBaseMismatch(f"base is missing leaf {key!r}")
-            b = base[key][0]
-            flat = np.array(b, copy=True).reshape(-1)
-            idx = data[_IDX + key]
-            vals = data[_VAL + key]
-            if _SCALE + key in data.files:
-                vals = dequantize_leaf(vals, data[_SCALE + key])
-            flat[idx] = vals.astype(flat.dtype, copy=False)
-            return flat.reshape(b.shape)
+    def reconstruct(key: str) -> np.ndarray:
+        if _DENSE + key in data:
+            arr = data[_DENSE + key]
+            if _SCALE + key in data:  # dense int8-quantized leaf
+                arr = dequantize_leaf(arr, data[_SCALE + key])
+            return arr
+        if key not in base:
+            raise DeltaBaseMismatch(f"base is missing leaf {key!r}")
+        b = base[key][0]
+        flat = np.array(b, copy=True).reshape(-1)
+        idx = data[_IDX + key]
+        vals = data[_VAL + key]
+        if _SCALE + key in data:
+            vals = dequantize_leaf(vals, data[_SCALE + key])
+        flat[idx] = vals.astype(flat.dtype, copy=False)
+        return flat.reshape(b.shape)
 
-        tree = _rebuild_tree(order, dtypes, reconstruct)
-    return _update_from_meta(tree, meta)
+    return _update_from_meta(_rebuild_tree(order, dtypes, reconstruct), meta)

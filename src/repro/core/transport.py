@@ -85,12 +85,10 @@ from .serialize import (
     apply_update_delta_flat,
     canonicalize_params,
     content_hash,
-    counting_writers,
     decode_params_flat,
     deserialize_update,
     deserialize_update_delta,
     deserialize_update_delta_flat,
-    deserialize_update_quantized,
     flat_update_from_meta,
     maybe_decompress,
     peek_meta,
@@ -470,14 +468,6 @@ class PipelineStats:
         # folder's listing token was unchanged and the parsed summary index
         # was reused without re-splitting every key
         "summary_index_hits", "summary_index_misses",
-        # flat decodes of full/quantized blobs (the delta codec's bases
-        # included) by reader: read in place from a stored envelope, or
-        # through np.load (deflated, or a member not viewable in place)
-        "direct_decodes", "npz_decodes",
-        # envelopes the pipeline wrote (pushes with their bases, history
-        # and summaries) by writer: filled in one pass as a stored npz, or
-        # through np.savez (deflated, or an array the stored layout cannot hold)
-        "direct_encodes", "savez_encodes",
     )
     _FLOAT_FIELDS = ("residual_norm", "topk_fraction_effective")
 
@@ -634,14 +624,6 @@ class Codec:
         """Drop per-node writer state (store.clear)."""
 
 
-def _decode_flat(env: NpzEnvelope, ctx: StoreContext):
-    """``decode_params_flat`` on ``ctx``'s spec table, counted by the reader
-    the blob's envelope took."""
-    out = decode_params_flat(env, ctx.specs)
-    ctx.stats.incr("direct_decodes" if env.direct else "npz_decodes")
-    return out
-
-
 class FullCodec(Codec):
     name = "full"
 
@@ -653,7 +635,7 @@ class FullCodec(Codec):
     @staticmethod
     def decode_wire(env: NpzEnvelope, ctx: StoreContext) -> NodeUpdate:
         try:
-            spec, flat, m = _decode_flat(env, ctx)
+            spec, flat, m = decode_params_flat(env, ctx.specs)
             return flat_update_from_meta(spec, flat, m)
         except FlatDecodeUnsupported:
             return deserialize_update(env.blob)
@@ -669,11 +651,9 @@ class QuantizedCodec(Codec):
 
     @staticmethod
     def decode_wire(env: NpzEnvelope, ctx: StoreContext) -> NodeUpdate:
-        try:
-            spec, flat, m = _decode_flat(env, ctx)
-            return flat_update_from_meta(spec, flat, m)
-        except FlatDecodeUnsupported:
-            return deserialize_update_quantized(env.blob)
+        # int8 leaves always embed in f32: no tree fallback is needed
+        spec, flat, m = decode_params_flat(env, ctx.specs)
+        return flat_update_from_meta(spec, flat, m)
 
 
 class _ChainState:
@@ -856,7 +836,7 @@ class DeltaCodec(Codec):
             if raw is not None and content_hash(raw) == cur:
                 env = NpzEnvelope(raw)
                 try:
-                    spec, flat, _ = _decode_flat(env, ctx)
+                    spec, flat, _ = decode_params_flat(env, ctx.specs)
                     state = (spec, flat)
                 except FlatDecodeUnsupported:
                     state = (None, deserialize_update(env.blob).params)
@@ -1192,26 +1172,22 @@ class TransportPipeline:
     def push(self, update: NodeUpdate, ctx: StoreContext) -> tuple[bytes, bool]:
         self.stats.incr("encodes")
         tel = ctx.telemetry
-        with counting_writers(self.stats.incr):
-            if tel is not None and tel.enabled:
-                with tel.span("encode"):
-                    return self.policy.encode(update, ctx)
-            return self.policy.encode(update, ctx)
+        if tel is not None and tel.enabled:
+            with tel.span("encode"):
+                return self.policy.encode(update, ctx)
+        return self.policy.encode(update, ctx)
 
     def encode_history(self, update: NodeUpdate) -> bytes:
         """Self-contained (and, for lossy policies, exact) history blob."""
-        with counting_writers(self.stats.incr):
-            return serialize_update(update, compress=self.compress_arg)
+        return serialize_update(update, compress=self.compress_arg)
 
     def encode_summary(self, summary) -> bytes:
         """Gossip group summaries ride the pipeline's envelope."""
-        with counting_writers(self.stats.incr):
-            return serialize_group_summary(summary, compress=self.compress_arg)
+        return serialize_group_summary(summary, compress=self.compress_arg)
 
     def encode_super_summary(self, summary) -> bytes:
         """Hierarchical-gossip tier folds ride the same envelope."""
-        with counting_writers(self.stats.incr):
-            return serialize_super_summary(summary, compress=self.compress_arg)
+        return serialize_super_summary(summary, compress=self.compress_arg)
 
     @property
     def compress_arg(self) -> str:

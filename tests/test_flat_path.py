@@ -7,10 +7,15 @@ FedAsync staleness), the store's flat decode reproduces the tree decode
 bitwise for every transport (full/quantized/delta/delta_q/topk), and
 flat↔tree round-trips preserve mixed-dtype pytrees exactly.
 """
+import io
+import json
+import zipfile
+
 import numpy as np
 import pytest
 from _hyp import given, settings, strategies as st
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import (
@@ -26,15 +31,18 @@ from repro.core.serialize import (
     NpzEnvelope,
     content_hash,
     decode_params_flat,
+    deserialize_params,
     deserialize_update,
     deserialize_update_delta,
     deserialize_update_quantized,
+    maybe_decompress,
     peek_meta,
     serialize_update,
     serialize_update_quantized,
 )
 from repro.core.strategies import STRATEGIES, FedAvg, FedAvgM, get_strategy
 from repro.core.strategies_ref import REF_STRATEGIES, get_ref_strategy
+from repro.core.tree import path_str
 
 
 def tree_of(vals, shift=0.0):
@@ -512,7 +520,7 @@ def test_fedavgm_state_is_flat_vectors():
     assert isinstance(strat.buf, np.ndarray) and strat.buf.dtype == np.float32
 
 
-# --- the in-place reader of stored envelopes ----------------------------------
+# --- the reader ----------------------------------------------------------------
 
 
 def _bf16_tree():
@@ -523,45 +531,75 @@ def _bf16_tree():
             "b": np.arange(6, dtype=np.float32).astype(ml_dtypes.bfloat16)}
 
 
-# name -> (blob writer taking compress=, whether the stored blob is read in place)
+# name -> blob writer taking compress=; every blob is read in place, its
+# members viewed in the stored bytes or in each deflated member inflated once
 READER_CASES = {
-    "f32": (lambda c: serialize_update(NodeUpdate(tree_of([1.5, -0.5]), 3, "n"),
-                                       compress=c), True),
-    "bf16_widened": (lambda c: serialize_update(NodeUpdate(_bf16_tree(), 3, "n"),
-                                                compress=c), True),
-    "f16": (lambda c: serialize_update(NodeUpdate(
-        {"w": np.linspace(-2, 2, 30, dtype=np.float16).reshape(5, 6)}, 3, "n"),
-        compress=c), True),
-    "quantized": (lambda c: serialize_update_quantized(
-        NodeUpdate(tree_of([2.0, 1.0]), 3, "n"), compress=c), True),
-    "zstd": (lambda c: serialize_update(NodeUpdate(tree_of([0.25, 2.0]), 3, "n"),
-                                        compress="zstd" if c == "none" else c), True),
-    "node_id_slash_dunder": (lambda c: serialize_update(
-        NodeUpdate(tree_of([1.0, 1.0]), 3, "site/a__b"), compress=c), True),
+    "f32": lambda c: serialize_update(NodeUpdate(tree_of([1.5, -0.5]), 3, "n"), compress=c),
+    "bf16_widened": lambda c: serialize_update(NodeUpdate(_bf16_tree(), 3, "n"), compress=c),
+    "f16": lambda c: serialize_update(NodeUpdate(
+        {"w": np.linspace(-2, 2, 30, dtype=np.float16).reshape(5, 6)}, 3, "n"), compress=c),
+    "quantized": lambda c: serialize_update_quantized(
+        NodeUpdate(tree_of([2.0, 1.0]), 3, "n"), compress=c),
+    "zstd": lambda c: serialize_update(NodeUpdate(tree_of([0.25, 2.0]), 3, "n"),
+                                       compress="zstd" if c == "none" else c),
+    "node_id_slash_dunder": lambda c: serialize_update(
+        NodeUpdate(tree_of([1.0, 1.0]), 3, "site/a__b"), compress=c),
     # above the inline and piece sizes: pool tasks and a leaf cut in pieces
-    "big_leaf": (lambda c: serialize_update(NodeUpdate(
+    "big_leaf": lambda c: serialize_update(NodeUpdate(
         {"big": np.arange(9 * 2**20, dtype=np.float32) * np.float32(1e-3),
-         "small": np.ones((3,), np.float32)}, 3, "n"), compress=c), True),
-    "fortran_leaf": (lambda c: serialize_update(NodeUpdate(
+         "small": np.ones((3,), np.float32)}, 3, "n"), compress=c),
+    "fortran_leaf": lambda c: serialize_update(NodeUpdate(
         {"w": np.asfortranarray(np.arange(12, dtype=np.float32).reshape(3, 4))},
-        3, "n"), compress=c), False),
+        3, "n"), compress=c),
 }
+
+
+def _np_load(blob) -> dict:
+    """Every member of ``blob`` as np.load reads it, in the blob's order."""
+    with np.load(io.BytesIO(maybe_decompress(blob))) as data:
+        return {k: data[k] for k in data.files}
+
+
+def _np_load_meta(blob) -> dict:
+    return json.loads(_np_load(blob)["__meta__"].tobytes().decode())
+
+
+def _np_load_flat(blob, spec) -> np.ndarray:
+    """The f32 flat of a full or quantized blob, in ``spec``'s leaf order,
+    built from what np.load reads."""
+    data, quantized = _np_load(blob), _np_load_meta(blob).get("quantized")
+    parts = []
+    for path in spec.paths:
+        key = path.replace("/", "|")
+        if quantized:
+            parts.append(data["q|" + key].astype(np.float32) * np.float32(data["s|" + key]))
+        else:
+            parts.append(data[key].astype(np.float32))
+    return np.concatenate([p.reshape(-1) for p in parts])
+
+
+def _assert_bitwise(a: np.ndarray, b: np.ndarray) -> None:
+    assert a.dtype == b.dtype and a.shape == b.shape
+    assert a.flags.f_contiguous == b.flags.f_contiguous
+    assert np.ascontiguousarray(a).tobytes() == np.ascontiguousarray(b).tobytes()
 
 
 @pytest.mark.parametrize("case", sorted(READER_CASES))
 def test_direct_reader_matches_np_load_bitwise(case):
-    """A stored blob read in place and the same update deflated (read by
-    np.load) give bitwise-equal flats, the same spec and the same meta."""
-    write, direct = READER_CASES[case]
-    stored, deflated = NpzEnvelope(write("none")), NpzEnvelope(write("npz"))
-    assert stored.direct is direct and deflated.direct is False
-    spec_a, flat_a, meta_a = decode_params_flat(stored, {})
-    spec_b, flat_b, meta_b = decode_params_flat(deflated, {})
-    assert flat_a.dtype == flat_b.dtype == np.float32
-    assert flat_a.view(np.uint32).tobytes() == flat_b.view(np.uint32).tobytes()
-    assert (spec_a.key, spec_a.paths, spec_a.shapes, spec_a.dtypes) == \
-        (spec_b.key, spec_b.paths, spec_b.shapes, spec_b.dtypes)
-    assert meta_a == meta_b
+    """A blob read in place, stored or deflated, gives bit for bit the flat
+    that np.load's arrays give, and the same spec and meta either way."""
+    decoded = []
+    for compress in ("none", "npz"):
+        blob = READER_CASES[case](compress)
+        spec, flat, meta = decode_params_flat(NpzEnvelope(blob), {})
+        assert flat.dtype == np.float32
+        want = _np_load_flat(blob, spec)
+        assert flat.view(np.uint32).tobytes() == want.view(np.uint32).tobytes()
+        want_meta = _np_load_meta(blob)
+        del want_meta["__order__"], want_meta["__dtypes__"]
+        assert meta == want_meta
+        decoded.append((spec.key, spec.paths, spec.shapes, spec.dtypes, meta))
+    assert decoded[0] == decoded[1]
 
 
 def _flip_inside(blob: bytes, payload: bytes) -> bytes:
@@ -571,22 +609,29 @@ def _flip_inside(blob: bytes, payload: bytes) -> bytes:
     return blob[:at] + bytes([blob[at] ^ 0x40]) + blob[at + 1:]
 
 
-@pytest.mark.parametrize("where", ["small_leaf", "big_leaf", "meta"])
+@pytest.mark.parametrize("where", ["small_leaf", "big_leaf", "meta", "deflated_leaf",
+                                   "deflated_crc"])
 def test_direct_reader_rejects_a_flipped_byte(where):
-    import zipfile
-
     big = np.arange(2**19, dtype=np.float32) + np.float32(0.5)  # 2 MiB: the pool's
     small = np.asarray([7.25, -1.5, 3.0], np.float32)
-    blob = serialize_update(NodeUpdate({"big": big, "small": small}, 3, "node-x"))
-    payload = {"small_leaf": small.tobytes(), "big_leaf": big.tobytes(),
-               "meta": b'"node-x"'}[where]
+    update = NodeUpdate({"big": big, "small": small}, 3, "node-x")
+    if where.startswith("deflated"):
+        blob = serialize_update(update, compress="npz")
+        if where == "deflated_leaf":  # a byte in the middle of big's deflate stream
+            zi = zipfile.ZipFile(io.BytesIO(blob)).getinfo("big.npy")
+            at = zi.header_offset + 30 + len("big.npy") + 20 + zi.compress_size // 2
+        else:  # the CRC-32 in big's directory entry: only the check sees it
+            at = blob.rfind(b"big.npy") - 46 + 16
+        blob = blob[:at] + bytes([blob[at] ^ 0x40]) + blob[at + 1:]
+    else:
+        payload = {"small_leaf": small.tobytes(), "big_leaf": big.tobytes(),
+                   "meta": b'"node-x"'}[where]
+        blob = _flip_inside(serialize_update(update), payload)
     with pytest.raises(zipfile.BadZipFile):
-        decode_params_flat(_flip_inside(blob, payload), {})
+        decode_params_flat(blob, {})
 
 
 def test_direct_reader_rejects_a_truncated_blob():
-    import zipfile
-
     blob = serialize_update(NodeUpdate(
         {"w": np.arange(4096, dtype=np.float32)}, 3, "n"))
     cut = blob.find(np.arange(4096, dtype=np.float32).tobytes()) + 1000
@@ -606,9 +651,27 @@ def test_direct_reader_int_leaves_still_unsupported():
         {"ids": np.asarray([2**40 + 3, 7], np.int64),
          "w": np.ones((2,), np.float32)}, 1, "n"))
     env = NpzEnvelope(blob)
-    assert env.direct
+    _assert_bitwise(env.view("ids"), _np_load(blob)["ids"])  # in place, exact
     with pytest.raises(FlatDecodeUnsupported):
         decode_params_flat(env, {})
+
+
+def _np_load_pulled(folder, node: str, spec) -> np.ndarray:
+    """What a reader of ``latest/<node>`` must reconstruct, from np.load: the
+    full blob's flat, or its delta's entries over its base's flat."""
+    blob = folder.get(f"latest/{node}")
+    meta = _np_load_meta(blob)
+    if "delta_of" not in meta:
+        return _np_load_flat(blob, spec)
+    flat = _np_load_flat(folder.get(f"base/{node}/{meta['delta_of']}"), spec)
+    data = _np_load(blob)
+    for i, path in enumerate(spec.paths):
+        key, o, n = path.replace("/", "|"), spec.offsets[i], spec.sizes[i]
+        if "d|" + key in data:
+            flat[o:o + n] = data["d|" + key].reshape(-1)
+        else:
+            flat[o + data["i|" + key]] = data["v|" + key]
+    return flat
 
 
 @pytest.mark.parametrize("transport,compress", [
@@ -616,9 +679,8 @@ def test_direct_reader_int_leaves_still_unsupported():
     ("full", "npz"),
 ])
 def test_store_pull_counts_decodes_by_reader(transport, compress):
-    """Every flat decode a store makes is counted once, by the reader its
-    blob's envelope took: in place for stored envelopes, np.load for
-    deflated ones."""
+    """Every blob a store pulls is decoded once, and each pulled flat is bit
+    for bit what np.load's arrays of the folder's blobs give."""
     from repro.api import connect
 
     uri = f"memory://flat-reader-{transport}-{compress}"
@@ -628,12 +690,12 @@ def test_store_pull_counts_decodes_by_reader(transport, compress):
         for node in ("a", "b"):
             writer.push(NodeUpdate(tree_of([1.0 + ctr, 0.5]), num_examples=1,
                                    node_id=node, counter=ctr))
-        assert len(reader.pull()) == 2
-    stats = reader.transport_stats()
-    counted, silent = (("npz_decodes", "direct_decodes") if compress == "npz"
-                       else ("direct_decodes", "npz_decodes"))
-    assert stats[silent] == 0
-    assert stats[counted] >= stats["decodes"] == 4
+        pulled = reader.pull()
+        assert len(pulled) == 2
+        for u in pulled:
+            want = _np_load_pulled(writer.folder, u.node_id, u.spec)
+            assert u.flat.view(np.uint32).tobytes() == want.view(np.uint32).tobytes()
+    assert reader.transport_stats()["decodes"] == 4
 
 
 def test_concurrent_direct_decodes_share_the_pool():
@@ -672,7 +734,7 @@ def test_concurrent_direct_decodes_share_the_pool():
         np.testing.assert_array_equal(flat, want)
 
 
-# --- the stored writer ----------------------------------------------------------
+# --- the writer -------------------------------------------------------------------
 
 
 def _wide_bf16(n):
@@ -711,28 +773,39 @@ ARRAY_CASES = {
                              "bool": np.arange(10) % 3 == 0,
                              "text": np.asarray(["ab", "cde"]),
                              "uint8": np.arange(256, dtype=np.uint8)},
+    "datetime_and_bytes": lambda: {
+        "when": np.arange("2024-01-01", "2024-03-01", dtype="M8[D]").reshape(4, -1)[:, ::2],
+        "took": np.asarray([3, -1, 7], "m8[ms]"),
+        "raw": np.asarray([b"ab", b"", b"cde\x00f"])},
     "non_ascii_key": lambda: {"wé|b": np.ones((3,), np.float32)},
 }
 
 
 def _meta_bytes(update, order, dtypes) -> bytes:
     """The __meta__ member serialize_update writes for ``update``."""
-    import json
-
     from repro.core.serialize import _update_meta
 
     return json.dumps({**_update_meta(update), "__order__": order,
                        "__dtypes__": dtypes}).encode()
 
 
-def _savez(arrays) -> bytes:
-    import io
-
+def _savez(arrays, deflate: bool = False) -> bytes:
+    """What np.savez (np.savez_compressed with ``deflate``) writes for
+    ``arrays``, each widened as it ships."""
     from repro.core import serialize
 
     buf = io.BytesIO()
-    np.savez(buf, **{k: serialize._wire_leaf(a)[0] for k, a in arrays.items()})
+    (np.savez_compressed if deflate else np.savez)(
+        buf, **{k: serialize._wire_leaf(a)[0] for k, a in arrays.items()})
     return buf.getvalue()
+
+
+def _savez_of(blob) -> bytes:
+    """np.savez (or np.savez_compressed, for a deflated blob) over what
+    np.load reads from ``blob``."""
+    blob = maybe_decompress(blob)
+    deflated = zipfile.ZipFile(io.BytesIO(blob)).infolist()[0].compress_type
+    return _savez(_np_load(blob), deflate=deflated == zipfile.ZIP_DEFLATED)
 
 
 @pytest.fixture(params=["inline", "pool"])
@@ -755,94 +828,137 @@ def writer_sizes(request, monkeypatch):
     return request.param
 
 
-@pytest.mark.parametrize("case", sorted(ARRAY_CASES))
+def _with_deflated(cases) -> list[str]:
+    """Each case stored, then each deflated: ``<case>+npz``."""
+    return [c + how for how in ("", "+npz") for c in cases]
+
+
+@pytest.mark.parametrize("case", _with_deflated(sorted(ARRAY_CASES)))
 def test_stored_writer_matches_np_savez_bytes(case, writer_sizes):
+    """The writer gives np.savez's bytes (np.savez_compressed's, deflated),
+    and the reader reads back from them what np.load does."""
     from repro.core import serialize
 
+    case, _, how = case.partition("+")
+    deflate = how == "npz"
     arrays = ARRAY_CASES[case]()
     arrays["__meta__"] = np.frombuffer(b'{"__order__": [], "x": 1}', np.uint8)
-    assert serialize._write_stored(dict(arrays)) == _savez(arrays)
+    blob = serialize._write_stored(dict(arrays), deflate=deflate)
+    assert blob == _savez(arrays, deflate)
+    env, want = NpzEnvelope(blob), _np_load(blob)
+    got = env.read(env.files)
+    assert list(got) == list(want)
+    for key in want:
+        _assert_bitwise(got[key], want[key])
+        assert got[key].flags.writeable
+        _assert_bitwise(env.view(key), want[key])
 
 
-@pytest.mark.parametrize("case", sorted(READER_CASES) + ["fleet_meta_only"])
+@pytest.mark.parametrize("case", _with_deflated(sorted(READER_CASES) + ["fleet_meta_only"]))
 def test_every_envelope_writer_matches_the_np_savez_path(case, writer_sizes, monkeypatch):
-    """Each blob writer's stored envelope, filled in one pass, is byte for
-    byte what the same call writes through np.savez."""
+    """Each blob writer's envelope is byte for byte what np.savez (deflated:
+    np.savez_compressed) writes for the arrays it was handed; ``peek_meta``
+    and ``deserialize_params`` read from it what np.load reads."""
     from repro.core import serialize
 
+    case, _, compress = case.partition("+")
+    compress = compress or "none"
     if case == "fleet_meta_only":  # no arrays: a lone uint8 __meta__
         def write(c):
             return serialize.serialize_fleet_blob("heartbeat", {"t": 1}, compress=c)
     else:
-        write = READER_CASES[case][0]
-    counted = []
-    with serialize.counting_writers(counted.append):
-        direct = write("none")
-        monkeypatch.setattr(serialize, "_write_stored", lambda arrays: None)
-        savez = write("none")
-    assert direct == savez
-    assert counted == ["direct_encodes", "savez_encodes"]
+        write = READER_CASES[case]
+    handed, write_stored = [], serialize._write_stored
+
+    def recording(arrays, *, deflate=False):
+        handed.append(_savez(arrays, deflate))
+        return write_stored(arrays, deflate=deflate)
+
+    monkeypatch.setattr(serialize, "_write_stored", recording)
+    blob = write(compress)
+    assert len(handed) == 1 and maybe_decompress(blob) == handed[0]
+    data, meta = _np_load(blob), _np_load_meta(blob)
+    assert peek_meta(blob) == meta
+    params, rest = deserialize_params(blob)
+    assert rest == {k: v for k, v in meta.items() if k not in ("__order__", "__dtypes__")}
+    leaves = {path_str(p).replace("/", "|"): leaf
+              for p, leaf in jax.tree_util.tree_flatten_with_path(params)[0]}
+    assert sorted(leaves) == sorted(meta["__order__"])
+    for key, leaf in leaves.items():
+        _assert_bitwise(np.asarray(leaf).astype(data[key].dtype), data[key])
+
+
+def _npy_file(arr) -> bytes:
+    buf = io.BytesIO()
+    np.lib.format.write_array(buf, arr)
+    return buf.getvalue()
 
 
 def test_stored_writer_hands_what_it_cannot_hold_to_np_savez():
+    """An object array (np.savez would pickle it) or a structured one is
+    refused by name, stored or deflated; and the reader refuses members this
+    writer cannot have written: pickled, a format 2.0 header, not .npy."""
     from repro.core import serialize
 
-    assert serialize._write_stored(
-        {"rec": np.zeros(3, dtype=[("a", "<f4"), ("b", "<i2")])}) is None
-    arrays = {"o": np.asarray([1, "a"], dtype=object)}
-    assert serialize._write_stored(dict(arrays)) is None
-    counted = []
-    with serialize.counting_writers(counted.append):
-        blob = serialize._pack_npz(dict(arrays), ["o"], {}, None)
-    assert blob == _savez({**arrays, "__meta__": np.frombuffer(
-        b'{"__order__": ["o"], "__dtypes__": {}}', np.uint8)})
-    assert counted == ["savez_encodes"]
+    for key, arr in (("rec", np.zeros(3, dtype=[("a", "<f4"), ("b", "<i2")])),
+                     ("o", np.asarray([1, "a"], dtype=object))):
+        for deflate in (False, True):
+            with pytest.raises(ValueError, match=repr(key)):
+                serialize._write_stored({key: arr}, deflate=deflate)
+    with pytest.raises(ValueError, match="'o'"):
+        serialize._pack_npz({"o": np.asarray([1, "a"], dtype=object)}, ["o"], {}, None)
+    meta = np.frombuffer(b'{"__order__": ["x"], "__dtypes__": {}}', np.uint8)
+    pickled = io.BytesIO()
+    np.savez(pickled, x=np.asarray([1, "a"], dtype=object), __meta__=meta)
+    v2 = io.BytesIO()
+    with zipfile.ZipFile(v2, "w") as zf:
+        zf.writestr("__meta__.npy", _npy_file(meta))
+        with zf.open("x.npy", "w") as f:
+            np.lib.format.write_array(f, np.ones(3, np.float32), version=(2, 0))
+    text = io.BytesIO()
+    with zipfile.ZipFile(text, "w") as zf:
+        zf.writestr("__meta__.npy", _npy_file(meta))
+        zf.writestr("x.txt", b"not an array")
+    for blob in (pickled.getvalue(), v2.getvalue(), text.getvalue()):
+        with pytest.raises(ValueError):
+            deserialize_params(blob)
 
 
-@pytest.mark.parametrize("fallback", ["zip64_member", "deflate"])
+@pytest.mark.parametrize("fallback", ["zip64_member", "deflate", "zip64_deflated_member"])
 def test_fallbacks_write_np_savez_bytes_and_count_as_savez(fallback, monkeypatch):
     """A member too big for the directory's 32-bit sizes (zipfile's
-    ZIP64_LIMIT, lowered here) and a deflated envelope go through np.savez,
-    and are counted so."""
-    import io
-    import zipfile
-
-    from repro.core import serialize
-
+    ZIP64_LIMIT, lowered here), stored or deflated, and a deflated envelope
+    are written as np.savez (np.savez_compressed) writes them, and read back
+    in place to what np.load reads."""
+    deflate = fallback != "zip64_member"
     update = NodeUpdate({"w": np.arange(600, dtype=np.float32), "b": _wide_bf16(9)},
                         3, "n")
-    if fallback == "zip64_member":
+    if fallback.startswith("zip64"):
         monkeypatch.setattr(zipfile, "ZIP64_LIMIT", 2000)
-    counted = []
-    with serialize.counting_writers(counted.append):
-        blob = serialize_update(update, compress="npz" if fallback == "deflate" else "none")
-    assert counted == ["savez_encodes"]
+    blob = serialize_update(update, compress="npz" if deflate else "none")
     arrays = {"b": _wide_bf16(9), "w": np.arange(600, dtype=np.float32)}
     arrays["__meta__"] = np.frombuffer(_meta_bytes(update, ["b", "w"], {"b": "bfloat16"}),
                                        np.uint8)
-    buf = io.BytesIO()
-    (np.savez_compressed if fallback == "deflate" else np.savez)(
-        buf, **{k: serialize._wire_leaf(a)[0] for k, a in arrays.items()})
-    assert blob == buf.getvalue()
+    assert blob == _savez(arrays, deflate)
+    big = zipfile.ZipFile(io.BytesIO(blob)).getinfo("w.npy")
+    assert big.file_size > 2000 and big.compress_type == (
+        zipfile.ZIP_DEFLATED if deflate else zipfile.ZIP_STORED)
+    spec, flat, _meta = decode_params_flat(blob, {})
+    assert flat.view(np.uint32).tobytes() == _np_load_flat(blob, spec).view(np.uint32).tobytes()
 
 
 def test_offsets_past_zip64_limit_are_written_directly(monkeypatch):
     """A blob over zipfile's ZIP64_LIMIT (lowered here), with every member
-    under it, keeps the stored writer: zip64 offsets in the directory and a
-    zip64 end record, as np.savez writes them, and the reader reads it."""
-    import zipfile
-
-    from repro.core import serialize
-
+    under it: zip64 offsets in the directory and a zip64 end record, as
+    np.savez writes them, and the reader reads it."""
     monkeypatch.setattr(zipfile, "ZIP64_LIMIT", 1500)
     tree = {f"l{i}": np.arange(150, dtype=np.float32) * np.float32(i) for i in range(12)}
-    counted = []
-    with serialize.counting_writers(counted.append):
-        blob = serialize_update(NodeUpdate(tree, 3, "n"))
-        monkeypatch.setattr(serialize, "_write_stored", lambda arrays: None)
-        savez = serialize_update(NodeUpdate(tree, 3, "n"))
-    assert counted == ["direct_encodes", "savez_encodes"] and blob == savez
-    assert len(blob) > 1500 and zipfile.ZipFile(__import__("io").BytesIO(blob)).testzip() is None
+    update = NodeUpdate(tree, 3, "n")
+    blob = serialize_update(update)
+    order = sorted(tree)
+    assert blob == _savez({**{k: tree[k] for k in order}, "__meta__": np.frombuffer(
+        _meta_bytes(update, order, {}), np.uint8)})
+    assert len(blob) > 1500 and zipfile.ZipFile(io.BytesIO(blob)).testzip() is None
     spec, flat, _meta = decode_params_flat(blob, {})
     np.testing.assert_array_equal(spec.unflatten(flat)["l11"], tree["l11"])
 
@@ -855,54 +971,55 @@ def _codec_params(ctr: int, node: str) -> dict:
     return {"w": w, "b": _wide_bf16(512)}
 
 
-# transport -> flat decodes in place a reader makes over three rounds of two
-# nodes: every full blob it pulls, and for the delta codecs each node's base
+# transport -> flat decodes a reader makes over three rounds of two nodes:
+# every full blob it pulls, and for the delta codecs each node's base
 CODEC_DIRECT_DECODES = {"full": 6, "quantized": 6, "delta": 4, "delta_q": 4, "topk": 4}
 
 
 @pytest.mark.parametrize("transport", sorted(CODEC_DIRECT_DECODES))
 def test_every_codec_writes_np_savez_bytes(transport, monkeypatch):
-    """Two stores take the same pushes, one through the stored writer and one
-    through np.savez: after every round the two folders hold the same
-    blobs, and a reader of the first still decodes every full blob in place."""
+    """After every round each blob in the folder is what np.savez writes over
+    what np.load reads from it, and a reader decodes each full blob once."""
     from repro.api import connect
-    from repro.core import serialize
+    from repro.core import transport as transport_mod
 
     uri = f"memory://writers-{transport}"
-    direct, reader = connect(uri, transport=transport), connect(uri, transport=transport)
-    savez = connect(uri + "-savez", transport=transport)
+    writer, reader = connect(uri, transport=transport), connect(uri, transport=transport)
+    flat_decodes, decode = [], transport_mod.decode_params_flat
+
+    def counting(env, specs):
+        flat_decodes.append(specs is reader._ctx.specs)
+        return decode(env, specs)
+
+    monkeypatch.setattr(transport_mod, "decode_params_flat", counting)
     for ctr in range(3):
-        for store in (direct, savez):
-            with monkeypatch.context() as m:
-                if store is savez:
-                    m.setattr(serialize, "_write_stored", lambda arrays: None)
-                for node in ("a", "b"):
-                    store.push(NodeUpdate(_codec_params(ctr, node), num_examples=1,
-                                          node_id=node, counter=ctr, timestamp=float(ctr)))
-        keys = sorted(direct.folder.keys())
-        assert keys == sorted(savez.folder.keys())
+        for node in ("a", "b"):
+            writer.push(NodeUpdate(_codec_params(ctr, node), num_examples=1,
+                                   node_id=node, counter=ctr, timestamp=float(ctr)))
+        keys = sorted(writer.folder.keys())
         for key in keys:
-            assert direct.folder.get(key) == savez.folder.get(key), (ctr, key)
+            blob = writer.folder.get(key)
+            assert blob == _savez_of(blob), (ctr, key)
         assert len(reader.pull()) == 2
     if transport not in ("full", "quantized"):
-        assert any("delta_of" in peek_meta(direct.folder.get(key)) for key in keys)
-    wrote = direct.transport_stats()
-    assert wrote["direct_encodes"] > 0 and wrote["savez_encodes"] == 0
-    assert savez.transport_stats()["savez_encodes"] == wrote["direct_encodes"]
-    read = reader.transport_stats()
-    assert read["npz_decodes"] == 0
-    assert read["direct_decodes"] == CODEC_DIRECT_DECODES[transport]
+        assert any("delta_of" in peek_meta(writer.folder.get(key)) for key in keys)
+    assert sum(flat_decodes) == CODEC_DIRECT_DECODES[transport]
 
 
 def test_full_transport_node_on_bf16_model_writes_directly():
+    """A node's bf16 update is shipped as np.savez writes the tree widened to
+    f32."""
     from repro.core import AsyncFederatedNode
 
-    node = AsyncFederatedNode(shared_folder=InMemoryFolder(), node_id="a")
+    folder = InMemoryFolder()
+    node = AsyncFederatedNode(shared_folder=folder, node_id="a")
+    tree = {"w": _wide_bf16(64).reshape(8, 8), "b": _wide_bf16(8)}
     for _ in range(2):
-        node.update_parameters({"w": _wide_bf16(64).reshape(8, 8), "b": _wide_bf16(8)},
-                               num_examples=1)
-    stats = node.transport_stats()
-    assert stats["direct_encodes"] > 0 and stats["savez_encodes"] == 0
+        node.update_parameters(tree, num_examples=1)
+    blob = folder.get("latest/a")
+    assert blob == _savez({"b": tree["b"].astype(np.float32),
+                           "w": tree["w"].astype(np.float32),
+                           "__meta__": _np_load(blob)["__meta__"]})
 
 
 def test_crc32_combine_matches_zlib():
@@ -960,4 +1077,5 @@ def test_concurrent_stored_writes_share_the_pool():
     assert len(results) == 36
     for i, blob in results:
         assert blob == want[i]
-    assert serialize.NpzEnvelope(results[0][1]).direct
+    i, blob = results[0]
+    np.testing.assert_array_equal(NpzEnvelope(blob).read(["a"])["a"], update(i).params["a"])
