@@ -199,6 +199,19 @@ class _BaseNode:
             self.store.push_strategy_state(
                 self.node_id, self.strategy.name, self.counter, state)
 
+    def _aggregate(self, own: NodeUpdate, peers: list[NodeUpdate]) -> PyTree:
+        """The strategy's aggregate, timed; the chunks its combine sent to the
+        codec pool go to the ``aggregate.pool_chunks`` counter."""
+        strategy, tel = self.strategy, self.telemetry
+        chunks = strategy.pool_chunks
+        with tel.span("aggregate"):
+            aggregated = strategy.aggregate(own, peers)
+        tel.count("aggregate.pool_chunks", strategy.pool_chunks - chunks)
+        self.num_aggregations += 1
+        if self.persist_strategy_state:
+            self._persist_strategy_state()
+        return aggregated
+
     def _push(self, params: PyTree, num_examples: int, metrics: dict | None = None) -> NodeUpdate:
         update = NodeUpdate(
             params=tree_to_numpy(params),
@@ -254,12 +267,7 @@ class AsyncFederatedNode(_BaseNode):
             # far behind our own counter each pulled peer update is.
             for u in peers:
                 tel.observe_staleness(own.counter - u.counter)
-        with tel.span("aggregate"):
-            aggregated = self.strategy.aggregate(own, peers)
-        self.num_aggregations += 1
-        if self.persist_strategy_state:
-            self._persist_strategy_state()
-        return self._finish_step(aggregated)
+        return self._finish_step(self._aggregate(own, peers))
 
 
 class SyncFederatedNode(_BaseNode):
@@ -322,10 +330,6 @@ class SyncFederatedNode(_BaseNode):
                 tel.observe_staleness(own.counter - u.counter)
         # Deterministic aggregation order across clients → identical results.
         peers.sort(key=lambda u: u.node_id)
-        with tel.span("aggregate"):
-            aggregated = self.strategy.aggregate(own, peers)
-        self.num_aggregations += 1
-        if self.persist_strategy_state:
-            self._persist_strategy_state()
+        aggregated = self._aggregate(own, peers)
         self._finish_step(aggregated)
         return aggregated

@@ -8,19 +8,22 @@ Reddi et al. 2021) therefore keep their momentum/moment buffers *on the
 client*, which is exactly the paper's "each client may implement its own
 aggregation strategy" property.
 
-Flat-vector hot path (this module's execution model): the store pulls
-``FlatUpdate``s — contiguous f32 vectors sharing one interned ``LeafSpec``
-per model structure — and every strategy aggregates them *vectorized over
-stacked flats*. There is no per-leaf Python loop anywhere on the steady-state
-path: peers' rows are copied into a reusable (K, N) stack only when their
-flat actually changed (decode-cache hits contribute zero copies), every
-combine is one BLAS matvec or one Pallas ``fed_agg`` kernel launch
-(``use_kernel=True``, plumbed through the ``Strategy`` base so *every*
-strategy honors it), and adaptive strategies keep their momentum/moment
-buffers as flat vectors with a fused pseudo-gradient+moment kernel
-(``fed_opt``). The aggregate is unflattened into the model's pytree exactly
-once, at the trainer boundary. The per-leaf reference implementations live in
-``strategies_ref.py`` (property-tested to match within 1e-6).
+Execution model: the store pulls ``FlatUpdate``s — contiguous f32 vectors
+sharing one interned ``LeafSpec`` per model structure — and the node's own
+update is a tree of host leaves in the model's dtypes. Every combine
+``Σ_k c_k·x_k`` is one pass (``_combine``): each update is read where it lies
+(a peer's flat in place, own's leaves in their own dtype), each element is
+accumulated in f32, and the result is stored once, straight into leaves of the
+spec's dtypes or, for the strategies that keep an f32 average as state
+(FedAvgM and the FedOpt family), into their f32 vector. Big leaves are cut
+into fixed chunks that run on the codec pool; a model whose chunks are all
+small runs on the caller's thread. ``use_kernel=True`` (plumbed through the
+``Strategy`` base so *every* strategy honors it) instead stacks the updates
+into a (K, N) array for one Pallas ``fed_agg`` launch, or the fused
+pseudo-gradient+moment kernel (``fed_opt``). Adaptive strategies keep their
+momentum/moment buffers as flat vectors. The per-leaf reference
+implementations live in ``strategies_ref.py`` (property-tested to match
+within 1e-6).
 
 Aggregation arithmetic is float32 — the same contract as the Pallas kernels
 and the wire transports (quantized/delta values are f32-centric). Models with
@@ -43,59 +46,84 @@ import re
 from abc import ABC, abstractmethod
 from typing import Sequence
 
+import jax
 import numpy as np
 
-from .serialize import NodeUpdate
+from .serialize import _INLINE_BYTES, NodeUpdate, _run_tasks
 from .tree import LeafSpec, PyTree
 
 
-def _combine_flat(stacked: np.ndarray, coeffs: np.ndarray, *,
-                  use_kernel: bool = False,
-                  out: np.ndarray | None = None) -> np.ndarray:
-    """Σ_k coeffs[k]·stacked[k] — THE aggregation primitive. One BLAS matvec
-    (single pass over the (K, N) stack) or one generalized ``fed_agg`` kernel
-    launch; the coefficients need not be normalized, which is what lets
-    FedAsync's lerp chain and weighted means share this code. ``out`` (a warm
-    buffer) skips the fresh-page allocation, which at 10^8 params costs more
-    than the matvec itself."""
-    if use_kernel and stacked.shape[0] > 1:
-        from repro.kernels.fed_agg import ops as fed_agg_ops
-
-        return np.asarray(fed_agg_ops.aggregate_flat(stacked, coeffs))
-    if out is None:
-        out = np.empty(stacked.shape[1], np.float32)
-    return np.dot(coeffs, stacked, out=out)
+# Elements of one combine task at most. A task whose f32 accumulator holds
+# ``_INLINE_BYTES`` or more runs on the codec pool, a smaller one (a small
+# leaf, a big leaf's tail) on the caller's thread: ``_run_tasks``'s rule.
+_CHUNK = 1 << 20
 
 
-class _StackCache:
-    """Reusable (K, N) stacked-flats buffer. A row is recopied only when its
-    source flat is a *different array object* than last round — the store's
-    decode cache returns the same ndarray for an unchanged peer, so in steady
-    state stacking costs zero copies (only the caller's own fresh row moves).
-    Tree-only updates (no flat) are flattened straight into their row — the
-    allocation-free trainer boundary."""
+def _leaf_sources(spec: LeafSpec, u: NodeUpdate) -> list[np.ndarray]:
+    """Each leaf of ``u`` as a 1-D array where it lies: slices of a flat
+    that ``spec`` describes, else the tree's own leaves in their own dtype."""
+    flat = getattr(u, "flat", None)
+    if flat is not None and spec.compatible(u.spec):
+        return [flat[o:o + n] for o, n in zip(spec.offsets.tolist(), spec.sizes)]
+    leaves = jax.tree.leaves(u.params)
+    if len(leaves) != len(spec.sizes):
+        raise ValueError(f"{len(leaves)} leaves vs spec's {len(spec.sizes)}")
+    out = []
+    for n, leaf in zip(spec.sizes, leaves):
+        arr = np.asarray(leaf).reshape(-1)
+        if arr.size != n:
+            raise ValueError(f"leaf size {arr.size} vs spec's {n}")
+        out.append(arr)
+    return out
 
-    def __init__(self):
-        self._rows: list = []  # source ndarray per row (held → ids stay valid)
-        self._buf: np.ndarray | None = None
 
-    def stack(self, spec: LeafSpec, updates: Sequence[NodeUpdate]) -> np.ndarray:
-        k, n = len(updates), spec.num_params
-        buf = self._buf
-        if buf is None or buf.shape != (k, n):
-            buf = np.empty((k, n), np.float32)
-            self._buf = buf
-            self._rows = [None] * k
-        for i, u in enumerate(updates):
-            flat = getattr(u, "flat", None)
-            if flat is not None and spec.compatible(u.spec):
-                if self._rows[i] is not flat:
-                    buf[i] = flat
-                self._rows[i] = flat
-            else:
-                spec.flatten_into(u.params, buf[i])
-                self._rows[i] = None  # trees are rewritten every round
-        return buf
+def _axpy(dst: np.ndarray, srcs: list, coeffs: list) -> None:
+    """dst = Σ_k coeffs[k]·srcs[k], each source widened to f32 and the sum
+    accumulated in f32 in the sources' order, then cast to ``dst``'s dtype."""
+    acc = dst if dst.dtype == np.float32 else np.empty(dst.size, np.float32)
+    np.multiply(srcs[0], coeffs[0], out=acc, dtype=np.float32)
+    if len(srcs) > 1:
+        tmp = np.empty(dst.size, np.float32)
+        for src, c in zip(srcs[1:], coeffs[1:]):
+            np.multiply(src, c, out=tmp, dtype=np.float32)
+            acc += tmp
+    if acc is not dst:
+        np.copyto(dst, acc, casting="unsafe")
+
+
+def _combine(spec: LeafSpec, updates: Sequence[NodeUpdate], coeffs: np.ndarray,
+             dst: list, leaves: Sequence[int]) -> int:
+    """Σ_k coeffs[k]·updates[k] — THE aggregation primitive — into ``dst[i]``
+    (1-D, any dtype) for each leaf index ``i`` in ``leaves``. One pass: each
+    update is read where it lies (``_leaf_sources``), each element
+    accumulated in f32 and stored once. Leaves are cut into chunks of
+    ``_CHUNK`` elements that run on the codec pool, the rest on the caller's
+    thread; the result does not depend on the cut. The coefficients need not
+    be normalized, which is what lets FedAsync's lerp chain and weighted
+    means share this code. Returns the number of chunks sent to the pool."""
+    sources = [_leaf_sources(spec, u) for u in updates]
+    cs = [np.float32(c) for c in coeffs]
+    tasks = []
+    for i in leaves:
+        out, srcs = dst[i], [s[i] for s in sources]
+        for a in range(0, out.size, _CHUNK):
+            b = a + _CHUNK
+            tasks.append((4 * (min(b, out.size) - a), _axpy,
+                          (out[a:b], [s[a:b] for s in srcs], cs)))
+    _run_tasks(tasks)
+    return sum(t[0] >= _INLINE_BYTES for t in tasks)
+
+
+def _stack(spec: LeafSpec, updates: Sequence[NodeUpdate]) -> np.ndarray:
+    """The (K, N) f32 rows the ``fed_agg`` kernels take."""
+    stacked = np.empty((len(updates), spec.num_params), np.float32)
+    for row, u in zip(stacked, updates):
+        flat = getattr(u, "flat", None)
+        if flat is not None and spec.compatible(u.spec):
+            row[:] = flat
+        else:
+            spec.flatten_into(u.params, row)
+    return stacked
 
 
 class Strategy(ABC):
@@ -107,17 +135,19 @@ class Strategy(ABC):
     """
 
     name: str = "strategy"
+    # chunks the combine has sent to the codec pool over this strategy's life
+    pool_chunks: int = 0
 
     def __init__(self, *, use_kernel: bool = False, reuse_output: bool = False):
         self.use_kernel = use_kernel
-        # reuse_output=True returns trees that VIEW a strategy-owned buffer,
+        # reuse_output=True returns trees whose leaves are strategy-owned,
         # valid only until the next aggregate() call — the steady-state fast
         # path for trainers that consume the aggregate immediately (e.g. copy
         # it to device). Default False: every aggregate returns fresh storage.
         self.reuse_output = reuse_output
         self._spec: LeafSpec | None = None
-        self._stack = _StackCache()
         self._bufs: dict[str, np.ndarray] = {}
+        self._reused: tuple[str, list] | None = None  # (spec key, leaves)
 
     # -- flat plumbing -------------------------------------------------------
     def _resolve_spec(self, own: NodeUpdate) -> LeafSpec:
@@ -141,9 +171,6 @@ class Strategy(ABC):
             return flat
         return spec.flatten(u.params)
 
-    def _stacked(self, spec: LeafSpec, updates: Sequence[NodeUpdate]) -> np.ndarray:
-        return self._stack.stack(spec, updates)
-
     def _buffer(self, name: str, spec: LeafSpec) -> np.ndarray:
         """Named warm scratch vector (internal use — never escapes unless
         ``reuse_output`` opted in)."""
@@ -153,8 +180,15 @@ class Strategy(ABC):
             self._bufs[name] = buf
         return buf
 
-    def _out_buf(self, spec: LeafSpec) -> np.ndarray | None:
-        return self._buffer("out", spec) if self.reuse_output else None
+    def _out_leaves(self, spec: LeafSpec) -> list[np.ndarray]:
+        """Leaves of the spec's shapes and dtypes to write an aggregate into:
+        fresh, or under ``reuse_output`` the strategy's own."""
+        if self._reused is not None and self._reused[0] == spec.key:
+            return self._reused[1]
+        leaves = [np.empty(s, d) for s, d in zip(spec.shapes, spec.dtypes)]
+        if self.reuse_output:
+            self._reused = (spec.key, leaves)
+        return leaves
 
     def _emit(self, spec: LeafSpec, state: np.ndarray) -> np.ndarray:
         """Detach internal state for the caller: a fresh copy by default, the
@@ -169,12 +203,52 @@ class Strategy(ABC):
         weights = np.asarray([max(1, u.num_examples) for u in updates], np.float32)
         return weights / weights.sum()
 
-    def _weighted_mean(self, spec: LeafSpec, updates: Sequence[NodeUpdate], *,
-                       out: np.ndarray | None = None) -> np.ndarray:
-        """Example-count weighted mean (FedAvg, eq. 1) over stacked flats."""
-        return _combine_flat(self._stacked(spec, updates),
-                             self._mean_coeffs(updates),
-                             use_kernel=self.use_kernel, out=out)
+    def _kernel_combine(self, spec: LeafSpec, updates: Sequence[NodeUpdate],
+                        coeffs: np.ndarray) -> np.ndarray | None:
+        """Σ_k coeffs[k]·updates[k] by one ``fed_agg`` launch over the
+        stacked updates, when ``use_kernel`` asks for it; else None."""
+        if not self.use_kernel or len(updates) < 2:
+            return None
+        from repro.kernels.fed_agg import ops as fed_agg_ops
+
+        return np.asarray(fed_agg_ops.aggregate_flat(_stack(spec, updates), coeffs))
+
+    def _combine_into(self, spec: LeafSpec, updates: Sequence[NodeUpdate],
+                      coeffs: np.ndarray, out: np.ndarray) -> np.ndarray:
+        """Σ_k coeffs[k]·updates[k] as an f32 vector in the spec's layout:
+        ``out``, or the kernel's own."""
+        vec = self._kernel_combine(spec, updates, coeffs)
+        if vec is not None:
+            return vec
+        offsets = spec.offsets.tolist()
+        self.pool_chunks += _combine(
+            spec, updates, coeffs,
+            [out[o:o + n] for o, n in zip(offsets, spec.sizes)], range(len(offsets)))
+        return out
+
+    def _combine_leaves(self, spec: LeafSpec, updates: Sequence[NodeUpdate],
+                        coeffs: np.ndarray, leaves: Sequence[int] | None = None,
+                        ) -> list[np.ndarray]:
+        """Σ_k coeffs[k]·updates[k] in leaves of the spec's shapes and dtypes
+        (``_out_leaves``); only the leaf indices in ``leaves`` (all by
+        default) are written."""
+        out = self._out_leaves(spec)
+        if leaves is None:
+            leaves = range(len(out))
+        vec = self._kernel_combine(spec, updates, coeffs)
+        if vec is None:
+            self.pool_chunks += _combine(spec, updates, coeffs,
+                                         [a.reshape(-1) for a in out], leaves)
+            return out
+        for i in leaves:
+            o, n = int(spec.offsets[i]), spec.sizes[i]
+            np.copyto(out[i].reshape(-1), vec[o:o + n], casting="unsafe")
+        return out
+
+    def _mean_tree(self, spec: LeafSpec, updates: Sequence[NodeUpdate]) -> PyTree:
+        """Example-count weighted mean (FedAvg, eq. 1) as the model's tree."""
+        return jax.tree.unflatten(
+            spec.treedef, self._combine_leaves(spec, updates, self._mean_coeffs(updates)))
 
     @abstractmethod
     def aggregate(self, own: NodeUpdate, peers: Sequence[NodeUpdate]) -> PyTree:
@@ -208,8 +282,8 @@ class Strategy(ABC):
 
     def reset(self) -> None:  # stateful subclasses extend
         self._spec = None
-        self._stack = _StackCache()
         self._bufs.clear()
+        self._reused = None
 
 
 class FedAvg(Strategy):
@@ -218,9 +292,7 @@ class FedAvg(Strategy):
     name = "fedavg"
 
     def aggregate(self, own: NodeUpdate, peers: Sequence[NodeUpdate]) -> PyTree:
-        spec = self._resolve_spec(own)
-        return spec.unflatten(
-            self._weighted_mean(spec, [own, *peers], out=self._out_buf(spec)))
+        return self._mean_tree(self._resolve_spec(own), [own, *peers])
 
 
 class _FedOpt(Strategy):
@@ -231,7 +303,7 @@ class _FedOpt(Strategy):
     optimizer step to x. ``x``/``m``/``v`` are flat f32 vectors, lazily
     initialized from the first own update; with ``use_kernel`` the whole
     avg→Δ→moments→step chain runs as the fused ``fed_opt`` Pallas kernel
-    (one pass over the stack, no (K, N) temporaries).
+    (one pass over the stacked updates).
     """
 
     variant: str = "adam"
@@ -269,7 +341,6 @@ class _FedOpt(Strategy):
         if self.x is not None and self.x.size != spec.num_params:
             self.x = self.m = self.v = None  # structure changed → reinit
         updates = [own, *peers]
-        stacked = self._stacked(spec, updates)
         coeffs = self._mean_coeffs(updates)
         if self.x is None:
             self.x = np.array(self._flat_of(own, spec), np.float32, copy=True)
@@ -279,7 +350,7 @@ class _FedOpt(Strategy):
             from repro.kernels.fed_agg import ops as fed_agg_ops
 
             self.x, self.m, self.v = fed_agg_ops.fed_opt_flat(
-                stacked, coeffs, self.x, self.m, self.v,
+                _stack(spec, updates), coeffs, self.x, self.m, self.v,
                 variant=self.variant, server_lr=self.server_lr,
                 beta1=self.beta1, beta2=self.beta2, tau=self.tau,
             )
@@ -288,7 +359,7 @@ class _FedOpt(Strategy):
             # detach copy needed
             return spec.unflatten(self.x)
         else:
-            avg = _combine_flat(stacked, coeffs, out=self._buffer("avg", spec))
+            avg = self._combine_into(spec, updates, coeffs, self._buffer("avg", spec))
             d = self.x - avg  # pseudo-gradient
             self.m *= self.beta1
             self.m += (1.0 - self.beta1) * d
@@ -328,7 +399,9 @@ class FedAvgM(Strategy):
         spec = self._resolve_spec(own)
         if self.x is not None and self.x.size != spec.num_params:
             self.x = self.buf = None
-        avg = self._weighted_mean(spec, [own, *peers], out=self._buffer("avg", spec))
+        updates = [own, *peers]
+        avg = self._combine_into(spec, updates, self._mean_coeffs(updates),
+                                 self._buffer("avg", spec))
         if self.x is None:
             self.x = np.array(self._flat_of(own, spec), np.float32, copy=True)
             self.buf = np.zeros_like(self.x)
@@ -373,8 +446,8 @@ class FedAsync(Strategy):
 
     The sequential lerp chain factorizes exactly into one linear combination:
     c_own = Π_j (1 − α_j) and c_k = α_k·Π_{j>k} (1 − α_j), so the whole chain
-    is a single fused pass over the stacked flats (per-*client* work stays a
-    trivial K-length Python loop computing coefficients).
+    is a single combine (per-*client* work stays a trivial K-length Python
+    loop computing coefficients).
 
     Elastic-fleet churn adds a second discount axis: a peer whose
     ``lease_epoch`` is *ahead* of ours was adopted by a surviving worker and
@@ -427,10 +500,8 @@ class FedAsync(Strategy):
             coeffs[k + 1] = alphas[k] * suffix
             suffix *= 1.0 - alphas[k]
         coeffs[0] = suffix
-        stacked = self._stacked(spec, [own, *peers])
-        return spec.unflatten(
-            _combine_flat(stacked, coeffs, use_kernel=self.use_kernel,
-                          out=self._out_buf(spec)))
+        return jax.tree.unflatten(
+            spec.treedef, self._combine_leaves(spec, [own, *peers], coeffs))
 
 
 class FedBuff(Strategy):
@@ -464,9 +535,7 @@ class FedBuff(Strategy):
             return own.params
         updates = list(self._pending.values())
         self._pending.clear()
-        spec = self._resolve_spec(own)
-        return spec.unflatten(
-            self._weighted_mean(spec, updates, out=self._out_buf(spec)))
+        return self._mean_tree(self._resolve_spec(own), updates)
 
 
 class PartialFedAvg(Strategy):
@@ -480,9 +549,9 @@ class PartialFedAvg(Strategy):
     mask and the wire selector can never diverge. It overrides
     ``shared_pattern`` when given.
 
-    The leaf filter compiles once per spec into a boolean mask over the flat
-    index space (per-leaf work at spec-construction time only); each aggregate
-    is then the usual fused weighted mean plus one vectorized select.
+    The leaf filter runs once per spec; each aggregate then combines the
+    shared leaves and copies own's personal leaves as they are, in their own
+    dtype, never rounded through f32.
     """
 
     name = "partial_fedavg"
@@ -492,58 +561,28 @@ class PartialFedAvg(Strategy):
         super().__init__(use_kernel=use_kernel, reuse_output=reuse_output)
         self.families = families
         self.pattern = re.compile(shared_pattern)
-        self._mask: np.ndarray | None = None
-        self._leaf_mask: list[bool] | None = None
-        self._mask_key: str | None = None
+        self._split: tuple[str, list[int], list[int]] | None = None
 
-    def _mask_for(self, spec: LeafSpec) -> np.ndarray:
-        if self._mask_key != spec.key:
+    def _split_for(self, spec: LeafSpec) -> tuple[list[int], list[int]]:
+        """(shared, personal) leaf indices of ``spec``."""
+        if self._split is None or self._split[0] != spec.key:
             if self.families is not None:
-                view = spec.family_view(self.families)
-                mask = view.mask
-                leaf_mask = list(view.leaf_mask)
+                mask = spec.family_view(self.families).leaf_mask
             else:
-                mask = np.zeros(spec.num_params, bool)
-                leaf_mask = []
-                for path, off, n in zip(spec.paths, spec.offsets, spec.sizes):
-                    shared = bool(self.pattern.search(path))
-                    leaf_mask.append(shared)
-                    if shared:
-                        mask[off:off + n] = True
-            self._mask = mask
-            self._leaf_mask = leaf_mask
-            self._mask_key = spec.key
-        return self._mask
+                mask = [bool(self.pattern.search(p)) for p in spec.paths]
+            self._split = (spec.key, [i for i, m in enumerate(mask) if m],
+                           [i for i, m in enumerate(mask) if not m])
+        return self._split[1], self._split[2]
 
     def aggregate(self, own: NodeUpdate, peers: Sequence[NodeUpdate]) -> PyTree:
         spec = self._resolve_spec(own)
         updates = [own, *peers]
-        stacked = self._stacked(spec, updates)
-        avg = _combine_flat(stacked, self._mean_coeffs(updates),
-                            use_kernel=self.use_kernel,
-                            out=self._buffer("avg", spec))
-        # stacked[0] is own's flat (just written by the stack fill) — reuse it
-        # for the personal entries instead of re-flattening own
-        out = self._out_buf(spec)
-        if out is None:
-            out = np.empty(spec.num_params, np.float32)
-        np.copyto(out, stacked[0])
-        np.copyto(out, avg, where=self._mask_for(spec))
-        out_tree = spec.unflatten(out)
-        if spec.f32_exact:
-            return out_tree
-        # Personal leaves of non-f32-embeddable models (int/f64) must pass
-        # through untouched — never rounded through the f32 flat. Swap own's
-        # original leaf objects back in (per-leaf, but only on this exact-
-        # dtype fallback, never on the f32 hot path).
-        import jax
-
-        agg_leaves = jax.tree.leaves(out_tree)
-        own_leaves = jax.tree.leaves(own.params)
-        return jax.tree.unflatten(spec.treedef, [
-            a if shared else o
-            for a, o, shared in zip(agg_leaves, own_leaves, self._leaf_mask)
-        ])
+        shared, personal = self._split_for(spec)
+        out = self._combine_leaves(spec, updates, self._mean_coeffs(updates), shared)
+        own_leaves = _leaf_sources(spec, own)
+        for i in personal:
+            np.copyto(out[i].reshape(-1), own_leaves[i], casting="unsafe")
+        return jax.tree.unflatten(spec.treedef, out)
 
 
 STRATEGIES = {

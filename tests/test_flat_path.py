@@ -405,29 +405,6 @@ def test_compressed_delta_transport_stays_bitwise(tmp_path):
 # --- steady-state shape of the hot path --------------------------------------
 
 
-def test_stack_cache_reuses_buffer_and_rows():
-    from repro.core.strategies import _StackCache
-
-    spec = LeafSpec.of(tree_of([1.0, 1.0]))
-    mk = lambda f: FlatUpdate(f, spec, num_examples=1, node_id="u")
-    f0, f1 = spec.flatten(tree_of([1.0, 1.0])), spec.flatten(tree_of([2.0, 0.0]))
-    u0, u1 = mk(f0), mk(f1)
-    cache = _StackCache()
-    buf1 = cache.stack(spec, [u0, u1])
-    np.testing.assert_array_equal(buf1[0], u0.flat)
-    buf1[0, 0] = 123.0  # poison: a reused row must be overwritten only if source changed
-    buf2 = cache.stack(spec, [u0, u1])
-    assert buf2 is buf1  # same buffer object, no realloc
-    assert buf2[0, 0] == 123.0  # row NOT recopied: same source flat object
-    u0b = mk(u0.flat.copy())
-    buf3 = cache.stack(spec, [u0b, u1])
-    assert buf3[0, 0] == u0.flat[0]  # new source object → row refreshed
-    # tree-only updates are flattened into their row every call
-    t = NodeUpdate(tree_of([3.0, 1.0]), num_examples=1, node_id="t")
-    buf4 = cache.stack(spec, [t, u1])
-    np.testing.assert_array_equal(buf4[0], spec.flatten(t.params))
-
-
 def test_partial_fedavg_personal_leaves_exact_for_nonf32_models():
     """Personal (non-federated) leaves of int/f64 models must pass through
     bit-exact — never rounded through the f32 flat."""
